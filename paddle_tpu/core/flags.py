@@ -359,17 +359,11 @@ define_flag("enable_auto_parallel_align_mode", bool, False,
 
 
 def _wire_compile_cache(v):
-    try:
-        import jax
-        if v:
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.environ.get("PADDLE_TPU_COMPILE_CACHE",
-                               "/tmp/paddle_tpu_jax_cache"))
-        else:
-            jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
+    from .compile_cache import disable_compile_cache, enable_compile_cache
+    if v:
+        enable_compile_cache()
+    else:
+        disable_compile_cache()
 
 
 define_flag("enable_cinn_compile_cache", bool, False,
@@ -394,9 +388,11 @@ define_flag("print_ir", bool, False,
 
 # ---- round-4 continuation: remaining TPU-meaningful reference flags,
 # each wired to observable behavior (tests/test_flags_behavior.py) ----
-define_flag("enable_fusion_fallback", bool, True,
-            "a failing fused (Pallas) kernel falls back to the composed "
-            "XLA body instead of raising (reference enable_fusion_fallback)")
+define_flag("enable_fusion_fallback", bool, False,
+            "opt-in: a failing fused (Pallas) kernel falls back to the "
+            "composed XLA body instead of raising (reference "
+            "enable_fusion_fallback). Off by default so a kernel the chip's "
+            "compiler refuses fails the step instead of hiding behind jnp")
 define_flag("flash_attn_version", int, 2,
             "1: pin the composed XLA attention (no flash tier); "
             "2: allow the Pallas flash kernel tier (default)")

@@ -9,8 +9,8 @@ stay pure).
 
 The base key is materialized lazily: creating a ``jax.random.key`` touches the
 JAX backend, and ``import paddle_tpu`` must never initialize a backend (a
-wedged/contended TPU pool would hang or crash the import — round-1 verdict
-item 1).
+process that has touched JAX holds the chip; an import must not decide
+that).
 """
 from __future__ import annotations
 

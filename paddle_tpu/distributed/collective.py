@@ -698,8 +698,7 @@ def send(tensor, dst=0, group=None, sync_op=True):
     eager multi-process: mailbox on the coordination service."""
     ax = _get_axis(group)
     if _in_manual_region(ax):
-        from ._shard_map_compat import axis_size
-        n = axis_size(ax)
+        n = lax.axis_size(ax)
         tensor._data = lax.ppermute(tensor._data, ax,
                                     [(i, dst) for i in range(n)])
         return tensor

@@ -29,8 +29,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from ._shard_map_compat import shard_map
 
 _BIG = 1e30
 

@@ -21,7 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ._shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..incubate.distributed.models.moe.gate import capacity_for
@@ -34,8 +34,7 @@ def _local_moe(x_local, gate_w, expert_params, *, expert_fn, top_k,
     experts)."""
     from ..incubate.distributed.models.moe.gate import topk_gating
 
-    from ._shard_map_compat import axis_size
-    ep = axis_size(ep_axis)
+    ep = jax.lax.axis_size(ep_axis)
     E = n_exp_local * ep
     logits = x_local @ gate_w                                    # [T, E]
     combine, aux_loss = topk_gating.pure(

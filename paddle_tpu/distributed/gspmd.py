@@ -432,11 +432,12 @@ _MESH_STACK: list = []
 
 
 class partitioning_scope:
-    def __init__(self, mesh):
+    def __init__(self, mesh, zero=False):
         self.mesh = mesh
+        self.zero = bool(zero)
 
     def __enter__(self):
-        _MESH_STACK.append(self.mesh)
+        _MESH_STACK.append(self)
         return self.mesh
 
     def __exit__(self, *exc):
@@ -445,7 +446,18 @@ class partitioning_scope:
 
 
 def active_mesh():
-    return _MESH_STACK[-1] if _MESH_STACK else None
+    return _MESH_STACK[-1].mesh if _MESH_STACK else None
+
+
+def flat_state_sharded() -> bool:
+    """True while tracing a program whose fused flat optimizer state may
+    arrive data-sharded: the ZeRO preset on a pure data mesh (every other
+    mesh keeps the state replicated, see :func:`opt_state_shardings`)."""
+    if not _MESH_STACK or not _MESH_STACK[-1].zero:
+        return False
+    shape = _MESH_STACK[-1].mesh.shape
+    return (shape.get(DATA_AXIS, 1) > 1 and shape.get(MODEL_AXIS, 1) <= 1
+            and shape.get(PIPELINE_AXIS, 1) <= 1)
 
 
 #: (mesh, n_stages, n_microbatches) bound while TrainStep traces a
@@ -508,9 +520,16 @@ def predicted_pipeline_permutes(pipe) -> int:
     return 5 if pipe > 1 else 0
 
 
+# An op DEFINITION reads ``= <result type> opname(``; an operand reference
+# reads ``%opname.3``. The result type is not parsed: a TPU layout such
+# as ``f32[512]{0:T(8,128)S(1)}`` carries parentheses of its own, and a
+# tuple type carries spaces. The op name standing free before ``(`` is
+# what only a definition has.
+_OP_DEF = r"(?<![%\w.-]){name}(?:-start)?\("
+
 _CP_PAIRS_RE = re.compile(
-    r"= (?:\([^)]*\)|[^\s(]+) collective-permute(?:-start)?\("
-    r"[^\n]*?source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")
+    _OP_DEF.format(name="collective-permute")
+    + r"[^\n]*?source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")
 
 
 def pipeline_permute_counts(hlo_text: str, pipe: int) -> dict:
@@ -596,14 +615,9 @@ def collective_counts(hlo_text: str) -> dict:
     of async collectives count once."""
     out = {}
     for name in _COLLECTIVES:
-        # `%all-reduce.3 = f32[...] all-reduce(` — count op instances,
-        # not operand references: match the `= <type> opname(`
-        # definition form. The result type is either one token or a
-        # TUPLE `(f32[8]{0}, f32[4]{0})` with spaces (XLA's
-        # AllReduceCombiner emits those) — both shapes must count.
+        # count op instances, not operand references (see _OP_DEF).
         # Async pairs define `-start`/`-done`; count the starts once.
-        defs = re.findall(
-            rf"= (?:\([^)]*\)|[^\s(]+) {name}(?:-start)?\(", hlo_text)
+        defs = re.findall(_OP_DEF.format(name=name), hlo_text)
         out[name.replace("-", "_")] = len(defs)
     return out
 
@@ -614,7 +628,8 @@ __all__ = [
     "named_param_shardings", "shard_serving_params", "kv_pool_sharding",
     "kv_scale_sharding", "opt_state_shardings", "batch_sharding",
     "replicated", "collective_counts", "partitioning_scope",
-    "active_mesh", "constrain_flat", "stage_state", "pipeline_scope",
+    "active_mesh", "flat_state_sharded", "constrain_flat", "stage_state",
+    "pipeline_scope",
     "active_pipeline", "stage_param_bytes",
     "predicted_pipeline_permutes", "pipeline_permute_counts",
 ]

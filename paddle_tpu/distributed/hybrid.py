@@ -28,7 +28,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from ._shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.generation import _rms_norm, _rope

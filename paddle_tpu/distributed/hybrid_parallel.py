@@ -46,7 +46,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ._shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor

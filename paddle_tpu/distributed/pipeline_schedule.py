@@ -41,9 +41,7 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ._shard_map_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 # opcodes (values are the lax.switch branch indices)

@@ -19,9 +19,7 @@ stage is a *sharding declaration* over the 'sharding' (or 'dp') mesh axis:
 from __future__ import annotations
 
 import jax
-from jax import lax
-
-from ._shard_map_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import ProcessMesh

@@ -685,6 +685,17 @@ class TrainStep:
                 out_sh = (p_sh, o_sh, b_sh, rep)
                 if check_finite:
                     out_sh = out_sh + (rep,)
+                # Put the state where the step keeps it BEFORE the first
+                # call (parameters one at a time). A freshly built model sits
+                # whole on device 0; handed to the jit as it is, the first
+                # call holds that original, its resharded (donated) copy
+                # and the program's temporaries together — at real sizes
+                # that does not fit the device the model was built on.
+                for k, prm in self._params.items():
+                    prm._data = jax.device_put(prm._data, p_sh[k])
+                self._install_opt_state(
+                    {k: jax.device_put(v, o_sh[k])
+                     for k, v in self._opt_state_arrays().items()})
                 jit_kw = dict(
                     in_shardings=(p_sh, o_sh, b_sh, rep, rep, rep)
                     + batch_sh,
@@ -731,7 +742,8 @@ class TrainStep:
             # recompile (shape change, remat/flag flip) is visible next
             # to the pipeline gauges instead of reading as one slow step.
             from ..profiler import compile_event
-            shard_ctx = (_gspmd.partitioning_scope(self._mesh)
+            shard_ctx = (_gspmd.partitioning_scope(self._mesh,
+                                                   zero=shard_cfg.zero)
                          if shard_cfg is not None else nullcontext())
             # pp>1: LayerStack.forward switches to the stage-sliced
             # pipelined scan while this scope is bound around the trace

@@ -86,15 +86,28 @@ def splash_attention(q, k, v, causal=True, scale=None, interpret=False):
 
 def _on_tpu() -> bool:
     # Touching jax.devices() initializes the backend — must never run at
-    # import time (a contended TPU pool blocks the import; round-1 verdict
-    # weakness 1). install() defers this check to the first attention call.
+    # import time (import paddle_tpu stays backend-free); install() defers
+    # this check to the first kernel call. A backend that fails to come up
+    # raises here: "no TPU" is an answer only jax.devices() itself gives.
     global _ON_TPU
     if _ON_TPU is None:
-        try:
-            _ON_TPU = jax.devices()[0].platform not in ("cpu", "gpu")
-        except Exception:
-            _ON_TPU = False
+        _ON_TPU = jax.devices()[0].platform not in ("cpu", "gpu")
     return _ON_TPU
+
+
+def flash_threshold(forced: bool = False) -> int:
+    """Sequence length from which the sdpa override engages the Pallas
+    flash kernel: PADDLE_TPU_FLASH_THRESHOLD, else (unless the kernel is
+    forced, where it is 256) FLAGS_pallas_flash_threshold, else 8192.
+    Read per call so tests/fixtures can flip the gates after import."""
+    env = os.environ.get("PADDLE_TPU_FLASH_THRESHOLD")
+    if env is not None:
+        return int(env)
+    if forced:
+        return 256
+    from ..core.flags import GLOBAL_FLAGS
+    flag = GLOBAL_FLAGS.get("pallas_flash_threshold")
+    return int(flag) if flag is not None else 8192
 
 
 def install():
@@ -116,8 +129,7 @@ def install():
         # PADDLE_TPU_ATTN_IMPL: step-level attention A/B selector
         # (round-5): auto (default tiering) | xla (pin the composition) |
         # flash (pin our Pallas kernel) | splash (pin jax's production
-        # TPU splash-attention kernel). The chip-window experiment
-        # matrix (tools/tpu_round5.py) flips this per bench run.
+        # TPU splash-attention kernel).
         impl = os.environ.get("PADDLE_TPU_ATTN_IMPL", "auto")
         if impl == "xla":
             return _sdpa_reference(q, k, v, *rest, causal=causal,
@@ -152,7 +164,7 @@ def install():
             forced = True        # pin the Pallas kernel (interpret off-TPU)
         use_pallas = forced or _on_tpu()
         interpret = not _on_tpu()
-        # Measured on the v5e pool chip (scan-chained fwd+bwd, readback
+        # Measured on a v5e chip in round 2 (scan-chained fwd+bwd, readback
         # sync; b=8 h=12 d=64): XLA composition beats every Pallas kernel
         # tried (ours, jax flash, splash) up to s=4096 — e.g. s=2048 XLA
         # 14.4ms vs Pallas 32.7ms; engaging Pallas at s=2048 cost 2.3x
@@ -161,16 +173,7 @@ def install():
         # the O(s^2) score materialization starts to dominate/ OOM and the
         # O(s) working set is worth it regardless. Tunable per deployment
         # via PADDLE_TPU_FLASH_THRESHOLD (re-measure on real v5p/v5e metal).
-        if forced:
-            thresh = int(os.environ.get("PADDLE_TPU_FLASH_THRESHOLD", "256"))
-        else:
-            from ..core.flags import GLOBAL_FLAGS
-            env = os.environ.get("PADDLE_TPU_FLASH_THRESHOLD")
-            if env is not None:
-                thresh = int(env)
-            else:
-                flag = GLOBAL_FLAGS.get("pallas_flash_threshold")
-                thresh = int(flag) if flag is not None else 8192
+        thresh = flash_threshold(forced)
         # Pallas path: no arbitrary mask, no dropout, seq long enough to
         # beat the fused XLA composition.
         from ..core.flags import GLOBAL_FLAGS

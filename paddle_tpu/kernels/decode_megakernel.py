@@ -16,13 +16,19 @@ decode layer body into ONE persistent Pallas kernel:
     the kernels/paged_attention.py discipline) -> o projection ->
     residual add -> rms_norm -> swiglu MLP -> residual add
 
-Grid = (row, kv-head group, logical page): the page axis is innermost
-and sequential, so VMEM scratch carries the online-softmax state
-(m, l, acc) and the roped queries across pages — HBM page reads scale
-with true kv length exactly like the ragged kernel. The projection
-prologue runs once per row at (group 0, page 0); the o-proj + MLP
-epilogue runs once at the last (group, page) step. Weight tiles use
-constant index maps, so the pipeline elides their reloads across rows.
+Grid = (row block, row in block, kv-head group, logical page): the page
+axis is innermost and sequential, so VMEM scratch carries the
+online-softmax state (m, l, acc) and the roped queries across pages —
+HBM page reads scale with true kv length exactly like the ragged
+kernel. Row operands (h, rope tables, outputs) move in blocks of
+``_ROW_BLOCK`` = 8 f32 rows — one row is not a legal TPU tile — with R
+padded up to a multiple of it (pad rows: kv_len 0, null-page tables);
+the kernel picks its row out of the resident block by a dynamic sublane
+index. The projection prologue runs once per row at (group 0, page 0);
+the o-proj + MLP epilogue runs once at the last (group, page) step.
+Weight tiles use constant index maps, so the pipeline elides their
+reloads across rows — and every projection matrix sits WHOLE in VMEM,
+which bounds the widths this kernel can serve (see ROADMAP A3).
 
 Two KV-append contracts (the caller owns the pool write):
 
@@ -83,6 +89,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+# row operands are blocked in eights: the second-minor dim of a TPU block
+# must be a multiple of 8 (f32 sublanes) or the whole array
+_ROW_BLOCK = 8
+
 _LAYER_MATS = ("q", "k", "v", "o", "gate", "up", "down")
 
 # process-wide record of a runtime Pallas failure that
@@ -134,6 +144,16 @@ def megakernel_mode(layer=None, interpret=None) -> str:
     if interpret is None:
         interpret = os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1"
     return "interpret" if interpret else "jnp"
+
+
+def whole_weight_vmem_limit(operands) -> int:
+    """Scoped-VMEM budget for a megakernel launch: its projection
+    matrices sit WHOLE in VMEM, which the compiler's default 16 MiB
+    scope holds only at toy widths. Ask for the operands plus working
+    room, capped under the chip's 128 MiB so a layer that cannot fit is
+    refused by the compiler and not by a guess made here."""
+    need = sum(a.size * a.dtype.itemsize for a in operands) + (16 << 20)
+    return int(min(max(need, 32 << 20), 100 << 20))
 
 
 def _rms(x, w, eps):
@@ -226,17 +246,19 @@ def _build_kernel(*, H, Hkv, grp, dh, ps, G, hb, self_kv, quant_w,
         l_scr = next(it)
         acc_scr = next(it)
 
-        r = pl.program_id(0)
-        g = pl.program_id(1)
-        p = pl.program_id(2)
+        ri = pl.program_id(1)
+        r = pl.program_id(0) * _ROW_BLOCK + ri
+        g = pl.program_id(2)
+        p = pl.program_id(3)
+        row = pl.ds(ri, 1)          # this step's row of the 8-row block
         kv_len = kl_ref[r]
         # cached positions visible in pages (self_kv keeps the current
         # token in-register, so pages cover one position fewer)
         Lc = kv_len - 1 if self_kv else kv_len
 
-        def mat(pair):
+        def mat(pair, rows=slice(None)):
             w_ref, s_ref = pair
-            w = w_ref[...].astype(jnp.float32)
+            w = w_ref[rows, :].astype(jnp.float32)
             if s_ref is not None:
                 # int8 prologue dequant (int8_matmul's discipline): the
                 # weight becomes fp only inside VMEM
@@ -250,32 +272,38 @@ def _build_kernel(*, H, Hkv, grp, dh, ps, G, hb, self_kv, quant_w,
 
         @pl.when((g == 0) & (p == 0))
         def _prologue():
-            hv = h_ref[...].astype(jnp.float32)             # [1, D]
-            cosv = cos_ref[...].astype(jnp.float32)         # [1, dh]
-            sinv = sin_ref[...].astype(jnp.float32)
+            hv = h_ref[row, :]                              # [1, D] f32
+            cosv = cos_ref[row, :]                          # [1, dh]
+            sinv = sin_ref[row, :]
             swap = _swap_matrix(dh)
             x = _rms(hv, ln1_ref[...].astype(jnp.float32), eps)
-            q = dot(x, mat(wq)).reshape(H, dh)
-            q = q * cosv + dot(q, swap) * sinv
-            q_scr[...] = q
+
+            def rope(t):                                    # [1, dh]
+                return t * cosv + dot(t, swap) * sinv
+
+            # heads come off the flat projection by STATIC lane slices
+            # in a static loop: Mosaic has no [1, H*dh] -> [H, dh]
+            # shape cast when dh is under a full 128-lane tile
+            qf = dot(x, mat(wq))                            # [1, H*dh]
+            for hh in range(H):
+                q_scr[pl.ds(hh, 1), :] = rope(
+                    qf[:, hh * dh:(hh + 1) * dh])
             if self_kv:
-                k = dot(x, mat(wk)).reshape(Hkv, dh)
-                k = k * cosv + dot(k, swap) * sinv
-                v = dot(x, mat(wv)).reshape(Hkv, dh)
-                kout_ref[...] = k.reshape(1, Hkv * dh) \
-                    .astype(kout_ref.dtype)
-                vout_ref[...] = v.reshape(1, Hkv * dh) \
-                    .astype(vout_ref.dtype)
-                krep = jnp.broadcast_to(k[:, None, :], (Hkv, grp, dh)) \
-                    .reshape(H, dh)
-                vrep = jnp.broadcast_to(v[:, None, :], (Hkv, grp, dh)) \
-                    .reshape(H, dh)
-                # the current token's self term seeds the online
-                # softmax: m = s_self, l = exp(0) = 1, acc = v
-                s_self = jnp.sum(q * krep, axis=1, keepdims=True) * scale
-                m_scr[...] = s_self
+                kf = dot(x, mat(wk))                        # [1, Hkv*dh]
+                vf = dot(x, mat(wv))
+                for j in range(Hkv):
+                    kh = rope(kf[:, j * dh:(j + 1) * dh])
+                    vh = vf[:, j * dh:(j + 1) * dh]
+                    kout_ref[ri, pl.ds(j, 1), :] = kh
+                    vout_ref[ri, pl.ds(j, 1), :] = vh
+                    # the current token's self term seeds the online
+                    # softmax of this kv head's grp query heads:
+                    # m = s_self, l = exp(0) = 1, acc = v
+                    heads = pl.ds(j * grp, grp)
+                    m_scr[heads, :] = jnp.sum(
+                        q_scr[heads, :] * kh, axis=1, keepdims=True) * scale
+                    acc_scr[heads, :] = jnp.broadcast_to(vh, (grp, dh))
                 l_scr[...] = jnp.ones_like(l_scr)
-                acc_scr[...] = vrep
             else:
                 m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
                 l_scr[...] = jnp.zeros_like(l_scr)
@@ -317,15 +345,20 @@ def _build_kernel(*, H, Hkv, grp, dh, ps, G, hb, self_kv, quant_w,
                 m_scr[pl.ds(row0, grp), :] = m_new
                 acc_scr[pl.ds(row0, grp), :] = aj * alpha + dot(e, vj)
 
-        @pl.when((g == G - 1) & (p == pl.num_programs(2) - 1))
+        @pl.when((g == G - 1) & (p == pl.num_programs(3) - 1))
         def _epilogue():
             o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)   # [H, dh]
-            hv = h_ref[...].astype(jnp.float32)
-            h2 = hv + dot(o.reshape(1, H * dh), mat(wo))
+            # o-proj without the [H, dh] -> [1, H*dh] shape cast: one
+            # [1, dh] x [dh, D] dot per head over static row slices of
+            # the o weight
+            h2 = h_ref[row, :]
+            for hh in range(H):
+                h2 = h2 + dot(o[hh:hh + 1, :],
+                              mat(wo, pl.ds(hh * dh, dh)))
             x2 = _rms(h2, ln2_ref[...].astype(jnp.float32), eps)
             mlp = dot(jax.nn.silu(dot(x2, mat(wg))) * dot(x2, mat(wu)),
                       mat(wd))
-            hout_ref[...] = (h2 + mlp).astype(hout_ref.dtype)
+            hout_ref[row, :] = h2 + mlp
 
     return kernel
 
@@ -446,21 +479,33 @@ def fused_decode_layer(layer, h, k_pages, v_pages, block_tables, kv_lens,
     PPS = block_tables.shape[1]
     scale = 1.0 / (dh ** 0.5)
     cos, sin = _rope_tables(kv_lens, theta, dh)
+    # row operands ride f32 blocks of _ROW_BLOCK rows (the kernel upcast
+    # them on entry and downcast on exit anyway, so moving the casts out
+    # here is value-identical); pad rows read kv_len 0 and the null page
+    Rp = -(-R // _ROW_BLOCK) * _ROW_BLOCK
+
+    def pad_rows(a):
+        return jnp.pad(a, ((0, Rp - R),) + ((0, 0),) * (a.ndim - 1))
+
+    h_rows = pad_rows(h.astype(jnp.float32))
+    cos, sin = pad_rows(cos), pad_rows(sin)
+    tbl_rows, kl_rows = pad_rows(block_tables), pad_rows(kv_lens)
     # kv head dim of the current page block for the index maps below
     shift = 1 if self_kv else 0
 
     def kv_map_for(hb):
-        def kv_map(r, g, p, tbl, kl, *rest):
+        def kv_map(rb, ri, g, p, tbl, kl, *rest):
             # dead pages clamp to the last live one: revisiting a block
             # lets the pipeline elide the copy (the ragged kernel trick)
+            r = rb * _ROW_BLOCK + ri
             last = jnp.maximum(kl[r] - shift - 1, 0) // ps
             return (g, tbl[r, jnp.minimum(p, last)], 0, 0)
         return kv_map
 
-    def row_map(r, g, p, *pf):
-        return (r, 0)
+    def row_map(rb, ri, g, p, *pf):
+        return (rb, 0)
 
-    def const_map(r, g, p, *pf):
+    def const_map(rb, ri, g, p, *pf):
         return (0, 0)
 
     def wop(key):
@@ -481,12 +526,12 @@ def fused_decode_layer(layer, h, k_pages, v_pages, block_tables, kv_lens,
                                hb=hb, self_kv=self_kv, quant_w=quant_w,
                                quant_kv=quant_kv, eps=float(eps),
                                scale=scale)
-        operands = [h, cos, sin,
+        operands = [h_rows, cos, sin,
                     jnp.asarray(layer["ln1"]).reshape(1, D),
                     jnp.asarray(layer["ln2"]).reshape(1, D)]
-        in_specs = [pl.BlockSpec((1, D), row_map),
-                    pl.BlockSpec((1, dh), row_map),
-                    pl.BlockSpec((1, dh), row_map),
+        in_specs = [pl.BlockSpec((_ROW_BLOCK, D), row_map),
+                    pl.BlockSpec((_ROW_BLOCK, dh), row_map),
+                    pl.BlockSpec((_ROW_BLOCK, dh), row_map),
                     pl.BlockSpec((1, D), const_map),
                     pl.BlockSpec((1, D), const_map)]
         for key in _LAYER_MATS:
@@ -496,18 +541,21 @@ def fused_decode_layer(layer, h, k_pages, v_pages, block_tables, kv_lens,
         operands += [k_pages, v_pages]
         in_specs += [pl.BlockSpec((hb, 1, ps, dh), kv_map_for(hb)),
                      pl.BlockSpec((hb, 1, ps, dh), kv_map_for(hb))]
-        out_shape = [jax.ShapeDtypeStruct((R, D), h.dtype)]
-        out_specs = [pl.BlockSpec((1, D), row_map)]
+        f32 = jnp.float32
+        out_shape = [jax.ShapeDtypeStruct((Rp, D), f32)]
+        out_specs = [pl.BlockSpec((_ROW_BLOCK, D), row_map)]
         if self_kv:
-            out_shape += [jax.ShapeDtypeStruct((R, Hkv * dh), h.dtype)] * 2
-            out_specs += [pl.BlockSpec((1, Hkv * dh), row_map)] * 2
-        prefetch = [block_tables, kv_lens]
+            out_shape += [jax.ShapeDtypeStruct((Rp, Hkv, dh), f32)] * 2
+            out_specs += [pl.BlockSpec(
+                (_ROW_BLOCK, Hkv, dh),
+                lambda rb, ri, g, p, *pf: (rb, 0, 0))] * 2
+        prefetch = [tbl_rows, kl_rows]
         if quant_kv:
             prefetch += [jnp.asarray(k_scales, jnp.float32),
                          jnp.asarray(v_scales, jnp.float32)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(R, G, PPS),
+            grid=(Rp // _ROW_BLOCK, _ROW_BLOCK, G, PPS),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
@@ -517,10 +565,14 @@ def fused_decode_layer(layer, h, k_pages, v_pages, block_tables, kv_lens,
                 pltpu.VMEM((H, dh), jnp.float32),    # acc
             ],
         )
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="fused_decode_layer",
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=whole_weight_vmem_limit(
+                    operands[:-2])),     # all but the HBM-paged pools
         )(*prefetch, *operands)
+        return [o[:R].astype(h.dtype) for o in out]
 
     traced = any(isinstance(a, jax.core.Tracer)
                  for a in (h, k_pages, kv_lens))
@@ -542,8 +594,7 @@ def fused_decode_layer(layer, h, k_pages, v_pages, block_tables, kv_lens,
             theta=theta, num_heads=num_heads, self_kv=self_kv,
             k_scales=k_scales, v_scales=v_scales)
     if self_kv:
-        h_out, k_cur, v_cur = out
-        return h_out, k_cur.reshape(R, Hkv, dh), v_cur.reshape(R, Hkv, dh)
+        return tuple(out)
     return out[0], None, None
 
 

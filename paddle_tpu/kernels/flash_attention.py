@@ -127,7 +127,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_fwd",
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hq * sq * sk * d // (2 if causal else 1),
             bytes_accessed=(q.size + k.size + v.size + q.size) * q.dtype.itemsize,
@@ -271,7 +271,7 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
                                lambda bi, hi, iq, ik: (bi, hi, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_bwd_dq",
     )(q, k, v, do, lse_r, delta_r)
 
     # dk/dv are accumulated per *query* head then reduced over the GQA
@@ -307,7 +307,7 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse_r, delta_r)
 
     if group > 1:

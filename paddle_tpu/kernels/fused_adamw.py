@@ -74,7 +74,7 @@ def _run(p, g, m, v, scalars, block_rows, interpret, *, beta1, beta2, eps,
         ],
         # in-place in HBM: the padded copies are consumed by their outputs
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interpret,
+        interpret=interpret, name="fused_adamw",
     )(scalars, p2, g2, m2, v2)
     return (new_p.reshape(-1)[:n], new_m.reshape(-1)[:n],
             new_v.reshape(-1)[:n])
@@ -107,8 +107,8 @@ def fused_adamw(p, g, m, v, lr, t, *, beta1=0.9, beta2=0.999, eps=1e-8,
     wd = float(weight_decay)
     c1 = 1 - beta1 ** t
     c2 = 1 - beta2 ** t
-    on_tpu = jax.default_backend() not in ("cpu", "gpu")
-    if not (on_tpu or interpret):
+    from . import _on_tpu   # the shared cached backend probe
+    if not (_on_tpu() or interpret):
         return _reference(p, g, m, v, lr, c1, c2, beta1=beta1, beta2=beta2,
                           eps=eps, wd=wd, decoupled=decoupled)
     scalars = jnp.stack([
@@ -152,14 +152,33 @@ def maybe_fused_adamw(p, g, m, v, lr, t, *, beta1, beta2, eps,
     it), else None so the engine keeps its jnp bucket body. A kernel
     failure falls back the same way under FLAGS_enable_fusion_fallback."""
     forced = os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1"
-    on_tpu = jax.default_backend() not in ("cpu", "gpu")
+    from . import _on_tpu
+    on_tpu = _on_tpu()
     if not (on_tpu or forced):
         return None
-    try:
+    from ..distributed.gspmd import active_mesh, flat_state_sharded
+    if flat_state_sharded():
+        # ZeRO on a data mesh: the partitioner splits the elementwise jnp
+        # update over the sharded state, which IS the ZeRO-1 update
+        return None
+
+    def run(p, g, m, v, lr, t):
         return fused_adamw(p, g, m, v, lr, t, beta1=beta1, beta2=beta2,
                            eps=eps, weight_decay=weight_decay,
                            decoupled=decoupled,
                            interpret=forced and not on_tpu)
+
+    mesh = active_mesh()
+    if mesh is not None:
+        # a Mosaic kernel cannot be partitioned automatically: run it as
+        # a manual region over the mesh. The flat bucket is replicated
+        # there (gspmd.constrain_flat), so every device updates its copy.
+        from jax.sharding import PartitionSpec as P
+        run = jax.shard_map(run, mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)
+        lr, t = jnp.asarray(lr), jnp.asarray(t)
+    try:
+        return run(p, g, m, v, lr, t)
     except Exception:
         from ..core.flags import GLOBAL_FLAGS
         if GLOBAL_FLAGS.get("enable_fusion_fallback"):

@@ -77,23 +77,29 @@ def _kernel_int8(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _kernel_int4(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
+def _kernel_int4(xe_ref, xo_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = w_ref[...]                                   # [bk//2, bn] int8
-    # one shared unpack implementation (quantization.unpack_int4): mask,
-    # sign-extend, interleave low/high nibbles back to contraction order
-    from ..quantization import unpack_int4
-    w_q = unpack_int4(packed, packed.shape[0] * 2)
-    w = w_q.astype(jnp.float32) * s_ref[...]              # [bk, bn]
-    x = x_ref[...].astype(jnp.float32)                    # [bm, bk]
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # Nibbles sign-extend through i32 shifts (Mosaic legalizes neither
+    # i8 vector shifts nor the sublane interleave that would put rows 2r
+    # and 2r+1 back in contraction order). The interleave is not needed:
+    # x @ W = x[:, 0::2] @ W_lo + x[:, 1::2] @ W_hi, and the caller ships
+    # x already split by column parity.
+    p = w_ref[...].astype(jnp.int32)                      # [bk//2, bn]
+    lo = jnp.right_shift(jnp.left_shift(p, 28), 28)       # rows 2r
+    hi = jnp.right_shift(jnp.left_shift(p, 24), 28)       # rows 2r+1
+    sc = s_ref[...]
+
+    def dot(x_ref, w):
+        return jax.lax.dot_general(
+            x_ref[...].astype(jnp.float32), w.astype(jnp.float32) * sc,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    acc_ref[...] += dot(xe_ref, lo) + dot(xo_ref, hi)
 
     @pl.when(k == n_k - 1)
     def _fin():
@@ -112,21 +118,24 @@ def _pallas_matmul(x2, w_q, scale, rows, bits, bm, bn, bk, interpret):
         wp = jnp.pad(w_q, ((0, pad_k), (0, pad_n))) if (pad_k or pad_n) \
             else w_q
         kernel, w_rows_per_bk = _kernel_int8, bk
+        xs = [xp]
     else:
         # packed rows = K/2; zero nibbles dequantize to 0 so K padding is
         # safe (pad_k is even because bk is)
-        wp = jnp.pad(w_q, ((0, pad_k // 2), (0, pad_n))) \
-            if (pad_k or pad_n) else w_q
+        w_rows = (k_dim + pad_k) // 2
+        wp = jnp.pad(w_q, ((0, w_rows - w_q.shape[0]), (0, pad_n))) \
+            if (w_rows != w_q.shape[0] or pad_n) else w_q
         kernel, w_rows_per_bk = _kernel_int4, bk // 2
+        xs = [xp[:, 0::2], xp[:, 1::2]]
     sp = jnp.pad(scale.reshape(1, -1), ((0, 0), (0, pad_n))) if pad_n \
         else scale.reshape(1, -1)
     grid = ((m + pad_m) // bm, (n + pad_n) // bn, (k_dim + pad_k) // bk)
+    x_spec = pl.BlockSpec((bm, bk // len(xs)), lambda i, j, k: (i, k),
+                          memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(kernel, n_k=grid[2]),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k),
-                         memory_space=pltpu.VMEM),
+        in_specs=[x_spec] * len(xs) + [
             pl.BlockSpec((w_rows_per_bk, bn), lambda i, j, k: (k, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j),
@@ -136,10 +145,10 @@ def _pallas_matmul(x2, w_q, scale, rows, bits, bm, bn, bk, interpret):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m + pad_m, n + pad_n), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(xp, wp, sp)
+        interpret=interpret, name="dequant_matmul",
+    )(*xs, wp, sp)
     return out[:m, :n]
 
 

@@ -244,7 +244,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
                           scale=scale),
         out_shape=jax.ShapeDtypeStruct((t, hkv, group, d), q.dtype),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=interpret, name="ragged_paged_attention",
     )(*prefetch, qg, k_pages, v_pages)
     return out.reshape(t, hq, d)
 

@@ -78,7 +78,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_megakernel import _rms, _swap_matrix
+from .decode_megakernel import (_rms, _swap_matrix,
+                               whole_weight_vmem_limit)
 
 _NEG_INF = -1e30
 
@@ -721,7 +722,11 @@ def fused_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts, q_lens,
         )
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret, input_output_aliases=aliases,
+            interpret=interpret, name="fused_prefill_layer",
+            input_output_aliases=aliases,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=whole_weight_vmem_limit(
+                    operands[:-2])),     # all but the HBM-paged pools
         )(*prefetch, *operands)
         return out
 

@@ -77,7 +77,7 @@ def _run_fwd(x2, w, eps, block_rows, interpret):
             jax.ShapeDtypeStruct((r, f), x2.dtype),
             jax.ShapeDtypeStruct((r, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="rms_norm_fwd",
     )(x2, w.reshape(1, f))
 
 
@@ -103,7 +103,7 @@ def _run_bwd(x2, w, rstd, dy2, block_rows, interpret):
             jax.ShapeDtypeStruct((1, f), w.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="rms_norm_bwd",
     )(x2, w.reshape(1, f), rstd, dy2)
 
 

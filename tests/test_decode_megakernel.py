@@ -1098,6 +1098,13 @@ def test_megakernel_mode_reports_jnp_after_tripped_fallback(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("simulated pallas lowering failure")
     monkeypatch.setattr(dm.pl, "pallas_call", boom)
+    # the reroute is opt-in: by default a refused kernel fails loudly
+    assert GLOBAL_FLAGS.get("enable_fusion_fallback") is False
+    with pytest.raises(RuntimeError, match="simulated pallas"):
+        fused_decode_layer(layer, h, Kp, Vp, tbls, kv_lens,
+                           self_kv=True, interpret=True, **kw)
+    assert not megakernel_fallback_tripped()
+    GLOBAL_FLAGS.set("enable_fusion_fallback", True)
     try:
         out = fused_decode_layer(layer, h, Kp, Vp, tbls, kv_lens,
                                  self_kv=True, interpret=True, **kw)
@@ -1108,12 +1115,9 @@ def test_megakernel_mode_reports_jnp_after_tripped_fallback(monkeypatch):
         assert megakernel_fallback_tripped()
         assert megakernel_mode() == "jnp"
         # with the fallback flag off, the trip is not a reroute promise
-        old = GLOBAL_FLAGS.get("enable_fusion_fallback")
-        try:
-            GLOBAL_FLAGS.set("enable_fusion_fallback", False)
-            assert megakernel_mode() == "interpret"
-        finally:
-            GLOBAL_FLAGS.set("enable_fusion_fallback", old)
+        GLOBAL_FLAGS.set("enable_fusion_fallback", False)
+        assert megakernel_mode() == "interpret"
     finally:
+        GLOBAL_FLAGS.set("enable_fusion_fallback", False)
         reset_megakernel_fallback()
     assert megakernel_mode() == "interpret"

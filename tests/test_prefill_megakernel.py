@@ -30,6 +30,8 @@ models/generation.py, serving/engine.py):
   rollback path, and a runtime Pallas failure reroutes through
   ``FLAGS_enable_fusion_fallback`` with the mode reporting ``jnp``.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -303,23 +305,27 @@ def test_prefill_mode_reports_jnp_after_tripped_fallback(monkeypatch):
         def __getattr__(self, name):
             return getattr(real_pl, name)
     monkeypatch.setattr(pm, "pl", _Shim())
+    call = functools.partial(
+        fused_prefill_layer, fused, h, Kp, Vp, tbls, pre, q_starts,
+        q_lens, kv_lens, interpret=True, attn_interpret=True, **kw)
+    # the reroute is opt-in: by default a refused kernel fails loudly
+    assert GLOBAL_FLAGS.get("enable_fusion_fallback") is False
+    with pytest.raises(RuntimeError, match="simulated pallas"):
+        call()
+    assert not prefill_fallback_tripped()
+    GLOBAL_FLAGS.set("enable_fusion_fallback", True)
     try:
-        out = fused_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts,
-                                  q_lens, kv_lens, interpret=True,
-                                  attn_interpret=True, **kw)
+        out = call()
         # the fallback still computed the right answer...
         np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
                                    rtol=1e-5, atol=1e-5)
         # ...and the mode now admits the reroute
         assert prefill_fallback_tripped()
         assert prefill_megakernel_mode(fused) == "jnp"
-        old = GLOBAL_FLAGS.get("enable_fusion_fallback")
-        try:
-            GLOBAL_FLAGS.set("enable_fusion_fallback", False)
-            assert prefill_megakernel_mode(fused) == "interpret"
-        finally:
-            GLOBAL_FLAGS.set("enable_fusion_fallback", old)
+        GLOBAL_FLAGS.set("enable_fusion_fallback", False)
+        assert prefill_megakernel_mode(fused) == "interpret"
     finally:
+        GLOBAL_FLAGS.set("enable_fusion_fallback", False)
         reset_prefill_fallback()
     assert prefill_megakernel_mode(fused) == "interpret"
 

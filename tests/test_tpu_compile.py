@@ -1,0 +1,252 @@
+"""The main path's Pallas kernels, compiled for a DESCRIBED v5e at
+TinyLlama-1.1B widths (hidden 2048, 32 q / 4 kv heads, head_dim 64, ffn
+5632, page 16) — rehearsal 3 of the on-chip-measurement guide kept as
+tests, so what the chip's compiler refuses fails here, at no chip time.
+
+Nothing runs: a compile that passes is not a chip run. This is the only
+test file that describes a topology, and it does so inside a
+module-scoped fixture (never at import: one process at a time may load
+the TPU's library, and every xdist worker imports every test file).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# TinyLlama-1.1B geometry
+D, H, HKV, DH, FFN, PS = 2048, 32, 4, 64, 5632, 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without a chip: keep these off it, and
+    # compile at the chip path's matmul precision, not conftest's
+    # "highest"
+    saved_cache = jax.config.jax_enable_compilation_cache
+    saved_prec = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_default_matmul_precision", saved_prec)
+        jax.config.update("jax_enable_compilation_cache", saved_cache)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The kernels' entry points ask the shared backend probe and take
+    their jnp branch on this host; steer it here, in the test."""
+    import paddle_tpu.kernels as K
+    monkeypatch.setattr(K, "_ON_TPU", True)
+    monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS", raising=False)
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _s(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---- one builder per kernel: returns (fn, shapes, expected custom calls)
+
+def _flash(dh, heads, bwd):
+    from paddle_tpu.kernels.flash_attention import flash_attention
+    q, kv = _s((1, heads, 2048, dh)), _s((1, HKV, 2048, dh))
+    if not bwd:
+        return (lambda q, k, v: flash_attention(q, k, v, causal=True),
+                (q, kv, kv), 1)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True) \
+            .astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), 3
+
+
+def _rms_norm():
+    from paddle_tpu.kernels.rms_norm import rms_norm
+
+    def loss(x, w):
+        return rms_norm(x, w).astype(jnp.float32).sum()
+    return (jax.value_and_grad(loss, argnums=(0, 1)),
+            (_s((2048, D)), _s((D,))), 2)
+
+
+def _pool(num_pages, dtype=jnp.bfloat16):
+    return _s((HKV, num_pages, PS, DH), dtype)
+
+
+def _paged():
+    from paddle_tpu.kernels.paged_attention import paged_attention
+    R, pages, pps = 8, 1024, 128
+    return (lambda q, k, v, t, l: paged_attention(q, k, v, t, l),
+            (_s((R, H, DH)), _pool(pages), _pool(pages),
+             _s((R, pps), jnp.int32), _s((R,), jnp.int32)), 1)
+
+
+def _ragged(T, q_block, int8_kv):
+    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+    R, pages, pps = 8, 1024, 128
+    i32 = jnp.int32
+    shapes = [_s((T, H, DH)),
+              _pool(pages, jnp.int8 if int8_kv else jnp.bfloat16),
+              _pool(pages, jnp.int8 if int8_kv else jnp.bfloat16),
+              _s((R, pps), i32), _s((R,), i32), _s((R,), i32),
+              _s((R,), i32)]
+    if int8_kv:
+        shapes += [_s((HKV, pages), jnp.float32)] * 2
+
+        def fn(q, k, v, t, qs, ql, kl, ks, vs):
+            return ragged_paged_attention(q, k, v, t, qs, ql, kl,
+                                          q_block=q_block, k_scales=ks,
+                                          v_scales=vs)
+    else:
+        def fn(q, k, v, t, qs, ql, kl):
+            return ragged_paged_attention(q, k, v, t, qs, ql, kl,
+                                          q_block=q_block)
+    return fn, tuple(shapes), 1
+
+
+def _adamw(n, dtype):
+    from paddle_tpu.kernels.fused_adamw import fused_adamw
+    f32 = jnp.float32
+
+    def fn(p, g, m, v, lr, t):
+        return fused_adamw(p, g, m, v, lr, t, weight_decay=0.01)
+    return fn, (_s((n,), dtype), _s((n,), dtype), _s((n,), f32),
+                _s((n,), f32), _s((), f32), _s((), jnp.int32)), 1
+
+
+def _dequant(bits):
+    from paddle_tpu.kernels.int8_matmul import dequant_matmul
+    rows = D // 2 if bits == 4 else D
+
+    def fn(x, w, s):
+        return dequant_matmul(x, w, s, rows=D, bits=bits)
+    return fn, (_s((8, D)), _s((rows, FFN), jnp.int8),
+                _s((1, FFN), jnp.float32)), 1
+
+
+# a head_dim-128 toy geometry the megakernels' whole-matrix-in-VMEM and
+# lane-reshape design does fit: pins what IS legal (the row tiling)
+# apart from what the TinyLlama widths still trip
+SMALL = dict(d=512, h=4, hkv=2, dh=128, ffn=1024)
+TINYLLAMA = dict(d=D, h=H, hkv=HKV, dh=DH, ffn=FFN)
+
+
+def _layer_shapes(d, h, hkv, dh, ffn):
+    w = {"q": (d, h * dh), "k": (d, hkv * dh), "v": (d, hkv * dh),
+         "o": (h * dh, d), "gate": (d, ffn), "up": (d, ffn),
+         "down": (ffn, d)}
+    layer = {k: _s(v) for k, v in w.items()}
+    layer["ln1"] = _s((d,))
+    layer["ln2"] = _s((d,))
+    return layer
+
+
+def _decode_layer(d, h, hkv, dh, ffn):
+    from paddle_tpu.kernels.decode_megakernel import fused_decode_layer
+    R, pages, pps = 8, 1024, 128
+    pool = _s((hkv, pages, PS, dh))
+
+    def fn(layer, hid, kp, vp, tbl, kl):
+        return fused_decode_layer(layer, hid, kp, vp, tbl, kl, eps=1e-5,
+                                  theta=10000.0, num_heads=h)
+    return fn, (_layer_shapes(d, h, hkv, dh, ffn), _s((R, d)), pool, pool,
+                _s((R, pps), jnp.int32), _s((R,), jnp.int32)), 1
+
+
+def _decode_model(d, h, hkv, dh, ffn):
+    from paddle_tpu.kernels.decode_megakernel import fused_decode_model
+    L, R, pages, pps = 2, 8, 1024, 128
+    layers = jax.tree.map(lambda s: _s((L,) + s.shape, s.dtype),
+                          _layer_shapes(d, h, hkv, dh, ffn))
+    pool = _s((L, hkv, pages, PS, dh))
+
+    def fn(layers, hid, kp, vp, tbl, kl):
+        return fused_decode_model(
+            layers, hid, kp, vp, tbl, kl, eps=1e-5, theta=10000.0,
+            num_heads=h, append_fn=lambda Kp, Vp, k, v: (Kp, Vp))
+    return fn, (layers, _s((R, d)), pool, pool, _s((R, pps), jnp.int32),
+                _s((R,), jnp.int32)), 1
+
+
+def _prefill_layer(d, h, hkv, dh, ffn, model_scope=False):
+    from paddle_tpu.kernels.prefill_megakernel import (
+        fuse_layer_weights, fused_prefill_layer, fused_prefill_model,
+        ragged_prologue)
+    T, R, pages, pps, qb, L = 128, 8, 1024, 64, 8, 2
+    i32 = jnp.int32
+    layer, pool = _layer_shapes(d, h, hkv, dh, ffn), _s((hkv, pages, PS, dh))
+    if model_scope:
+        layer, pool = jax.tree.map(
+            lambda s: _s((L,) + s.shape, s.dtype), (layer, pool))
+
+    def fn(layer, hid, kp, vp, pos, tbl, qs, ql, kl):
+        pre = ragged_prologue(pos, tbl, qs, ql, theta=10000.0,
+                              head_dim=dh, page_size=PS, max_pages=pps,
+                              q_block=qb)
+        kw = dict(eps=1e-5, num_heads=h, q_block=qb)
+        if model_scope:
+            fused = jax.vmap(fuse_layer_weights)(layer)
+            return fused_prefill_model(fused, hid, kp, vp, tbl, pre, qs,
+                                       ql, kl, **kw)
+        return fused_prefill_layer(fuse_layer_weights(layer), hid, kp, vp,
+                                   tbl, pre, qs, ql, kl, **kw)
+    return fn, (layer, _s((1, T, d)), pool, pool, _s((T,), i32),
+                _s((R, pps), i32), _s((R,), i32), _s((R,), i32),
+                _s((R,), i32)), 1
+
+
+CASES = {
+    "flash_fwd_d64": lambda: _flash(DH, H, bwd=False),
+    "flash_fwd_bwd_d64": lambda: _flash(DH, H, bwd=True),
+    "flash_fwd_bwd_d128": lambda: _flash(128, 16, bwd=True),
+    "rms_norm_fwd_bwd": _rms_norm,
+    "paged_attention_r8": _paged,
+    "ragged_t512_qb128_bf16": lambda: _ragged(512, 128, False),
+    "ragged_t64_qb8_bf16": lambda: _ragged(64, 8, False),
+    "ragged_t512_qb128_int8kv": lambda: _ragged(512, 128, True),
+    "ragged_t64_qb8_int8kv": lambda: _ragged(64, 8, True),
+    "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
+    "fused_adamw_bf16": lambda: _adamw(11_534_336, jnp.bfloat16),
+    "dequant_matmul_int8": lambda: _dequant(8),
+    "dequant_matmul_int4": lambda: _dequant(4),
+    "fused_decode_layer_r8_small": lambda: _decode_layer(**SMALL),
+    "fused_decode_model_r8_small": lambda: _decode_model(**SMALL),
+    "fused_prefill_layer_t128_small": lambda: _prefill_layer(**SMALL),
+    "fused_decode_layer_r8": lambda: _decode_layer(**TINYLLAMA),
+    "fused_decode_model_r8": lambda: _decode_model(**TINYLLAMA),
+    "fused_prefill_layer_t128": lambda: _prefill_layer(**TINYLLAMA),
+    "fused_prefill_model_t128": lambda: _prefill_layer(
+        **TINYLLAMA, model_scope=True),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, on_tpu):
+    fn, shapes, n_calls = CASES[name]()
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert text.count("tpu_custom_call") >= n_calls, (
+        f"{name}: expected >= {n_calls} tpu_custom_call in the compiled "
+        f"text, found {text.count('tpu_custom_call')}")

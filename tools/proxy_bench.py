@@ -1,8 +1,8 @@
 """CPU-tier proxy perf bench: chip-free regression gate over the
 counted perf surfaces.
 
-The flagship bench (bench.py) needs a live chip for tok/s and MFU — and
-the chip pool can wedge for days (BENCH_r03-r05 are stale fallbacks).
+The flagship bench (bench.py) needs a live chip for tok/s and MFU
+(BENCH_r03-r05 hold none: they are stale fallbacks).
 This harness runs the measurements that DON'T need a chip and are
 (near-)deterministic counts rather than timings:
 
