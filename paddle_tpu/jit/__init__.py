@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from ..core import autograd as _ag
 from ..core import random as _rng
 from ..core.tensor import Tensor
+from ..profiler.spans import span
 
 
 def _collect_state(layer):
@@ -506,6 +507,16 @@ class TrainStep:
                 self.optimizer._state[id(p)] = st
 
     def __call__(self, *batch):
+        """One optimizer step. The call is the span ``train.step`` (the
+        device works on past its end), cut into ``train.gather``
+        (everything before the jitted call) and ``train.dispatch``, or
+        ``train.compile`` on a specialization's first call
+        (profiler/spans.py)."""
+        with span("train.step") as sp:
+            return self._step(sp, batch)
+
+    def _step(self, sp, batch):
+        sp.phase("train.gather")
         from ..core.flags import GLOBAL_FLAGS
         from ..io.prefetch import PIPELINE_METRICS
         from ..nn.scan_stack import remat_policy_scope, effective_remat_policy
@@ -742,6 +753,7 @@ class TrainStep:
             # recompile (shape change, remat/flag flip) is visible next
             # to the pipeline gauges instead of reading as one slow step.
             from ..profiler import compile_event
+            sp.phase(None)
             shard_ctx = (_gspmd.partitioning_scope(self._mesh,
                                                    zero=shard_cfg.zero)
                          if shard_cfg is not None else nullcontext())
@@ -768,15 +780,17 @@ class TrainStep:
                 except Exception:
                     self.last_hlo_text = None
                     self.last_hlo_collectives = None
-            with policy_ctx, shard_ctx, pipe_ctx, compile_event(
-                    f"TrainStep(K={K},remat={remat})") as ev:
+            with policy_ctx, shard_ctx, pipe_ctx, \
+                    compile_event("train.compile") as ev:
                 out = self._cache[key](*args)
             self._compiled_keys.add(key)
             self.last_compile_ms = ev.ms
             self.compile_ms_total += ev.ms
         else:
+            sp.phase("train.dispatch")
             with policy_ctx:
                 out = self._cache[key](*args)
+            sp.phase(None)
         if donate_batch:
             for b in batch:
                 # buffer handed to XLA: mark so a reuse raises our error

@@ -8,8 +8,14 @@ cuda_tracer.cc; chrome-trace export chrometracing_logger.cc; stats tables
 profiler_statistic.py).
 
 Mapping onto this stack:
-- host spans -> the native C++ event recorder (core/native/csrc/profiler.cc)
-  with per-op hooks in the eager dispatch;
+- the program's own spans -> ``spans.span`` (profiler/spans.py): the one
+  span primitive, always on; each span goes to ``jax.profiler``'s
+  timeline (the clock of the device lines) and to one bounded in-memory
+  log. ``compile_event`` is a thin wrapper over it;
+- per-op host spans -> the native C++ event recorder
+  (core/native/csrc/profiler.cc) with per-op hooks in the eager dispatch,
+  on only while a ``Profiler`` records; ``compile_event`` and the user's
+  ``RecordEvent`` feed it too;
 - device side -> jax.profiler (XLA xplane; the TPU equivalent of CUPTI),
   started/stopped alongside when ``targets`` includes ProfilerTarget.TPU;
 - export -> chrome://tracing JSON (host) + TensorBoard xplane dir (device);
@@ -27,11 +33,14 @@ from __future__ import annotations
 
 import enum
 import os
-import time
 from collections import defaultdict
+
+from jax.profiler import TraceAnnotation
 
 from ..core import dispatch as _dispatch
 from ..core import native as _nv
+from . import spans
+from .spans import span
 
 
 class ProfilerTarget(enum.Enum):
@@ -71,16 +80,27 @@ def make_scheduler(*, closed=0, ready=0, record=1, repeat=0, skip_first=0):
 
 
 class RecordEvent:
-    """User span (reference: paddle.profiler.RecordEvent)."""
+    """User span (reference: paddle.profiler.RecordEvent), on both
+    timelines: ``jax.profiler``'s, beside the device lines, while a trace
+    runs, and the native recorder's while a ``Profiler`` records. Free
+    otherwise, and not in the span log: that ring is sized for the
+    program's own ``serve.*`` / ``train.*`` spans, which a user's loop
+    of events must not push out."""
 
     def __init__(self, name, event_type=None):
         self.name = name
+        self._ann = None
         self._tok = 0
 
     def begin(self):
         self._tok = _nv.prof_begin(self.name, 2)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
 
     def end(self):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         _nv.prof_end(self._tok)
 
     def __enter__(self):
@@ -250,8 +270,8 @@ def export_chrome_tracing(dir_name, worker_name=None):
 
 
 class compile_event:
-    """Span marking a compilation (trace + lower + build) on the host
-    timeline, named ``compile:<what>``.
+    """A :func:`spans.span` marking a compilation (trace + lower +
+    build) that also feeds the native recorder, under the one ``name``.
 
     Used by ``jit.TrainStep`` around each first-call trace so recompiles
     caused by shape / flag changes show up next to the pipeline gauges
@@ -260,25 +280,27 @@ class compile_event:
     synchronous through tracing/lowering; execution stays async, so the
     span measures compilation, not the step)."""
 
-    def __init__(self, what):
-        self.name = f"compile:{what}"
+    def __init__(self, name):
+        self.name = name
         self.ms = None
+        self._span = span(name)
         self._tok = 0
-        self._t0 = 0.0
 
     def __enter__(self):
         self._tok = _nv.prof_begin(self.name, 2)
-        self._t0 = time.perf_counter()
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._span.end()
+        self.ms = (self._span.t1_ns - self._span.t0_ns) / 1e6
         _nv.prof_end(self._tok)
         return False
 
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
-           "make_scheduler", "export_chrome_tracing", "compile_event"]
+           "make_scheduler", "export_chrome_tracing", "compile_event",
+           "span", "spans"]
 
 
 class SortedKeys(enum.Enum):

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 from ..models.generation import (_logits, _rms_norm, _rope, _wmat,
                                  extract_params, request_keys, sample_rows)
 from ..kernels.paged_attention import ragged_paged_attention
+from ..profiler import spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 from .metrics import ServingMetrics
 from .scheduler import Scheduler, SchedulerConfig, Sequence, SequenceStatus
@@ -479,6 +480,12 @@ class LLMEngine:
         #: replica id under a ClusterEngine (fleet flight entries carry
         #: it); None for a standalone engine
         self.engine_id = engine_id
+        #: always-on spans (profiler/spans.py): the tag by which
+        #: ``metrics_snapshot()["spans"]`` tells a replica's step and
+        #: request spans from its neighbours', and each request's open
+        #: life span (``serve.queue`` or ``serve.prefill``)
+        self._span_tags = {} if engine_id is None else {"engine": engine_id}
+        self._life = {}
         # a failing pool audit raises InvariantViolation WITH the
         # flight recorder's last-N context attached (kv_cache.py reads
         # these back-references at raise time; the counter keeps
@@ -1207,6 +1214,7 @@ class LLMEngine:
         self._seqs[rid] = seq
         self._outputs[rid] = RequestOutput(rid, prompt)
         self.metrics.requests_added.inc()
+        self._life_span(seq, "serve.queue")
         self._trace(rid, "enqueue", t=now, prompt_len=len(prompt),
                     max_new_tokens=int(max_new_tokens))
         return rid
@@ -1244,6 +1252,7 @@ class LLMEngine:
             self._draft.drop(request_id)
         if self.adapters is not None and seq.adapter_id not in (0, None):
             self.adapters.release(seq.adapter_id)
+        self._life_span(seq, None)
         del self._seqs[request_id]
         del self._outputs[request_id]
         return True
@@ -1278,6 +1287,7 @@ class LLMEngine:
             self._draft.drop(request_id)
         if self.adapters is not None and seq.adapter_id not in (0, None):
             self.adapters.release(seq.adapter_id)
+        self._life_span(seq, None)
         del self._seqs[request_id]
         del self._outputs[request_id]
         self.flight.record("handoff_out", self._now(), request=request_id,
@@ -1349,6 +1359,7 @@ class LLMEngine:
         seq.tokens = list(payload["tokens"])
         seq.cached_len = cached_len
         seq.first_token_at = payload["first_token_at"]
+        self._life_span(seq, "serve.queue")
         self._seqs[rid] = seq
         self._outputs[rid] = RequestOutput(
             rid, list(seq.prompt_ids), token_ids=list(seq.tokens),
@@ -1392,6 +1403,19 @@ class LLMEngine:
         if self.tracer is not None:
             self.tracer.span(rid, kind, self._now() if t is None else t,
                              **detail)
+
+    def _life_span(self, seq, name):
+        """Move the request to the next span of its life: end the one it
+        has open (``serve.queue`` or ``serve.prefill``) and begin
+        ``name`` at the same instant (None: nothing follows). Log only,
+        these cross steps (profiler/spans.py)."""
+        now_ns = time.perf_counter_ns()
+        cur = self._life.pop(seq.seq_id, None)
+        if cur is not None:
+            cur.end(now_ns)
+        if name is not None:
+            self._life[seq.seq_id] = spans.begin(
+                name, now_ns, request=seq.seq_id, **self._span_tags)
 
     def record_fleet_event(self, kind, **detail):
         """Engine-scope event onto the flight recorder (always) and the
@@ -1518,6 +1542,13 @@ class LLMEngine:
                 - m.adapter_evict_refusals.value)
             m.adapter_slots_used.set(self.adapters.slots_used)
         snap = self.metrics.snapshot()
+        # the step's phases and the requests' waits over the newest
+        # records of the always-on span log (profiler/spans.py): a few
+        # hundred steps, 2 ms at most of a snapshot once the log is
+        # full; replicas of one process share the log, each reads its
+        # own by its tag
+        snap["spans"] = spans.summary(prefix="serve.", last=2048,
+                                      **self._span_tags)
         snap["decode_cache_size"] = self.decode_cache_size()
         snap["burst_tokens"] = self.burst_tokens
         # tensor-parallel forensics: 1 = single-device engine
@@ -1601,8 +1632,19 @@ class LLMEngine:
         one host dispatch); otherwise it is one ragged step (decode
         steps and prefill chunks interleaved). Returns the
         RequestOutputs touched this step (admitted, token streamed,
-        finished, shed, or preempted)."""
+        finished, shed, or preempted).
+
+        The step is the span ``serve.step``, cut into the phases
+        ``serve.plan`` / ``.assemble`` / ``.dispatch`` / ``.wait`` /
+        ``.commit`` (``.draft`` on a speculative round); what it carried
+        is counted on it where the work happens (profiler/spans.py,
+        docs/OBSERVABILITY.md)."""
+        with spans.span("serve.step", **self._span_tags) as sp:
+            return self._step(sp)
+
+    def _step(self, sp):
         touched = {}
+        sp.phase("serve.plan")
         if self._tiered:
             # advance the pool's virtual round clock FIRST: a restore
             # this step claims at clock c, so a prefetch issued at the
@@ -1633,6 +1675,9 @@ class LLMEngine:
         hook = self._prefix_probe if self.prefix_caching else None
         for seq in self.scheduler.admit(prefix_hook=hook):
             touched[seq.seq_id] = self._sync_output(seq)
+            # a row preempted after its first token re-prefills unseen
+            self._life_span(
+                seq, "serve.prefill" if seq.first_token_at is None else None)
             if self.tracer is not None:
                 now = self._now()
                 extra = {} if seq.tenant_id is None \
@@ -1670,10 +1715,11 @@ class LLMEngine:
             self._trace(t.seq_id, "preempt",
                         num_preemptions=t.num_preemptions)
             self.flight.record("preempt", self._now(), request=t.seq_id)
+            self._life_span(t, "serve.queue")
         if splan is not None:
             if splan.cow_copies:
                 self.metrics.cow_copies.inc(splan.cow_copies)
-            if self._launch_spec(splan, touched):
+            if self._launch_spec(splan, touched, sp):
                 self.metrics.decode_steps.inc()
                 self.metrics.ragged_pad_fraction.set(splan.pad_fraction)
             else:
@@ -1682,15 +1728,17 @@ class LLMEngine:
                 # to an ordinary decode round — target pressure
                 # preempts, draft pressure must never kill the loop
                 splan = None
+                sp.phase("serve.plan")
                 plan = self.scheduler.prepare_step()
                 for t in self.scheduler.last_preempted:
                     self._draft.drop(t.seq_id)
                     self._sync_output(t)
                     touched[t.seq_id] = self._outputs[t.seq_id]
+                    self._life_span(t, "serve.queue")
         if splan is None and bplan is not None:
             if bplan.cow_copies:
                 self.metrics.cow_copies.inc(bplan.cow_copies)
-            self._launch_burst(bplan, touched)
+            self._launch_burst(bplan, touched, sp)
             self.metrics.decode_steps.inc()
             # pad fraction is a ragged-packing concept; zero it so the
             # gauge never freezes on a stale prefill step's value while
@@ -1699,7 +1747,8 @@ class LLMEngine:
         elif plan is not None:
             if plan.cow_copies:
                 self.metrics.cow_copies.inc(plan.cow_copies)
-            sampled, _, finite = self._launch(plan)
+            sampled, _, finite = self._launch(plan, sp)
+            sp.phase("serve.commit")
             step_prefill_rows = 0
             for i, (seq, q_start, q_len) in enumerate(plan.rows):
                 if not finite[i]:
@@ -1719,6 +1768,9 @@ class LLMEngine:
                 if q_len > 1 or before < len(seq.prompt_ids):
                     self.metrics.prefill_chunks.inc()
                     step_prefill_rows += 1
+                    life = self._life.get(seq.seq_id)
+                    if life is not None:
+                        life.set(chunks=life.attrs.get("chunks", 0) + 1)
                 if self.prefix_caching and \
                         before < len(seq.prompt_ids) <= seq.cached_len:
                     self._register_prefix(seq)
@@ -1747,6 +1799,8 @@ class LLMEngine:
                 # number of prefill-chunk rows is ONE prefill launch
                 self.metrics.prefill_launches.inc()
             self.metrics.ragged_pad_fraction.set(plan.pad_fraction)
+        if self._tiered or self.tenant_policy is not None:
+            sp.phase("serve.commit")
         if self._tiered:
             # cursor-ahead prefetch: issue background staging for the
             # parked sequences the NEXT admission round will restore —
@@ -1779,6 +1833,8 @@ class LLMEngine:
                         seq.tenant_id, seq.cached_len * bpt * dt)
                     if seq.adapter_slot:
                         self.tenant_policy.charge_slot(seq.tenant_id, dt)
+        # what is left of the step is serve.step's own time
+        sp.phase(None)
         self.metrics.record_step(self.scheduler, self.pool)
         # one O(1) flight-recorder entry per step: the bounded last-N
         # context a post-mortem dump replays (ints only — cheap and
@@ -1790,6 +1846,8 @@ class LLMEngine:
         if self.engine_id is not None:
             f["engine"] = self.engine_id
         self.flight.record("step", self._now(), **f)
+        sp.set(used_pages=f["used_pages"], num_pages=self.pool.capacity,
+               max_num_seqs=self.max_num_seqs)
         return list(touched.values())
 
     def run(self, max_steps=None):
@@ -2138,15 +2196,22 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # internals
     # ------------------------------------------------------------------
-    def _launch(self, plan, draft_tokens=None, draft_probs=None):
+    def _launch(self, plan, sp, draft_tokens=None, draft_probs=None):
         """Assemble the fixed-shape operands for the plan and run the one
         ragged-step executable. Returns ``(out [R, K+1], n_out [R],
         finite [R])`` — ordinary rounds commit ``out[i, 0]`` (n_out is
         1), speculative rounds commit ``out[i, :n_out[i]]``; a row with
         ``finite[i] == False`` produced NaN/Inf logits and must be
-        aborted instead of committed (the in-graph isfinite guard)."""
+        aborted instead of committed (the in-graph isfinite guard).
+
+        ``sp`` is the step's span: the launch is its phases
+        ``serve.assemble`` (numpy operands), ``serve.dispatch`` (the
+        puts and the call, until it returns) and ``serve.wait`` (the
+        host blocked on the tokens), and what the step carries is
+        counted on it from the operands built here."""
         T, R, PPS = plan.token_budget, plan.num_slots, self.max_pages_per_seq
         K = self.spec_tokens
+        sp.phase("serve.assemble")
         self.metrics.host_dispatches.inc()
         if not self._step_launched:
             self._step_launched = True
@@ -2171,6 +2236,7 @@ class LLMEngine:
             # below — every row has spec == 0)
             draft_tokens, draft_probs = self._zero_draft
         specs = plan.spec_lens
+        prefill_tokens = 0
         for i, (seq, q_start, q_len) in enumerate(plan.rows):
             ids = seq.all_ids
             lo = seq.cached_len
@@ -2182,6 +2248,9 @@ class LLMEngine:
                                         draft_tokens[i, :spec]]
             else:
                 row_toks = ids[lo:lo + q_len]
+                if q_len > 1 or lo < len(seq.prompt_ids):
+                    # a prefill-chunk row, as step() counts them
+                    prefill_tokens += q_len
             tokens[q_start:q_start + q_len] = row_toks
             positions[q_start:q_start + q_len] = np.arange(lo, lo + q_len)
             tbls[i] = self.pool.padded_block_table(seq.seq_id, PPS)
@@ -2199,6 +2268,11 @@ class LLMEngine:
             spec_lens[i] = spec
             if slot_ids is not None and seq.adapter_slot:
                 slot_ids[q_start:q_start + q_len] = seq.adapter_slot
+        sp.set(rows=len(plan.rows), prefill_tokens=prefill_tokens,
+               decode_tokens=int(q_lens.sum()) - prefill_tokens,
+               # what attention must read: every row's context
+               live_kv_tokens=int(kv_lens.sum()))
+        sp.phase("serve.dispatch")
         out, n_out, finite, new_kv, new_scales = self._ragged_jit(
             self._ragged_params, self.pool.kv, self.pool.kv_scales,
             jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tbls),
@@ -2213,9 +2287,10 @@ class LLMEngine:
         self.pool.kv = new_kv
         if new_scales is not None:
             self.pool.kv_scales = new_scales
+        sp.phase("serve.wait")
         return np.asarray(out), np.asarray(n_out), np.asarray(finite)
 
-    def _launch_spec(self, plan, touched):
+    def _launch_spec(self, plan, touched, sp):
         """One speculative round: draft sync + k proposal steps, then
         ONE target launch verifying every row's k+1 positions through
         the ordinary ragged executable. Accepted tokens commit through
@@ -2227,6 +2302,7 @@ class LLMEngine:
         R = self.max_num_seqs
         seqs = [seq for seq, _, _ in plan.rows]
         spec_lens = plan.spec_lens
+        sp.phase("serve.draft")
         try:
             self._draft.sync(seqs)
             d_toks, d_probs = self._draft.propose(seqs, spec_lens, K)
@@ -2246,7 +2322,8 @@ class LLMEngine:
         # buffer); d_probs is already the [R, K, V] DEVICE operand
         draft_tokens = np.zeros((R, K), np.int32)
         draft_tokens[:len(seqs)] = d_toks
-        out, n_out, finite = self._launch(plan, draft_tokens, d_probs)
+        out, n_out, finite = self._launch(plan, sp, draft_tokens, d_probs)
+        sp.phase("serve.commit")
         drafted = accepted = rollbacks = 0
         for i, (seq, _q_start, _q_len) in enumerate(plan.rows):
             if not finite[i]:
@@ -2288,7 +2365,7 @@ class LLMEngine:
                                    / m.spec_drafted_tokens.value)
         return True
 
-    def _launch_burst(self, bplan, touched):
+    def _launch_burst(self, bplan, touched, sp):
         """Assemble the fixed-shape burst operands and run the
         on-device token loop: ONE host dispatch for up to
         ``burst_tokens`` tokens per row. The host then replays the
@@ -2296,6 +2373,7 @@ class LLMEngine:
         callbacks, EOS/length finalization, prefix registration) and
         re-syncs the pool's committed lengths."""
         R, PPS = self.max_num_seqs, self.max_pages_per_seq
+        sp.phase("serve.assemble")
         tokens = np.zeros((R,), np.int32)
         kv_lens = np.zeros((R,), np.int32)
         tbls = np.full((R, PPS), NULL_PAGE, np.int32)
@@ -2327,6 +2405,7 @@ class LLMEngine:
             # rides the same forensics counter as the ragged step's
             self._burst_launched = True
             self.metrics.decode_compiles.inc()
+        sp.phase("serve.dispatch")
         out, gen, ok, new_kv, new_scales = self._burst_jit(
             self._step_params, self.pool.kv, self.pool.kv_scales,
             jnp.asarray(tokens), jnp.asarray(kv_lens), jnp.asarray(tbls),
@@ -2337,9 +2416,16 @@ class LLMEngine:
         self.pool.kv = new_kv
         if new_scales is not None:
             self.pool.kv_scales = new_scales
+        sp.phase("serve.wait")
         out = np.asarray(out)
         gen = np.asarray(gen)
         ok = np.asarray(ok)
+        sp.phase("serve.commit")
+        # a burst reads each row's context again for every token of it:
+        # the context is of its first iteration, the tokens of all
+        sp.set(rows=len(bplan.rows), prefill_tokens=0,
+               decode_tokens=int(gen.sum()),
+               live_kv_tokens=int(kv_lens.sum()))
         for i, (seq, cap) in enumerate(bplan.rows):
             if not ok[i]:
                 # the row went non-finite at some loop iteration: every
@@ -2370,6 +2456,7 @@ class LLMEngine:
             # host boundary, so a burst's tokens share this timestamp —
             # latency quantizes to burst length by design (docs/BENCH.md)
             seq.first_token_at = self._now()
+            self._life_span(seq, None)
         self.metrics.tokens_generated.inc()
         if self.tenant_policy is not None:
             self.tenant_policy.charge_tokens(seq.tenant_id, 1)
@@ -2412,6 +2499,9 @@ class LLMEngine:
         }[status])
         out = self._sync_output(seq)
         out.finish_reason = reason or status
+        if seq.seq_id in self._life:
+            # left before its first token: shed, cancelled, aborted
+            self._life_span(seq, None)
         if self.tracer is not None:
             # terminal span: kind encodes the lifecycle exit so the
             # breakdown/post-mortem can branch without string-matching

@@ -26,6 +26,8 @@ from paddle_tpu.loadgen import (ClusterDriver, Driver, TraceRequest,
                                 build_cluster_report, build_report,
                                 report_json)
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config
+from paddle_tpu.profiler import spans as program_spans
+from paddle_tpu.profiler.spans import span
 from paddle_tpu.serving import (ClusterEngine, FaultEvent, FaultSchedule,
                                 FlightRecorder, InvariantViolation,
                                 LLMEngine, RequestTracer,
@@ -46,6 +48,17 @@ def _engine(model, clock, **kw):
     kw.setdefault("page_size", 4)
     kw.setdefault("seed", 0)
     return LLMEngine(model, now_fn=clock.now, **kw)
+
+
+def _span_mark():
+    """An id below every span the program records from here on."""
+    with span("test.mark") as m:
+        pass
+    return m.id
+
+
+def _spans_since(mark, name):
+    return [r for r in program_spans.records(name) if r.id > mark]
 
 
 def _spec(**kw):
@@ -131,7 +144,14 @@ def test_tracing_adds_no_compiles_and_no_dispatches(tiny_model):
     def run(tracer):
         clock = VirtualClock()
         eng = _engine(tiny_model, clock, tracer=tracer)
+        mark = _span_mark()
         Driver(eng, clock, step_time_s=0.01).run(_spec().compile())
+        # the always-on spans (profiler/spans.py) ran through all of it:
+        # a ``serve.dispatch`` a host dispatch, in a ``serve.step`` each
+        launches = _spans_since(mark, "serve.dispatch")
+        assert len(launches) == eng.metrics.host_dispatches.value
+        assert {r.parent_id for r in launches} <= \
+            {r.id for r in _spans_since(mark, "serve.step")}
         return eng
 
     traced = run(RequestTracer())
@@ -151,6 +171,7 @@ def test_tracing_preserves_burst_dispatch_ratio(tiny_model):
     def run(tracer):
         clock = VirtualClock()
         eng = _engine(tiny_model, clock, tracer=tracer, burst_tokens=4)
+        mark = _span_mark()
         rid = eng.add_request([1, 2, 3], max_new_tokens=8)
         steps = 0
         while eng.has_unfinished():
@@ -158,6 +179,13 @@ def test_tracing_preserves_burst_dispatch_ratio(tiny_model):
             eng.step()
             steps += 1
             assert steps < 50
+        # the ratio as the always-on spans count it is the counters' own:
+        # the prefill step emits the first token, the bursts the rest
+        launches = len(_spans_since(mark, "serve.dispatch"))
+        tokens = 1 + sum(r.attrs["decode_tokens"]
+                         for r in _spans_since(mark, "serve.step"))
+        assert launches / tokens == \
+            eng.metrics_snapshot()["host_dispatches_per_token"]
         return eng, rid
 
     traced, rid = run(RequestTracer())
