@@ -11,31 +11,48 @@ metadata, so a mixed batch of decode steps (q_len=1) and prefill chunks
 (q_len=k, causally masked inside the kernel) runs as ONE grid:
 
 - the KV pool stays paged ``[num_kv_heads, num_pages, page_size,
-  head_dim]`` (head-major so one grid step DMAs exactly one head's page);
+  head_dim]`` and stays in HBM (``memory_space=pl.ANY``): the body
+  fetches the pages it walks itself, one strided DMA a page bringing
+  every kv head of it;
 - ``block_tables [num_seqs, pages_per_seq]`` maps each sequence's logical
-  pages to pool pages — scalar-prefetched so the index map can steer the
-  DMA before the kernel body runs;
+  pages to pool pages — scalar-prefetched (SMEM) so the body can steer
+  its DMAs by it;
 - queries are packed into fixed ``q_block``-row slots (each sequence's
   rows start at a multiple of ``q_block``), and a ``block_row`` map
   (derived in-graph from the sorted ``q_starts``) assigns each q block to
-  its sequence. Grid = (q_block index, kv_head, page): the page axis
-  iterates sequentially, so VMEM scratch carries the online-softmax state
-  (m, l, acc) across pages — only pages up to the block's causal horizon
-  are read, which is the entire point of paged attention (HBM reads scale
-  with true kv length, not pool capacity);
+  its sequence. Grid = (q_block index,), and the walk over KV is a LOOP
+  inside the body whose trip count follows what is live: slabs of
+  ``ragged_slab_pages`` pages (256 KV tokens) from 0 to the block's
+  causal horizon, none for a block outside every live slot. The slab's
+  page copies are double-buffered (slab ``i+1`` flies while slab ``i`` is
+  multiplied), pages of the last slab past the horizon are not fetched,
+  and VMEM scratch carries each head's online-softmax state (m, l, acc)
+  across the loop. So HBM reads AND time scale with live KV: a decode
+  row at 400 tokens costs 2 iterations. (Before PR 27 the page axis was a
+  grid axis, ``max_len / page_size`` steps a q block a head whatever was
+  live: dead steps skipped their arithmetic and their DMA, so the reads
+  scaled with live KV, but each was still walked, and the time did not —
+  40,960 grid steps a layer at 7B widths, 0.3 % of the HBM roofline);
 - causal masking is per q token INSIDE the kernel: token ``i`` of a
   chunk at absolute position ``kv_len - q_len + i`` sees kv positions
   ``<=`` that — decode (q_len=1) degenerates to the old ``pos < seq_len``
-  mask, so one program covers prefill chunks and decode rows alike.
+  mask, so one program covers prefill chunks and decode rows alike. V
+  rows past the horizon are zeroed before the weighted sum: their weight
+  is exactly 0, and what lies there (the tail of a last page, a slab
+  page that was not fetched) may be anything.
 
 GQA: each q block's ``[q_block * group, head_dim]`` rows ride one MXU
-matmul per page; decode rows waste ``q_block - 1`` of those rows to
-padding, which is free in practice — the MXU tile is 128 rows and decode
-is bandwidth-bound on the page DMAs, which are unchanged.
+matmul per slab and head; decode rows waste ``q_block - 1`` of those
+rows to padding, which is free in practice — the MXU tile is 128 rows.
+A chunk's prefix is walked once a q block (``ragged_kv_tokens_read``
+counts it): a q tile as wide as the chunk is the follow-on.
 
-int8 pools (``k_scales``/``v_scales`` per (head, page)) dequantize the
-DMA'd page in-kernel with scales read off the scalar-prefetch channel
-(SMEM) — the low-bit KV path rides the ragged kernel unchanged.
+int8 pools (``k_scales``/``v_scales`` per (head, page)) dequantize each
+fetched page in-kernel with scales read off the scalar-prefetch channel
+(SMEM) by pool page — the low-bit KV path rides the ragged kernel
+unchanged. A head narrower than the 128 lanes is zero-padded to them by
+the wrapper (the chip's compiler slices an HBM ref only by whole lane
+rows).
 """
 from __future__ import annotations
 
@@ -48,14 +65,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_SLAB_TOKENS = 256
 
 
-def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref,
-                   q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   page_size, q_block, scale, ks_ref=None, vs_ref=None):
+def ragged_slab_pages(page_size, pages_per_seq):
+    """Pages one fetch of the ragged kernel brings into VMEM: a slab of
+    ``_SLAB_TOKENS`` KV tokens, so a q block's scores against it fill
+    whole lane tiles (a page larger than that is its own slab; a block
+    table shorter than that is one slab)."""
+    return min(max(1, _SLAB_TOKENS // page_size), pages_per_seq)
+
+
+def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, page_size,
+                          pages_per_seq):
+    """KV tokens ONE kv head's walk covers for a launch with these
+    (numpy) row lengths: over the live q blocks, each block's causal
+    horizon rounded up to the slab. The roofline's floor counts a live
+    token once; this counts it once a q block that sees it (a 64-token
+    chunk at ``q_block`` 8 walks its prefix 8 times)."""
+    q_lens = np.asarray(q_lens, np.int64)
+    kv_lens = np.asarray(kv_lens, np.int64)
+    slab = ragged_slab_pages(page_size, pages_per_seq) * page_size
+    blocks = -(-q_lens // q_block)               # live q blocks a row
+    row = np.repeat(np.arange(len(q_lens)), blocks)
+    first = np.cumsum(blocks) - blocks           # a row's first block
+    off = (np.arange(len(row)) - first[row]) * q_block
+    horizon = np.minimum(kv_lens[row],
+                         kv_lens[row] - q_lens[row] + off + q_block)
+    return int((-(-horizon // slab) * slab).sum())
+
+
+def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
+                   page_size, q_block, scale, quantized):
+    ks_ref = vs_ref = None
+    if quantized:
+        # int8 pool: the per-(head, page) dequant scales ride the
+        # scalar-prefetch channel (SMEM) as operands 5 and 6
+        ks_ref, vs_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref,
+     k_buf, v_buf, sem, m_ref, l_ref, acc_ref) = refs
     g = pl.program_id(0)          # q block
-    h = pl.program_id(1)          # kv head
-    p = pl.program_id(2)          # logical page of this block's sequence
 
     row = row_ref[g]
     q_len = ql_ref[row]
@@ -63,73 +112,116 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref,
     kv_start = kv_len - q_len     # absolute position of the chunk's token 0
     blk_off = g * q_block - qs_ref[row]   # this block's offset in the chunk
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    qb, hkv, grp, d = q_ref.shape
+    ppf = k_buf.shape[1]          # pages a fetch
+    slab = ppf * page_size        # KV tokens a fetch
 
-    base = p * page_size
-    # causal horizon of the block's LAST live token: pages past it hold
-    # nothing any of this block's queries may see — skip them entirely
-    # (early prefill chunks therefore read only their causal prefix)
-    horizon = jnp.minimum(kv_len, kv_start + blk_off + q_block)
+    # causal horizon of the block's LAST live token: KV past it holds
+    # nothing any of this block's queries may see, so the walk ends
+    # there (early prefill chunks read only their causal prefix); a
+    # block outside every live slot walks nothing
     live_block = (blk_off >= 0) & (blk_off < q_len)
+    horizon = jnp.where(
+        live_block, jnp.minimum(kv_len, kv_start + blk_off + q_block), 0)
+    n_pages = pl.cdiv(horizon, page_size)
+    n_slabs = pl.cdiv(horizon, slab)
 
-    @pl.when(live_block & (base < horizon))
-    def _page():
-        qb, _, grp, d = q_ref.shape
-        q = q_ref[...].reshape(qb * grp, d).astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)        # [ps, d]
-        v = v_ref[0, 0].astype(jnp.float32)
-        if ks_ref is not None:
-            # int8 pool: dequantize the DMA'd page with its own
-            # per-(head, page) scale — a scalar read off the prefetch
-            # channel (SMEM), indexed by the same pool page the DMA read
-            last_live = jnp.maximum(kv_len - 1, 0) // page_size
-            page = tbl_ref[row, jnp.minimum(p, last_live)]
-            k = k * ks_ref[h, page]
-            v = v * vs_ref[h, page]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [qb*grp, ps]
-        # per-token causal mask: token i of the chunk (absolute position
-        # kv_start + blk_off + i) sees kv positions <= its own; tokens
-        # past q_len (slot padding) are masked out entirely
-        s3 = s.reshape(qb, grp, page_size)
-        tok = blk_off + jax.lax.broadcasted_iota(jnp.int32, s3.shape, 0)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s3.shape, 2)
-        ok = (tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)
-        s = jnp.where(ok, s3, _NEG_INF).reshape(qb * grp, page_size)
-        m_prev = m_ref[...]                        # [qb*grp, 1]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        e = jnp.exp(s - m_new)                     # [qb*grp, ps]
-        l_ref[...] = l_prev * alpha + jnp.sum(e, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            e, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [qb*grp, d]
+    def each_live_page(i, slot, op):
+        """``op`` on the K and V copy of every live page of slab ``i``:
+        one strided DMA a page brings all kv heads of it; pages of the
+        last slab past the block's last live page are not fetched."""
+        for j in range(ppf):
+            p = i * ppf + j
 
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _fin():
-        qb, _, grp, d = o_ref.shape
-        o_ref[...] = (acc_ref[...] /
-                      jnp.maximum(l_ref[...], 1e-30)) \
-            .reshape(qb, 1, grp, d).astype(o_ref.dtype)
+            @pl.when(p < n_pages)
+            def _copy():
+                page = tbl_ref[row, p]
+                op(pltpu.make_async_copy(
+                    k_hbm.at[:, page], k_buf.at[slot, j], sem.at[0, slot]))
+                op(pltpu.make_async_copy(
+                    v_hbm.at[:, page], v_buf.at[slot, j], sem.at[1, slot]))
 
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-def _ragged_kernel_quant(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, ks_ref,
-                         vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                         acc_ref, *, page_size, q_block, scale):
-    """int8-pool variant: the per-(head, page) dequant scales ride the
-    scalar-prefetch channel (SMEM) as operands 5 and 6."""
-    _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_ref, l_ref, acc_ref,
-                   page_size=page_size, q_block=q_block, scale=scale,
-                   ks_ref=ks_ref, vs_ref=vs_ref)
+    @pl.when(n_slabs > 0)
+    def _first():
+        each_live_page(0, 0, lambda c: c.start())
+
+    def _slab(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_slabs)
+        def _next():              # flies while slab i is multiplied
+            each_live_page(i + 1, 1 - slot, lambda c: c.start())
+
+        each_live_page(i, slot, lambda c: c.wait())
+
+        base = i * slab
+        # per-token causal mask, the same for every head: token t of the
+        # chunk (absolute position kv_start + blk_off + t) sees kv
+        # positions <= its own; tokens past q_len (slot padding) are
+        # masked out entirely
+        tok = blk_off + jax.lax.broadcasted_iota(
+            jnp.int32, (qb, grp, slab), 0)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (qb, grp, slab), 2)
+        ok = ((tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)) \
+            .reshape(qb * grp, slab)
+        # rows of V past the horizon are pool positions no query of this
+        # block may see, or slab pages that were not fetched: their
+        # weight is exactly 0, and 0 x what lies there must stay 0
+        seen = base + jax.lax.broadcasted_iota(
+            jnp.int32, (slab, d), 0) < horizon
+
+        def slab_of(buf, s_ref, h):
+            """Head ``h`` of the slab as f32 ``[slab, d]``; an int8
+            page is dequantized with its own per-(head, page) scale, a
+            scalar off SMEM indexed by the pool page the DMA read (a
+            page that was not fetched takes the last live page's: its
+            columns are masked)."""
+            pages = [buf[slot, j, h].astype(jnp.float32)
+                     for j in range(ppf)]
+            if quantized:
+                pages = [x * s_ref[h, tbl_ref[row, jnp.minimum(
+                    i * ppf + j, n_pages - 1)]]
+                    for j, x in enumerate(pages)]
+            return jnp.concatenate(pages, axis=0)
+
+        def _head(h, carry):
+            q = q_ref[:, h].reshape(qb * grp, d).astype(jnp.float32)
+            k = slab_of(k_buf, ks_ref, h)
+            v = jnp.where(seen, slab_of(v_buf, vs_ref, h), 0.0)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [qb*grp, slab]
+            s = jnp.where(ok, s, _NEG_INF)
+            m_prev = m_ref[h]                            # [qb*grp, 1]
+            l_prev = l_ref[h]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            e = jnp.exp(s - m_new)                       # [qb*grp, slab]
+            l_ref[h] = l_prev * alpha + jnp.sum(e, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                e, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [qb*grp, d]
+            return carry
+
+        # unrolled: the heads' matmul -> softmax -> matmul chains are
+        # independent, and side by side they hide one another's latency
+        jax.lax.fori_loop(0, hkv, _head, None, unroll=True)
+        return carry
+
+    jax.lax.fori_loop(0, n_slabs, _slab, None)
+
+    def _write(h, carry):
+        o_ref[:, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)) \
+            .reshape(qb, grp, d).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, hkv, _write, None)
 
 
 def ragged_block_row(q_starts, num_blocks, q_block):
@@ -185,10 +277,27 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
                          f"{q_block}")
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
-    group = hq // hkv
-    pages_per_seq = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    return _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
+                        kv_lens, k_scales, v_scales, block_row,
+                        q_block=q_block, scale=float(scale),
+                        interpret=interpret)
+
+
+# jitted on its own so that a step which calls the kernel once a layer
+# traces and lowers the body ONCE and calls it L times: the body's
+# unrolled copies and heads cost seconds of lowering a layer otherwise
+# (14 s of a 12-layer step's first call on the chip's host), and that
+# is paid before the compile cache can be asked
+@functools.partial(jax.jit, static_argnames=("q_block", "scale", "interpret"))
+def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
+                 kv_lens, k_scales, v_scales, block_row, *, q_block, scale,
+                 interpret):
+    t, hq, d = q.shape
+    hkv, _, page_size, _ = k_pages.shape
+    group = hq // hkv
+    pages_per_seq = block_tables.shape[1]
     quantized = k_scales is not None
     num_blocks = t // q_block
 
@@ -201,52 +310,62 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
     else:
         block_row = jnp.asarray(block_row, jnp.int32)
 
-    qg = q.reshape(t, hkv, group, d)
+    # the body's DMAs slice the pools in HBM, and the chip's compiler
+    # slices an HBM ref only by whole 128-lane rows: for it a narrower
+    # head is zero-padded to the lane width (exact: the padding adds 0
+    # to every score and lands in output columns cut off below). XLA
+    # already relaid such a pool out lane-padded, by a pool-sized copy,
+    # for any Mosaic call; the interpreter slices anything
+    dl = d if interpret else -(-d // 128) * 128
+    if dl != d:
+        q, k_pages, v_pages = (
+            jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, dl - d),))
+            for x in (q, k_pages, v_pages))
+    qg = q.reshape(t, hkv, group, dl)
+    ppf = ragged_slab_pages(page_size, pages_per_seq)
 
-    def _kv_map(g, h, p, rows, qs, ql, kl, tbl, *scales):
-        # dead pages (past the sequence's last live page) clamp to the
-        # last live page: revisiting the same block lets the pipeline
-        # elide the copy, so HBM reads scale with true kv_len
-        row = rows[g]
-        last_live = jnp.maximum(kl[row] - 1, 0) // page_size
-        return (h, tbl[row, jnp.minimum(p, last_live)], 0, 0)
-
-    def _q_map(g, h, p, rows, qs, ql, kl, tbl, *scales):
-        return (g, h, 0, 0)
+    def _q_map(g, *prefetch):
+        return (g, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # block_row, q_starts, q_lens, kv_lens, block_tables
         # (+ k/v scales for int8 pools)
         num_scalar_prefetch=7 if quantized else 5,
-        grid=(num_blocks, hkv, pages_per_seq),
+        grid=(num_blocks,),
         in_specs=[
-            pl.BlockSpec((q_block, 1, group, d), _q_map),
-            pl.BlockSpec((1, 1, page_size, d), _kv_map),
-            pl.BlockSpec((1, 1, page_size, d), _kv_map),
+            pl.BlockSpec((q_block, hkv, group, dl), _q_map),
+            # the pools stay in HBM: the body fetches the pages its
+            # block's horizon covers, and no others
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((q_block, 1, group, d), _q_map),
+        out_specs=pl.BlockSpec((q_block, hkv, group, dl), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((q_block * group, 1), jnp.float32),   # m
-            pltpu.VMEM((q_block * group, 1), jnp.float32),   # l
-            pltpu.VMEM((q_block * group, d), jnp.float32),   # acc
+            # K and V slabs, double-buffered; a page is a leading index
+            # so an int8 page (half an int8 tile) lands whole
+            pltpu.VMEM((2, ppf, hkv, page_size, dl), k_pages.dtype),
+            pltpu.VMEM((2, ppf, hkv, page_size, dl), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),                     # K/V, slot
+            pltpu.VMEM((hkv, q_block * group, 1), jnp.float32),  # m
+            pltpu.VMEM((hkv, q_block * group, 1), jnp.float32),  # l
+            pltpu.VMEM((hkv, q_block * group, dl), jnp.float32),  # acc
         ],
     )
     prefetch = [block_row, q_starts,
                 q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
                 block_tables.astype(jnp.int32)]
-    kernel = _ragged_kernel
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
-        kernel = _ragged_kernel_quant
     out = pl.pallas_call(
-        functools.partial(kernel, page_size=page_size, q_block=q_block,
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((t, hkv, group, d), q.dtype),
+        functools.partial(_ragged_kernel, page_size=page_size,
+                          q_block=q_block, scale=scale,
+                          quantized=quantized),
+        out_shape=jax.ShapeDtypeStruct((t, hkv, group, dl), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret, name="ragged_paged_attention",
     )(*prefetch, qg, k_pages, v_pages)
-    return out.reshape(t, hq, d)
+    return out.reshape(t, hq, dl)[..., :d]
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
@@ -343,5 +462,6 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
 
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "ragged_block_row", "ragged_paged_attention",
-           "ragged_paged_attention_reference"]
+           "ragged_block_row", "ragged_kv_tokens_read",
+           "ragged_paged_attention", "ragged_paged_attention_reference",
+           "ragged_slab_pages"]

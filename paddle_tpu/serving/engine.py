@@ -65,7 +65,8 @@ import jax.numpy as jnp
 
 from ..models.generation import (_logits, _rms_norm, _rope, _wmat,
                                  extract_params, request_keys, sample_rows)
-from ..kernels.paged_attention import ragged_paged_attention
+from ..kernels.paged_attention import (ragged_kv_tokens_read,
+                                       ragged_paged_attention)
 from ..profiler import spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 from .metrics import ServingMetrics
@@ -2271,7 +2272,11 @@ class LLMEngine:
         sp.set(rows=len(plan.rows), prefill_tokens=prefill_tokens,
                decode_tokens=int(q_lens.sum()) - prefill_tokens,
                # what attention must read: every row's context
-               live_kv_tokens=int(kv_lens.sum()))
+               live_kv_tokens=int(kv_lens.sum()),
+               # what the ragged kernel's walk covers, a kv head
+               attn_kv_tokens_read=ragged_kv_tokens_read(
+                   q_lens, kv_lens, q_block=self.q_block,
+                   page_size=self.page_size, pages_per_seq=PPS))
         sp.phase("serve.dispatch")
         out, n_out, finite, new_kv, new_scales = self._ragged_jit(
             self._ragged_params, self.pool.kv, self.pool.kv_scales,
@@ -2425,7 +2430,12 @@ class LLMEngine:
         # the context is of its first iteration, the tokens of all
         sp.set(rows=len(bplan.rows), prefill_tokens=0,
                decode_tokens=int(gen.sum()),
-               live_kv_tokens=int(kv_lens.sum()))
+               live_kv_tokens=int(kv_lens.sum()),
+               # as the ragged walk would cover these rows, so that the
+               # two counts stay a pair on every step
+               attn_kv_tokens_read=ragged_kv_tokens_read(
+                   live, kv_lens, q_block=1, page_size=self.page_size,
+                   pages_per_seq=PPS))
         for i, (seq, cap) in enumerate(bplan.rows):
             if not ok[i]:
                 # the row went non-finite at some loop iteration: every

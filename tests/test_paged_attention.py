@@ -123,6 +123,19 @@ def _pack_rows(q_lens, q_block, budget):
     return np.asarray(starts, np.int32)
 
 
+def _quantize_pools(kp, vp):
+    """int8 pools with per-(head, page) absmax scales, as the engine's
+    quantized KV cache holds them."""
+    out = []
+    for pool in (np.asarray(kp), np.asarray(vp)):
+        s = np.maximum(np.abs(pool).max(axis=(2, 3)), 1e-8) / 127.0
+        out += [jnp.asarray(np.clip(np.round(pool / s[:, :, None, None]),
+                                    -127, 127).astype(np.int8)),
+                jnp.asarray(s)]
+    kq, ks, vq, vs = out
+    return kq, vq, dict(k_scales=ks, v_scales=vs)
+
+
 def _ragged_case(q_lens, kv_lens, *, qb=4, budget=32, hq=4, hkv=2, d=32,
                  ps=8, pps=6, seed=0, quant=False):
     rng = np.random.default_rng(seed)
@@ -139,17 +152,7 @@ def _ragged_case(q_lens, kv_lens, *, qb=4, budget=32, hq=4, hkv=2, d=32,
                 kv_lens=jnp.asarray(kv_lens, jnp.int32))
     scales = {}
     if quant:
-        ks = np.maximum(np.abs(np.asarray(kp)).max(axis=(2, 3)),
-                        1e-8) / 127.0
-        vs = np.maximum(np.abs(np.asarray(vp)).max(axis=(2, 3)),
-                        1e-8) / 127.0
-        kp = jnp.asarray(np.clip(np.round(np.asarray(kp) /
-                                          ks[:, :, None, None]),
-                                 -127, 127).astype(np.int8))
-        vp = jnp.asarray(np.clip(np.round(np.asarray(vp) /
-                                          vs[:, :, None, None]),
-                                 -127, 127).astype(np.int8))
-        scales = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+        kp, vp, scales = _quantize_pools(kp, vp)
     out = ragged_paged_attention(q, kp, vp, tbl, q_block=qb,
                                  interpret=True, **args, **scales)
     ref = ragged_paged_attention_reference(q, kp, vp, tbl, q_starts,
@@ -235,3 +238,118 @@ def test_ragged_rejects_misaligned_budget():
             jnp.zeros((2, 4, 4, 8)), jnp.zeros((1, 2), jnp.int32),
             jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
             jnp.ones((1,), jnp.int32), q_block=4, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# the walk over live KV: a loop whose trip count follows each q block's
+# causal horizon, a slab of pages a fetch
+# ---------------------------------------------------------------------------
+
+from paddle_tpu.kernels.paged_attention import (  # noqa: E402
+    ragged_kv_tokens_read, ragged_slab_pages)
+
+_PS = 16
+_SLAB = ragged_slab_pages(_PS, 1 << 20) * _PS     # KV tokens a fetch
+_PPS = 3 * _SLAB // _PS                           # max_len: three slabs
+# (q_lens, kv_lens) per edge of the trip count; pad rows (q_len 0) last
+_WALK_EDGES = {
+    "every_row_dead": ([0, 0, 0], [0, 0, 0]),
+    "decode_at_kv_len_1": ([1], [1]),
+    "one_under_a_slab": ([1], [_SLAB - 1]),
+    "exactly_a_slab": ([1], [_SLAB]),
+    "one_over_a_slab": ([1], [_SLAB + 1]),
+    "row_at_max_len": ([1], [_PS * _PPS]),
+    # kv_start 28 under the slab boundary: the 8-token blocks' horizons
+    # run from 20 under it to 36 over it
+    "chunk_across_slabs": ([64], [_SLAB + 36]),
+    "mixed_with_pad_rows": ([1, 64, 1, 0, 0],
+                            [_SLAB + 1, _SLAB + 72, _PS * _PPS, 0, 0]),
+}
+_WALK_BUDGET = {1: 72, 8: 96, 128: 384}
+_WALK_ROWS = 5
+
+
+def _walk_operands(q_lens, kv_lens, qb, seed=0):
+    """Fixed shapes over every edge (so one compile a (q_block, pool
+    dtype)): rows padded to ``_WALK_ROWS`` with ``q_start = T``."""
+    pad = _WALK_ROWS - len(q_lens)
+    q_lens, kv_lens = list(q_lens) + [0] * pad, list(kv_lens) + [0] * pad
+    rng = np.random.default_rng(seed)
+    hq, hkv, d, T = 4, 2, 32, _WALK_BUDGET[qb]
+    npages = _WALK_ROWS * _PPS + 3
+    q = rng.standard_normal((T, hq, d)).astype(np.float32)
+    kp = rng.standard_normal((hkv, npages, _PS, d)).astype(np.float32)
+    vp = rng.standard_normal((hkv, npages, _PS, d)).astype(np.float32)
+    tbl = (rng.permutation(npages - 1)[:_WALK_ROWS * _PPS] + 1) \
+        .reshape(_WALK_ROWS, _PPS).astype(np.int32)
+    return (q, kp, vp, tbl, _pack_rows(q_lens, qb, T),
+            np.asarray(q_lens, np.int32), np.asarray(kv_lens, np.int32))
+
+
+_walk_kernel = jax.jit(ragged_paged_attention,
+                       static_argnames=("q_block", "interpret"))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("qb", [1, 8, 128])
+@pytest.mark.parametrize("edge", list(_WALK_EDGES))
+def test_ragged_walk_follows_live_kv(edge, qb, quant):
+    q_lens, kv_lens = _WALK_EDGES[edge]
+    q, kp, vp, tbl, qs, ql, kl = _walk_operands(q_lens, kv_lens, qb)
+    scales = {}
+    if quant:
+        kp, vp, scales = _quantize_pools(kp, vp)
+    out = np.asarray(_walk_kernel(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        jnp.asarray(qs), jnp.asarray(ql), jnp.asarray(kl), q_block=qb,
+        interpret=True, **scales))
+    ref = np.asarray(ragged_paged_attention_reference(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        qs, ql, kl, **scales))
+    assert np.isfinite(out).all()
+    _assert_live_rows_close(out, ref, qs, ql)
+
+
+@pytest.mark.parametrize("qb", [1, 8, 128])
+def test_ragged_poisoned_pool_never_reaches_the_output(qb):
+    """Every pool position no live query may see holds NaN: pages no
+    live row's table reaches within its kv_len, the tail of a row's
+    last page, every page of a pad row. A weight of exactly 0 on such a
+    position must stay 0 — the output is finite everywhere and the live
+    rows equal the oracle's on the clean pool."""
+    q_lens, kv_lens = _WALK_EDGES["mixed_with_pad_rows"]
+    q, kp, vp, tbl, qs, ql, kl = _walk_operands(q_lens, kv_lens, qb,
+                                                seed=11)
+    ref = np.asarray(ragged_paged_attention_reference(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        qs, ql, kl))
+    seen = np.zeros(kp.shape[1:3], bool)          # [page, slot]
+    for i, n in enumerate(kl):
+        pos = np.arange(n)
+        seen[tbl[i, pos // _PS], pos % _PS] = True
+    kp = np.where(seen[None, :, :, None], kp, np.nan)
+    vp = np.where(seen[None, :, :, None], vp, np.nan)
+    out = np.asarray(_walk_kernel(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        jnp.asarray(qs), jnp.asarray(ql), jnp.asarray(kl), q_block=qb,
+        interpret=True))
+    assert np.isfinite(out).all()
+    _assert_live_rows_close(out, ref, qs, ql)
+
+
+@pytest.mark.parametrize("q_lens,kv_lens,qb,want", [
+    ([0, 0], [0, 0], 8, 0),                   # nothing aboard
+    ([1], [1], 8, _SLAB),                     # a row rounds up to a slab
+    ([1, 1, 1], [_SLAB - 1, _SLAB, _SLAB + 1], 8, 4 * _SLAB),
+    # kv_start 28 under the slab boundary, eight blocks with horizons
+    # from 20 under it to 36 over it: three under (-20, -12, -4), five
+    # over
+    ([64], [_SLAB + 36], 8, 3 * _SLAB + 5 * 2 * _SLAB),
+    ([64], [_SLAB + 36], 128, 2 * _SLAB),     # one block sees it once
+    ([64, 0], [_SLAB + 36, 0], 1, 28 * _SLAB + 36 * 2 * _SLAB),
+])
+def test_ragged_kv_tokens_read_counts_the_walk(q_lens, kv_lens, qb, want):
+    """The count on ``serve.step``: each live q block's causal horizon
+    rounded up to the slab, summed — what one kv head's loops cover."""
+    assert ragged_kv_tokens_read(q_lens, kv_lens, q_block=qb,
+                                 page_size=_PS, pages_per_seq=_PPS) == want

@@ -96,7 +96,7 @@ def _layer_fixture(seed=0, T=32, R=4, D=64, H=4, Hkv=2, dh=16, F=96,
     tbls[:, :3] = rng.permutation(np.arange(1, P))[:R * 3].reshape(R, 3)
     tbls = jnp.asarray(tbls)
     q_lens = np.array([8, 1, 13, 0], np.int32)
-    q_starts = np.array([0, 8, 9, T], np.int32)
+    q_starts = np.array([0, 8, 16, T], np.int32)   # slots: q_block-aligned
     kv_lens = np.array([8, 5, 20, 0], np.int32)
     positions = np.zeros((T,), np.int32)
     for r in range(R):
@@ -116,6 +116,14 @@ def _layer_fixture(seed=0, T=32, R=4, D=64, H=4, Hkv=2, dh=16, F=96,
 # kernel parity: the Pallas body vs the bitwise-fused jnp reference
 # ---------------------------------------------------------------------------
 
+def _live_tokens(q_starts, q_lens):
+    """Rows of the packed chunk that hold a live token: slot padding
+    holds garbage by the ragged kernel's contract, and the two bodies'
+    attention need not agree on which."""
+    return np.concatenate([np.arange(s, s + n) for s, n in
+                           zip(np.asarray(q_starts), np.asarray(q_lens))])
+
+
 def test_fused_prefill_layer_matches_reference_fp():
     (layer, h, Kp, Vp, tbls, pre, q_starts, q_lens, kv_lens,
      kw) = _layer_fixture()
@@ -129,7 +137,9 @@ def test_fused_prefill_layer_matches_reference_fp():
     out = fused_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts,
                               q_lens, kv_lens, interpret=True,
                               attn_interpret=True, **kw)
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
+    live = _live_tokens(q_starts, q_lens)
+    np.testing.assert_allclose(np.asarray(out[0])[:, live],
+                               np.asarray(ref[0])[:, live],
                                rtol=1e-4, atol=1e-4)
     # page 0 is the NULL/trash page: the jnp scatter dumps dead-token
     # rows there, the kernel preserves committed bytes — both
@@ -179,7 +189,9 @@ def test_fused_prefill_layer_matches_reference_int8():
                               q_lens, kv_lens, interpret=True,
                               attn_interpret=True, k_scales=Ks0,
                               v_scales=Vs0, quant_append_fn=qafn, **kw)
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
+    live = _live_tokens(q_starts, q_lens)
+    np.testing.assert_allclose(np.asarray(out[0])[:, live],
+                               np.asarray(ref[0])[:, live],
                                rtol=1e-4, atol=1e-4)
     for i in (1, 2, 3, 4):
         np.testing.assert_array_equal(np.asarray(out[i]),

@@ -187,17 +187,23 @@ def test_summary_keeps_one_tag_and_its_children():
 
 def _run_counting(eng, prompts, max_new=4):
     """Drive the engine to the end; returns the steps made and, a step,
-    the context attention had to read and the query tokens scheduled, as
-    the scheduler's plan gives them."""
+    the context attention had to read, the query tokens scheduled and
+    what the ragged kernel's walk covers, as the scheduler's plan gives
+    them."""
+    from paddle_tpu.kernels.paged_attention import ragged_kv_tokens_read
     want_live = []
     prepare = eng.scheduler.prepare_step
 
     def spy():
         plan = prepare()
         if plan is not None:
-            want_live.append((sum(seq.cached_len + q_len
-                                  for seq, _, q_len in plan.rows),
-                              sum(q_len for _, _, q_len in plan.rows)))
+            q_lens = [q_len for _, _, q_len in plan.rows]
+            kv_lens = [seq.cached_len + q_len for seq, _, q_len in plan.rows]
+            want_live.append((sum(kv_lens), sum(q_lens),
+                              ragged_kv_tokens_read(
+                                  q_lens, kv_lens, q_block=eng.q_block,
+                                  page_size=eng.page_size,
+                                  pages_per_seq=eng.max_pages_per_seq)))
         return plan
 
     eng.scheduler.prepare_step = spy
@@ -258,8 +264,12 @@ def test_counts_agree_with_the_programs_counters(tiny_model):
     assert sum(r.attrs["chunks"] for r in _since(mark, "serve.prefill")) \
         == delta("prefill_chunks")
     assert [(s.attrs["live_kv_tokens"],
-             s.attrs["prefill_tokens"] + s.attrs["decode_tokens"])
+             s.attrs["prefill_tokens"] + s.attrs["decode_tokens"],
+             s.attrs["attn_kv_tokens_read"])
             for s in step_recs] == want_live
+    # the walk covers every live token at least once
+    assert all(s.attrs["attn_kv_tokens_read"] >= s.attrs["live_kv_tokens"]
+               for s in step_recs)
     assert sum(s.attrs["prefill_tokens"] for s in step_recs) \
         == sum(len(eng._seqs[r].prompt_ids) for r in rids)
     # three row slots for seven requests: full at first, then draining

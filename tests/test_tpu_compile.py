@@ -104,17 +104,16 @@ def _paged():
              _s((R, pps), jnp.int32), _s((R,), jnp.int32)), 1)
 
 
-def _ragged(T, q_block, int8_kv):
+def _ragged(T, q_block, int8_kv, *, h=H, hkv=HKV, dh=DH, R=8, pages=1024):
     from paddle_tpu.kernels.paged_attention import ragged_paged_attention
-    R, pages, pps = 8, 1024, 128
+    pps = 128
     i32 = jnp.int32
-    shapes = [_s((T, H, DH)),
-              _pool(pages, jnp.int8 if int8_kv else jnp.bfloat16),
-              _pool(pages, jnp.int8 if int8_kv else jnp.bfloat16),
+    pool = _s((hkv, pages, PS, dh), jnp.int8 if int8_kv else jnp.bfloat16)
+    shapes = [_s((T, h, dh)), pool, pool,
               _s((R, pps), i32), _s((R,), i32), _s((R,), i32),
               _s((R,), i32)]
     if int8_kv:
-        shapes += [_s((HKV, pages), jnp.float32)] * 2
+        shapes += [_s((hkv, pages), jnp.float32)] * 2
 
         def fn(q, k, v, t, qs, ql, kl, ks, vs):
             return ragged_paged_attention(q, k, v, t, qs, ql, kl,
@@ -228,6 +227,11 @@ CASES = {
     "ragged_t64_qb8_bf16": lambda: _ragged(64, 8, False),
     "ragged_t512_qb128_int8kv": lambda: _ragged(512, 128, True),
     "ragged_t64_qb8_int8kv": lambda: _ragged(64, 8, True),
+    # the geometry the benchmark's serving cell runs (mistral-7b.
+    # chat-steady: step_token_budget 320, 32 q / 8 kv heads of 128, 32
+    # rows x 128 page slots over a pool of 6,144 pages)
+    "ragged_t320_qb8_bf16_mistral7b": lambda: _ragged(
+        320, 8, False, h=32, hkv=8, dh=128, R=32, pages=6144),
     "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
     "fused_adamw_bf16": lambda: _adamw(11_534_336, jnp.bfloat16),
     "dequant_matmul_int8": lambda: _dequant(8),
