@@ -173,14 +173,24 @@ def main(argv=None):
                 "idle_gaps": xplane.top(run["trace"]["idle_seconds_by_span"]),
             }
     names = [m["name"] for m in wanted if m["name"] in values]
+    # what decided ``correct``, each number beside its limit: the last
+    # lines of the errors and the last key of the result
+    compared = {k: {"value": v, "limit": limit}
+                for k, (v, limit) in out["compared"].items()}
+    for k, c in compared.items():
+        print(f"compared {k}: {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    print(f"correct: {out['correct']}", file=sys.stderr, flush=True)
     if args.rehearse or not on_chip:
         print(json.dumps({"rehearsal": cell["name"], "correct": out["correct"],
                           "attempted": out["attempted"],
-                          "failed": out["failed"], "would_report": names}))
+                          "failed": out["failed"], "would_report": names,
+                          "compared": sorted(compared)}))
         return 0
     result["metrics"] = {k: {"value": values[k], "unit": units[k]}
                          for k in names}
     result["device"] = device
+    result["compared"] = compared
     print(json.dumps(result, default=float))
     return 0
 

@@ -192,8 +192,13 @@ def run(ctx):
     summary = summarize(raw)
     ctx.info(phase="window", compiled_in_window=compiled_in_window, **summary["counts"],
              **detail)
+    tol = ctx.config["logit_tol"]
     return {
         "correct": bool(ok and compiled_in_window == 0),
+        "compared": {
+            "margin_mean": [detail.get("margin_mean"), tol["mean"]],
+            "margin_max": [detail.get("margin_max"), tol["max"]],
+            "compiled_in_window": [compiled_in_window, 0]},
         "attempted": len(raw["in_window"]),
         "failed": len(raw["bad"]) + raw["rejected"],
         "t_open": raw["t_open"],

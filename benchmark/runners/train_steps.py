@@ -99,6 +99,10 @@ def run(ctx):
              **latency.step_stats([b - a for a, b in spans]))
     return {
         "correct": bool(correct and finite == steps),
+        "compared": {
+            "loss_rel_err": [rel, recipe["loss_rtol"]],
+            "loss_third_less_first": [warm[-1] - warm[0], 0.0],
+            "nonfinite_losses": [steps - finite, 0]},
         "attempted": steps, "failed": steps - finite,
         "t_open": t_open,
         "end_to_end": {"train_tok_s": tok_s},

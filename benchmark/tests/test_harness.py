@@ -55,6 +55,11 @@ NEW_CELLS = {
 }
 
 
+COMPARED = {"serve": ["margin_mean", "margin_max", "compiled_in_window"],
+            "train": ["loss_rel_err", "loss_third_less_first",
+                      "nonfinite_losses"]}
+
+
 @pytest.mark.parametrize("kind", sorted(NEW_CELLS))
 @pytest.mark.parametrize("trace", [0, 1])
 def test_a_cell_made_only_of_new_files_runs(tmp_path, kind, trace):
@@ -87,6 +92,14 @@ def test_a_cell_made_only_of_new_files_runs(tmp_path, kind, trace):
     assert last["rehearsal"] == cell and last["correct"] is True
     assert last["attempted"] > 0 and last["failed"] == 0
     assert (layers if trace else e2e) <= set(last["would_report"])
+    # each number that decided ``correct`` beside its limit, as the last
+    # lines of the errors
+    names = COMPARED[kind]
+    assert last["compared"] == sorted(names)
+    tail = p.stderr.strip().splitlines()[-len(names) - 1:]
+    assert tail[-1] == "correct: True"
+    for line, name in zip(tail, names):
+        assert line.startswith(f"compared {name}: ") and " limit " in line
 
 
 def test_without_an_accelerator_no_result_line():

@@ -3,7 +3,6 @@ the hand tool's innermost-span attribution, on records small enough to
 check by hand."""
 import pytest
 
-from benchmark import idle_by_program_span as tool
 from benchmark import peaks, program_log, xplane
 from benchmark import run as harness
 
@@ -203,10 +202,9 @@ def test_a_program_without_the_log_reads_nothing(monkeypatch):
     assert harness.read_layer_metric("serve_wait_ms_p50", run) is None
 
 
-# --- the hand tool -----------------------------------------------------------
+# --- idle gaps by the program's spans ----------------------------------------
 
-def test_idle_goes_to_the_innermost_covering_span():
-    ev = {"device": {"/device:TPU:0": [["fusion.1", 100.0, 20.0],
+NESTED = {"device": {"/device:TPU:0": [["fusion.1", 100.0, 20.0],
                                        ["fusion.2", 150.0, 30.0]]},
           "spans": [["bench.trace_window", 100.0, 100.0],
                     ["bench.step", 110.0, 50.0],
@@ -214,21 +212,44 @@ def test_idle_goes_to_the_innermost_covering_span():
                     ["serve.wait", 112.0, 13.0],
                     ["serve.commit", 125.0, 15.0],
                     ["bench.admit", 185.0, 5.0]]}
-    assert tool.idle_gaps(ev["device"]["/device:TPU:0"], 100.0, 200.0) == \
-        [(120.0, 150.0), (180.0, 200.0)]
-    window_s, idle_s, by = tool.idle_by_span(ev)
-    assert window_s == pytest.approx(100e-9)
-    assert idle_s == pytest.approx(50e-9)
-    # gap [120, 150): wait to 125, commit to 140, then serve.step's own;
-    # bench.step covers all of it and is charged none of it
+
+
+def test_idle_goes_to_the_innermost_covering_span():
+    r = xplane.reduce(NESTED)
+    assert r["window_s"] == pytest.approx(100e-9)
+    assert r["busy_s"] == pytest.approx(50e-9)
+    # gaps [120, 150) and [180, 200). In the first: wait to 125, commit
+    # to 140, then serve.step's own; bench.step covers all of it and is
+    # charged none of it
+    by = r["idle_seconds_by_span"]
     assert by == {"serve.wait": pytest.approx(5e-9),
                   "serve.commit": pytest.approx(15e-9),
                   "serve.step": pytest.approx(10e-9),
                   "bench.admit": pytest.approx(5e-9),
                   "(no span)": pytest.approx(15e-9)}
-    # the result line's reduction charges every covering span: right for
-    # the flat bench.* spans, double for nested ones
-    flat = xplane.reduce(ev)["idle_seconds_by_span"]
-    assert flat["bench.step"] == pytest.approx(30e-9)
-    assert sum(flat.values()) > idle_s
-    assert tool.idle_by_span({"device": {}, "spans": ev["spans"]}) is None
+    # so the charges add up to the idle time, nested spans or not
+    assert sum(by.values()) == pytest.approx(r["window_s"] - r["busy_s"])
+
+
+def test_innermost_pieces_are_disjoint_and_take_the_latest_start():
+    pieces = xplane.innermost(NESTED["spans"][1:])
+    assert pieces == [["bench.step", 110.0, 2.0],
+                      ["serve.wait", 112.0, 13.0],
+                      ["serve.commit", 125.0, 15.0],
+                      ["serve.step", 140.0, 18.0],
+                      ["bench.step", 158.0, 2.0],
+                      ["bench.admit", 185.0, 5.0]]
+    # two that start together: the one that ends first is the inner one
+    assert xplane.innermost([["train.step", 0.0, 10.0],
+                             ["train.gather", 0.0, 4.0]]) == [
+        ["train.gather", 0.0, 4.0], ["train.step", 4.0, 6.0]]
+    assert xplane.innermost([]) == []
+
+
+def test_the_hand_tool_prints_the_same_attribution(monkeypatch, capsys):
+    from benchmark import idle_by_program_span as tool
+    monkeypatch.setattr(xplane, "load", lambda path: NESTED)
+    assert tool.main(["tool", "some.xplane.pb"]) == 0
+    out = capsys.readouterr().out
+    assert "device idle 0.0000 s (50.00 %), 1 program steps" in out
+    assert "serve.commit" in out and "30.0" in out     # 15 of 50 ns

@@ -14,15 +14,21 @@ Pallas kernel's instruction is named after the ``name=`` its
 times may overlap while the busy union does not. The line ``XLA
 Modules`` holds one event per executable run. Host threads are lines of
 the plane ``/host:CPU``; ``jax.profiler.TraceAnnotation`` spans appear
-there under their own names, on the same clock as the device lines.
+there under their own names, on the same clock as the device lines: the
+benchmark's ``bench.*`` around its calls into the program, and inside
+them the program's own (``serve.*``, ``train.*``;
+``paddle_tpu/profiler/spans.py``), nested.
 """
+import bisect
 import glob
 import os
 
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
-SPAN_PREFIX = "bench."
+#: the benchmark's spans and the program's
+SPAN_PREFIXES = ("bench.", "serve.", "train.")
+NO_SPAN = "(no span)"
 
 
 def find_xplane(trace_dir):
@@ -62,7 +68,7 @@ def load(path):
             for line in plane.lines:
                 spans += [[e.name, float(e.start_ns), float(e.duration_ns)]
                           for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
+                          if e.name.startswith(SPAN_PREFIXES)]
     spans.sort(key=lambda e: e[1])
     return {"device": device, "spans": spans}
 
@@ -85,19 +91,49 @@ def _clip(events, lo, hi):
             yield name, a, b
 
 
+def innermost(spans):
+    """The spans flattened to disjoint events, sorted: over each piece
+    of the host's time the covering span that started last (of nested
+    spans the innermost; of two that start together the one that ends
+    first). Time that no span covers is left out."""
+    bounds = sorted({x for _, s, d in spans for x in (s, s + d)})
+    by_start = sorted(spans, key=lambda e: e[1])
+    out, active, i = [], [], 0
+    for a, b in zip(bounds, bounds[1:]):
+        while i < len(by_start) and by_start[i][1] <= a:
+            name, s, d = by_start[i]
+            active.append((s, -(s + d), name))
+            i += 1
+        active = [x for x in active if -x[1] > a]
+        if not active:
+            continue
+        name = max(active)[2]
+        if out and out[-1][0] == name and out[-1][1] + out[-1][2] == a:
+            out[-1][2] = b - out[-1][1]
+        else:
+            out.append([name, a, b - a])
+    return out
+
+
 def reduce(events, window_span="bench.trace_window"):
     """Numbers of the traced window. The window is the span
     ``window_span`` (the harness wraps the traced part of its loop in
     it); device events are clipped to it. With several chips, busy and
-    per-operation seconds are averaged over them. Returns None when the
-    trace holds no device plane or no window span."""
+    per-operation seconds are averaged over them. Each part of each
+    idle gap of the device is charged to the INNERMOST host span that
+    covers it: a gap inside ``serve.commit`` inside ``serve.step``
+    inside ``bench.step`` counts for ``serve.commit`` alone, what only
+    ``serve.step`` covers is that span's own time, so the charges add
+    up to the idle time. Returns None when the trace holds no device
+    plane or no window span."""
     win = [e for e in events["spans"] if e[0] == window_span]
     if not win or not events["device"]:
         return None
     lo, hi = win[0][1], win[0][1] + win[0][2]
     n = len(events["device"])
     busy_ns, ops, counts, gaps_by_span = 0.0, {}, {}, {}
-    spans = [e for e in events["spans"] if e[0] != window_span]
+    pieces = innermost([e for e in events["spans"] if e[0] != window_span])
+    starts = [p[1] for p in pieces]
     for plane_events in events["device"].values():
         clipped = list(_clip(plane_events, lo, hi))
         merged = _union([(a, b) for _, a, b in clipped])
@@ -105,19 +141,19 @@ def reduce(events, window_span="bench.trace_window"):
         for name, a, b in clipped:
             ops[name] = ops.get(name, 0.0) + (b - a)
             counts[name] = counts.get(name, 0) + 1
-        # idle gaps: the complement of the union inside the window,
-        # each charged to the bench.* spans that cover it
+        # idle gaps: the complement of the union inside the window
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 <= g0:
                 continue
             left = g1 - g0
-            for name, a, b in _clip(spans, g0, g1):
+            first = max(0, bisect.bisect_right(starts, g0) - 1)
+            last = bisect.bisect_left(starts, g1)
+            for name, a, b in _clip(pieces[first:last], g0, g1):
                 gaps_by_span[name] = gaps_by_span.get(name, 0.0) + (b - a)
                 left -= b - a
             if left > 0:
-                gaps_by_span["(no span)"] = \
-                    gaps_by_span.get("(no span)", 0.0) + left
+                gaps_by_span[NO_SPAN] = gaps_by_span.get(NO_SPAN, 0.0) + left
     window_s = (hi - lo) / 1e9
     busy_s = busy_ns / n / 1e9
     return {
