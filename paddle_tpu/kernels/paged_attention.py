@@ -41,6 +41,13 @@ metadata, so a mixed batch of decode steps (q_len=1) and prefill chunks
   is exactly 0, and what lies there (the tail of a last page, a slab
   page that was not fetched) may be anything.
 
+A window layer (``window=W``, static: a query at position ``p`` sees
+keys ``p - W < j <= p``) is the same program with a lower bound: the
+walk starts at the slab that holds the first key the block's FIRST
+query may see, pages under that key's are not fetched (the pool has
+released them: ``serving/kv_cache.py``'s window group), and the mask
+gains ``j > p - W``. ``window=None`` lowers what it lowered before.
+
 GQA: each q block's ``[q_block * group, head_dim]`` rows ride one MXU
 matmul per slab and head; decode rows waste ``q_block - 1`` of those
 rows to padding, which is free in practice — the MXU tile is 128 rows.
@@ -77,12 +84,14 @@ def ragged_slab_pages(page_size, pages_per_seq):
 
 
 def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, page_size,
-                          pages_per_seq):
+                          pages_per_seq, window=None):
     """KV tokens ONE kv head's walk covers for a launch with these
     (numpy) row lengths: over the live q blocks, each block's causal
-    horizon rounded up to the slab. The roofline's floor counts a live
-    token once; this counts it once a q block that sees it (a 64-token
-    chunk at ``q_block`` 8 walks its prefix 8 times)."""
+    horizon rounded up to the slab, less (with a ``window``) the whole
+    slabs below the first key the block's first query may see. The
+    roofline's floor counts a live token once; this counts it once a q
+    block that sees it (a 64-token chunk at ``q_block`` 8 walks its
+    prefix 8 times)."""
     q_lens = np.asarray(q_lens, np.int64)
     kv_lens = np.asarray(kv_lens, np.int64)
     slab = ragged_slab_pages(page_size, pages_per_seq) * page_size
@@ -90,13 +99,16 @@ def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, page_size,
     row = np.repeat(np.arange(len(q_lens)), blocks)
     first = np.cumsum(blocks) - blocks           # a row's first block
     off = (np.arange(len(row)) - first[row]) * q_block
-    horizon = np.minimum(kv_lens[row],
-                         kv_lens[row] - q_lens[row] + off + q_block)
-    return int((-(-horizon // slab) * slab).sum())
+    start = kv_lens[row] - q_lens[row] + off     # the block's first query
+    horizon = np.minimum(kv_lens[row], start + q_block)
+    walked = -(-horizon // slab)
+    if window is not None:
+        walked = walked - np.maximum(start - window + 1, 0) // slab
+    return int((walked * slab).sum())
 
 
 def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
-                   page_size, q_block, scale, quantized):
+                   page_size, q_block, scale, quantized, window):
     ks_ref = vs_ref = None
     if quantized:
         # int8 pool: the per-(head, page) dequant scales ride the
@@ -125,15 +137,31 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
         live_block, jnp.minimum(kv_len, kv_start + blk_off + q_block), 0)
     n_pages = pl.cdiv(horizon, page_size)
     n_slabs = pl.cdiv(horizon, slab)
+    if window is None:
+        first_key = first_page = first_slab = 0
+    else:
+        # a window layer: a query at position p sees keys j with
+        # p - window < j <= p, so nothing below the block's FIRST
+        # query's window is seen by any of its queries. The walk starts
+        # at the slab that holds that key; pages under it were released
+        # by the pool (their table slots name the null page) and are
+        # not fetched
+        first_key = jnp.maximum(kv_start + blk_off - window + 1, 0)
+        first_page = first_key // page_size
+        first_slab = first_page // ppf
 
     def each_live_page(i, slot, op):
         """``op`` on the K and V copy of every live page of slab ``i``:
         one strided DMA a page brings all kv heads of it; pages of the
-        last slab past the block's last live page are not fetched."""
+        last slab past the block's last live page are not fetched, nor
+        those of the first under a window's first page."""
         for j in range(ppf):
             p = i * ppf + j
+            fetch = p < n_pages
+            if window is not None:
+                fetch &= p >= first_page
 
-            @pl.when(p < n_pages)
+            @pl.when(fetch)
             def _copy():
                 page = tbl_ref[row, p]
                 op(pltpu.make_async_copy(
@@ -145,9 +173,9 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(n_slabs > 0)
+    @pl.when(n_slabs > first_slab)
     def _first():
-        each_live_page(0, 0, lambda c: c.start())
+        each_live_page(first_slab, first_slab % 2, lambda c: c.start())
 
     def _slab(i, carry):
         slot = i % 2
@@ -166,13 +194,17 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
         tok = blk_off + jax.lax.broadcasted_iota(
             jnp.int32, (qb, grp, slab), 0)
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (qb, grp, slab), 2)
-        ok = ((tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)) \
-            .reshape(qb * grp, slab)
+        ok = (tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)
+        if window is not None:
+            ok &= pos > kv_start + tok - window
+        ok = ok.reshape(qb * grp, slab)
         # rows of V past the horizon are pool positions no query of this
         # block may see, or slab pages that were not fetched: their
         # weight is exactly 0, and 0 x what lies there must stay 0
-        seen = base + jax.lax.broadcasted_iota(
-            jnp.int32, (slab, d), 0) < horizon
+        vpos = base + jax.lax.broadcasted_iota(jnp.int32, (slab, d), 0)
+        seen = vpos < horizon
+        if window is not None:
+            seen &= vpos >= first_key
 
         def slab_of(buf, s_ref, h):
             """Head ``h`` of the slab as f32 ``[slab, d]``; an int8
@@ -214,7 +246,7 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
         jax.lax.fori_loop(0, hkv, _head, None, unroll=True)
         return carry
 
-    jax.lax.fori_loop(0, n_slabs, _slab, None)
+    jax.lax.fori_loop(first_slab, n_slabs, _slab, None)
 
     def _write(h, carry):
         o_ref[:, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)) \
@@ -242,7 +274,7 @@ def ragged_block_row(q_starts, num_blocks, q_block):
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
                            q_lens, kv_lens, *, q_block=8, scale=None,
                            interpret=False, k_scales=None, v_scales=None,
-                           block_row=None):
+                           block_row=None, window=None):
     """Mixed prefill-chunk + decode attention over a paged KV cache.
 
     q:            [total_q_tokens, num_q_heads, head_dim] — queries of
@@ -263,6 +295,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
     block_row:    optional precomputed :func:`ragged_block_row` result
         (``[total_q_tokens // q_block] int32``) — lets a fused prefill
         step derive the map once and share it across layers.
+    window:       None (every key up to the query's own), or the number
+        of keys a query sees, itself included: the token at position
+        ``p`` attends ``p - window < j <= p``. Static: a window layer
+        is another program. ``block_tables`` slots wholly under a row's
+        window may name any page (the pool releases them); they are
+        neither fetched nor seen.
     Returns [total_q_tokens, num_q_heads, head_dim]; padding rows hold
     garbage (finite, never NaN) and must be ignored by the caller.
     """
@@ -277,12 +315,15 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
                          f"{q_block}")
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window must be >= 1 or None, got {window}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     return _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
                         kv_lens, k_scales, v_scales, block_row,
                         q_block=q_block, scale=float(scale),
-                        interpret=interpret)
+                        interpret=interpret,
+                        window=None if window is None else int(window))
 
 
 # jitted on its own so that a step which calls the kernel once a layer
@@ -290,10 +331,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
 # unrolled copies and heads cost seconds of lowering a layer otherwise
 # (14 s of a 12-layer step's first call on the chip's host), and that
 # is paid before the compile cache can be asked
-@functools.partial(jax.jit, static_argnames=("q_block", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("q_block", "scale", "interpret",
+                                             "window"))
 def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
                  kv_lens, k_scales, v_scales, block_row, *, q_block, scale,
-                 interpret):
+                 interpret, window=None):
     t, hq, d = q.shape
     hkv, _, page_size, _ = k_pages.shape
     group = hq // hkv
@@ -360,7 +402,7 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size,
                           q_block=q_block, scale=scale,
-                          quantized=quantized),
+                          quantized=quantized, window=window),
         out_shape=jax.ShapeDtypeStruct((t, hkv, group, dl), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret, name="ragged_paged_attention",
@@ -421,9 +463,11 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
                                      q_starts, q_lens, kv_lens, scale=None,
-                                     k_scales=None, v_scales=None):
+                                     k_scales=None, v_scales=None,
+                                     window=None):
     """jnp oracle for the ragged kernel: per sequence, gather its pages
-    densely and run a causally-masked softmax over its chunk's queries;
+    densely and run a causally-masked softmax over its chunk's queries
+    (each seeing its last ``window`` keys only, where one is given);
     rows outside any live slot stay zero."""
     t, hq, d = q.shape
     hkv, _, ps, _ = k_pages.shape
@@ -454,6 +498,8 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
         limit = (kl - ql + np.arange(ql))[None, None, :, None]
         ok = (pos[None, None, None, :] <= limit) & \
             (pos[None, None, None, :] < kl)
+        if window is not None:
+            ok &= pos[None, None, None, :] > limit - window
         s = jnp.where(jnp.asarray(ok), s, _NEG_INF)
         w = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("hgqs,hsd->qhgd", w, v).reshape(ql, hq, d)

@@ -18,6 +18,7 @@ from .llama_moe import (  # noqa: F401
     LlamaMoeConfig, LlamaMoeModel, LlamaMoeForCausalLM,
     llama_moe_tiny_config,
 )
+from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM  # noqa: F401
 from .hf_interop import (  # noqa: F401
     llama_from_hf, load_llama_state_dict, llama_config_from_hf,
     bert_from_hf, load_bert_state_dict, bert_config_from_hf,

@@ -30,6 +30,7 @@ functions — change them here and the ragged step body together.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -235,11 +236,41 @@ _STACKED_LAYER_KEYS = {
 }
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one decoder layer is, as static data of its config: the
+    serving step's layer body (serving/spec_decode.py) reads it at trace
+    time, so layers of different kinds are one executable, not modes.
+    The default is a Llama layer."""
+    #: keys a query sees, itself included; None = every key before it
+    window: int | None = None
+    #: rotary embedding on q and k
+    rope: bool = True
+    #: RMSNorm over each q and k head (weights ``q_norm``/``k_norm``)
+    qk_norm: bool = False
+    #: "dense" SwiGLU, or "sparse": routed experts + a shared expert
+    mlp: str = "dense"
+
+
+def layer_kinds(cfg):
+    """The kind of each of ``cfg``'s layers: the config's own
+    ``layer_kinds()`` where it has one, else all Llama layers."""
+    own = getattr(cfg, "layer_kinds", None)
+    if own is not None:
+        return tuple(own())
+    return (LayerKind(),) * cfg.num_hidden_layers
+
+
 def extract_params(model):
-    """Pull the LlamaForCausalLM weights into a pure pytree. Scanned
+    """Pull a causal LM's weights into a pure pytree. A model whose
+    layers differ in kind says itself what its serving step indexes
+    (``serving_params()``); a LlamaForCausalLM is read here. Scanned
     models (FLAGS_scan_layers: ``m.layers`` is an nn.LayerStack) unstack
     the leading axis back into the per-layer dicts the decode/prefill
     bodies index."""
+    own = getattr(model, "serving_params", None)
+    if own is not None:
+        return own()
     from ..nn.scan_stack import LayerStack
     cfg = model.config
     m = model.model if hasattr(model, "model") else model

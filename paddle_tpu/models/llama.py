@@ -70,6 +70,32 @@ class LlamaConfig:
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
 
+    @classmethod
+    def check_published(cls, cfg):
+        """Refuse, each by name, what this class's model would silently
+        drop of a published ``config.json`` (``cfg``: the whole file as
+        a dict): full causal attention with heads of ``hidden_size /
+        num_attention_heads``, plain RoPE, SwiGLU, no bias anywhere. A
+        wrong model under a real name is worse than none."""
+        dropped = []
+        if cfg.get("sliding_window") is not None:
+            dropped.append("sliding_window is set; this path attends fully")
+        if cfg.get("hidden_act", "silu") != "silu":
+            dropped.append(f"hidden_act {cfg['hidden_act']!r} is not SwiGLU's")
+        hd = cfg.get("head_dim")
+        if hd is not None and hd * cfg["num_attention_heads"] \
+                != cfg["hidden_size"]:
+            dropped.append("head_dim x heads differs from hidden_size")
+        if cfg.get("rope_scaling") is not None:
+            dropped.append("rope_scaling is set; this path rotates by "
+                           "rope_theta alone")
+        for key in ("attention_bias", "mlp_bias"):
+            if cfg.get(key):
+                dropped.append(f"{key} is true; this path's projections "
+                               f"have no bias")
+        if dropped:
+            raise ValueError("LlamaConfig would drop: " + "; ".join(dropped))
+
 
 def llama2_7b_config():
     return LlamaConfig()

@@ -1,4 +1,14 @@
-"""Mixtral-style MoE Llama — the sparse flagship family.
+"""Mixtral-style MoE Llama — a TRAINING model whose gate drops tokens.
+
+What this is: a GShard gate with ``capacity_factor`` 1.25. Each expert
+gets 1.25 x its fair share of slots a batch, and a token whose expert is
+full is DROPPED (it passes through the residual alone); the gate's
+load-balancing aux loss is kept as layer state and added to the training
+loss. That is a training recipe. It is not what the serving path routes
+with: ``serving/engine.py`` has no body for this class's layers, and a
+serving step must not drop a token. The dropless, sigmoid-routed expert
+layer that is told which experts it holds is ``nn/moe_dropless.py`` (over
+``kernels/grouped_matmul.py``), run by ``models/exaone_moe.py``.
 
 The dense decoder's SwiGLU MLP is replaced (every
 ``moe_layer_interval``-th layer) by a GShard-gated mixture of SwiGLU
@@ -29,6 +39,12 @@ class LlamaMoeConfig(LlamaConfig):
     capacity_factor: float = 1.25
     moe_layer_interval: int = 1     # 1 = every layer is MoE (Mixtral)
     aux_loss_weight: float = 0.01
+
+    #: this class has not written down what its model would drop of a
+    #: published MoE file (its expert keys are its own, not a published
+    #: config's): it carries no check yet, so a harness that asks finds
+    #: the rules of its nearest base that has them (LlamaConfig's)
+    check_published = None
 
 
 class LlamaMoeDecoderLayer(nn.Layer):

@@ -1,0 +1,117 @@
+"""A dropless, sigmoid-routed expert layer that is told which experts it
+holds — the serving path's routed feed-forward (pure ``jax.numpy`` over
+raw arrays, like ``models/generation.py``'s bodies).
+
+The layer a chip runs in an expert-parallel deployment: the router scores
+ALL ``router_width`` experts in float32 and picks ``top_k`` a token; this
+chip holds the ``held`` experts ``[first, first + held)`` and computes the
+part of ``y = sum_i g_i E_i(x)`` whose experts it holds. The other chips'
+parts, and the exchange that would sum them, are not stood in for.
+
+Nothing is dropped: the token-expert pairs that land here are sorted by
+expert into a buffer sized for the worst routing (every token sending all
+its picks here), each expert's rows padded to whole row tiles, and the
+three projections run as grouped matrix products over it
+(``kernels/grouped_matmul.py``). ``incubate/distributed/models/moe``'s
+GShard gate, which ``models/llama_moe.py`` trains with, gives each expert
+``capacity_factor`` x its fair share of slots and drops the rest; a
+serving step must not.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..kernels.grouped_matmul import grouped_matmul
+
+F32 = jnp.float32
+
+
+def route_sigmoid(x, router, bias, *, top_k, scaling, norm_topk_prob=True):
+    """Experts and gates of every token. ``x [T, h]``; ``router [h,
+    router_width]``; ``bias [router_width]`` (the score-correction bias:
+    it steers the CHOICE, the gate is the raw score). Scores in float32
+    at full precision whatever ``x`` is stored in: a near-tie between
+    the k-th and (k+1)-th expert flips on less. Returns ``(idx [T, k]
+    int32, gates [T, k] float32)``."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
+                               precision="highest"))
+    _, idx = jax.lax.top_k(s + bias.astype(F32), top_k)
+    g = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk_prob:
+        g = g / jnp.sum(g, -1, keepdims=True)
+    return idx.astype(jnp.int32), g * scaling
+
+
+def buffer_rows(tokens, top_k, held, tm):
+    """Rows of the sorted buffer: every token's picks landing here (at
+    most ``min(top_k, held)`` a token), and a partly filled last tile
+    an expert."""
+    worst = tokens * min(top_k, held)
+    return -(-worst // tm) * tm + held * tm
+
+
+def dispatch_plan(idx, live, *, first, held, tm):
+    """Where each token-expert pair goes. ``idx [T, k]`` global expert
+    ids; ``live [T]`` bool (a dead slot of a packed step routes
+    nowhere). Returns a dict: ``here [T, k]`` bool (the pair's expert
+    is held and its token live), ``pair_row [T, k]`` the pair's row in
+    the sorted buffer, ``src [M]`` the token each buffer row reads,
+    ``tile_group [M / tm]``, ``live_tiles`` and ``counts [held]`` tokens
+    an expert."""
+    t, k = idx.shape
+    m = buffer_rows(t, k, held, tm)
+    here = (idx >= first) & (idx < first + held) & live[:, None]
+    local = jnp.where(here, idx - first, held).reshape(-1)   # held = away
+    order = jnp.argsort(local, stable=True)                  # by expert
+    counts = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    padded = -(-counts // tm) * tm
+    ends = jnp.cumsum(padded)
+    sorted_e = local[order]
+    start_sorted = jnp.cumsum(counts) - counts
+    e = jnp.minimum(sorted_e, held - 1)
+    row = jnp.where(sorted_e < held,
+                    (ends - padded)[e] + jnp.arange(t * k) - start_sorted[e],
+                    m)                                        # away: dropped
+    src = jnp.zeros((m,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.minimum(row, m - 1).astype(jnp.int32)).reshape(t, k)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(m // tm) * tm, side="right"),
+        held - 1).astype(jnp.int32)
+    return {"here": here, "pair_row": pair_row, "src": src,
+            "tile_group": tile_group, "live_tiles": ends[-1] // tm,
+            "counts": counts}
+
+
+def dropless_experts(x, idx, gates, live, gate_w, up_w, down_w, *, first,
+                     tm=128, interpret=False):
+    """This chip's part of the routed sum. ``x [T, h]``; ``idx``/``gates
+    [T, k]`` from :func:`route_sigmoid`; ``gate_w``/``up_w [held, h,
+    m]``, ``down_w [held, m, h]`` the held experts' SwiGLU weights.
+    Returns ``(y [T, h], stats [3] int32)``: ``y[t] = sum over t's
+    picks held here of gate x E(x[t])``, and (pairs computed here, held
+    experts with at least one token, most tokens any one expert got)."""
+    held = gate_w.shape[0]
+    plan = dispatch_plan(idx, live, first=first, held=held, tm=tm)
+    xs = x[plan["src"]]                                       # [M, h]
+
+    def mm(a, w):
+        return grouped_matmul(a, w, plan["tile_group"], plan["live_tiles"],
+                              tm=tm, interpret=interpret)
+    act = (jax.nn.silu(mm(xs, gate_w).astype(F32))
+           * mm(xs, up_w).astype(F32)).astype(x.dtype)
+    out = mm(act, down_w)                                     # [M, h]
+    # every row of ``out`` is finite (dead tiles are zeros, a live tile's
+    # padding rows read a real token), so a gate of 0 masks a pair away
+    # (an elementwise product: a dot would round the f32 gates on the chip)
+    y = jnp.sum(out[plan["pair_row"]].astype(F32)
+                * jnp.where(plan["here"], gates, 0.0)[..., None], 1)
+    c = plan["counts"]
+    stats = jnp.stack([jnp.sum(c), jnp.sum(c > 0), jnp.max(c)])
+    return y.astype(x.dtype), stats.astype(jnp.int32)
+
+
+__all__ = ["buffer_rows", "dispatch_plan", "dropless_experts",
+           "route_sigmoid"]

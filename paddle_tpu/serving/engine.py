@@ -63,8 +63,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.generation import (_logits, _rms_norm, _rope, _wmat,
-                                 extract_params, request_keys, sample_rows)
+from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
+                                 _wmat, extract_params, layer_kinds,
+                                 request_keys, sample_rows)
 from ..kernels.paged_attention import (ragged_kv_tokens_read,
                                        ragged_paged_attention)
 from ..profiler import spans
@@ -354,6 +355,41 @@ class LLMEngine:
         #: bit-identical to the pre-burst engine.
         self.burst_tokens = burst_tokens
         self.cfg = cfg = model.config
+        # what each layer is (models/generation.py LayerKind): static
+        # data of the config. A model whose layers are not all Llama
+        # layers (q/k head norm, window layers, routed experts) runs
+        # through the one ragged step; the modes that have not been
+        # carried over to such layers are refused here, by name
+        self._kinds = layer_kinds(cfg)
+        self._plain_layers = all(k == LayerKind() for k in self._kinds)
+        windows = sorted({k.window for k in self._kinds if k.window})
+        if not self._plain_layers:
+            refused = {
+                "quantized_mode": quantized_mode is not None,
+                "kv_cache_dtype int8": kv_cache_dtype in (
+                    "int8", jnp.int8, jnp.dtype(jnp.int8)),
+                "prefill_megakernel='fused'":
+                    self.prefill_megakernel == "fused",
+                "megakernel_scope='model'": self.megakernel_scope == "model",
+                "burst_tokens > 1": burst_tokens > 1,
+                "draft_model": draft_model is not None,
+                "adapter_slots / adapter_store": bool(adapter_slots),
+                "mesh": mesh is not None,
+                "host_kv_pages": bool(host_kv_pages),
+                "prefix_caching=True (with window layers: a fork would "
+                "need window pages that were released)":
+                    bool(windows) and (bool(prefix_caching)
+                                       or prefix_store is not None
+                                       or bool(pinned_prefix_pages)
+                                       or fleet_prefix_cache is not None),
+                "layers with different windows": len(windows) > 1,
+            }
+            if any(refused.values()):
+                raise ValueError(
+                    f"LLMEngine: {type(cfg).__name__}'s layers are not all "
+                    f"Llama layers, and these have not been carried over "
+                    f"to them: " + "; ".join(k for k, v in refused.items()
+                                             if v))
         self.params = extract_params(model)
         # low-bit serving weights: the jitted ragged step traces over a
         # quantized pytree; projections run the fused dequant-matmul
@@ -453,12 +489,25 @@ class LLMEngine:
                 prefetch_depth=self._kv_prefetch_depth,
                 spill_seed=kv_spill_seed)
         else:
+            # window layers keep their pages in a second group of the
+            # pool (kv_cache.py), sized so that every row slot can hold
+            # its bound: that group never preempts
+            group = {}
+            if windows:
+                group = dict(
+                    window_layers=[i for i, k in enumerate(self._kinds)
+                                   if k.window],
+                    window=windows[0],
+                    window_pages=1 + max_num_seqs
+                    * PagedKVPool.window_pages_per_row(
+                        windows[0], chunk_size, page_size))
             self.pool = PagedKVPool(
                 cfg.num_hidden_layers, cfg.num_key_value_heads,
                 cfg.head_dim, num_pages=num_pages, page_size=page_size,
                 dtype=dtype, high_watermark=high_watermark,
                 low_watermark=low_watermark,
-                pinned_page_budget=pinned_prefix_pages, mesh=self.mesh)
+                pinned_page_budget=pinned_prefix_pages, mesh=self.mesh,
+                **group)
         self._tiered = hasattr(self.pool, "arena")
         # gauge_stale_after_s: snapshot-side staleness horizon — gauges
         # last set longer ago than this read as null (listed under
@@ -693,12 +742,13 @@ class LLMEngine:
         scope = self.megakernel_scope
         num_layers = cfg.num_hidden_layers
         prefill_fused = self.prefill_megakernel == "fused"
+        kinds = self._kinds
 
         def ragged_step(params, kv, kv_scales, tokens, positions, tbls,
                         q_starts, q_lens, kv_lens, sample_idx, temps,
                         top_ks, top_ps, seeds, sample_pos, spec_lens,
                         draft_tokens, draft_probs, base_key,
-                        adapters, adapter_slots):
+                        adapters, adapter_slots, tbls_w):
             # tokens/positions [T] packed row-wise (pad rows: q_len=0,
             # q_start=T); tbls [R, PPS]; kv_lens = committed + q_len per
             # row (the attention length AFTER this step's appends);
@@ -716,7 +766,10 @@ class LLMEngine:
             # ZERO operands (empty pytrees), so adapter-free engines
             # lower byte-identical HLO; with a registry, which adapter
             # a token wears is a gather — data, never shape.
+            # tbls_w: the window page group's tables (None, an empty
+            # pytree, for a model without window layers).
             tok_row = live = pre = None
+            moe_stats = []
             if prefill_fused:
                 # the layer-invariant ragged prologue, hoisted: rope
                 # phase tables, the page-slot scatter map, the packed
@@ -738,7 +791,7 @@ class LLMEngine:
                 A, B = ad[p]
                 return (A, B, adapter_slots)
 
-            def fp_layer(lyr, ad, h, Kp, Vp):
+            def fp_layer(lyr, ad, h, Kp, Vp, kind=LayerKind()):
                 if prefill_fused:
                     from ..kernels.prefill_megakernel import \
                         fused_prefill_layer
@@ -754,9 +807,11 @@ class LLMEngine:
                 # draft worker also runs — draft/target numerics come
                 # from ONE definition
                 return _ragged_fp_layer(
-                    lyr, h, Kp, Vp, positions, tbls, tok_row, live,
+                    lyr, h, Kp, Vp, positions,
+                    tbls_w if kind.window else tbls, tok_row, live,
                     q_starts, q_lens, kv_lens, cfg, ps, PPS, qb,
-                    interpret, adapters=ad, slots=adapter_slots)
+                    interpret, adapters=ad, slots=adapter_slots,
+                    kind=kind, moe_stats=moe_stats)
 
             def int8_layer(lyr, ad, h, Kp, Ks, Vp, Vs):
                 if prefill_fused:
@@ -851,7 +906,7 @@ class LLMEngine:
                         zip(params["layers"], kv)):
                     ad = adapters[li] if adapters is not None else None
                     if not quant_pool:
-                        h, Kp, Vp = fp_layer(lyr, ad, h, Kp, Vp)
+                        h, Kp, Vp = fp_layer(lyr, ad, h, Kp, Vp, kinds[li])
                         new_kv.append((Kp, Vp))
                         continue
                     Ks, Vs = kv_scales[li]
@@ -874,6 +929,15 @@ class LLMEngine:
             out, n_out = speculative_sample(
                 logits, draft_tokens, draft_probs, spec_lens, temps,
                 top_ks, top_ps, base_key, seeds, sample_pos)
+            if moe_stats:
+                # the routed layers' counts ride home behind n_out, in
+                # the transfer the host makes anyway: pairs computed
+                # here and held experts touched, summed over layers, and
+                # the most tokens any one expert got
+                st = jnp.stack(moe_stats)
+                n_out = jnp.concatenate([
+                    n_out, jnp.stack([st[:, 0].sum(), st[:, 1].sum(),
+                                      st[:, 2].max()]).astype(n_out.dtype)])
             return (out, n_out, finite, new_kv,
                     new_scales if quant_pool else None)
 
@@ -1261,6 +1325,13 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # disaggregated serving: KV handoff (serving/fabric.py KVFabric)
     # ------------------------------------------------------------------
+    def _llama_layers_only(self, what):
+        if not self._plain_layers:
+            raise ValueError(
+                f"LLMEngine.{what}: {type(self.cfg).__name__}'s layers are "
+                f"not all Llama layers, and the handoff's wire format has "
+                f"not been carried over to them")
+
     def extract_request(self, request_id) -> dict:
         """Pull a caught-up RUNNING request out of this engine for a
         prefill->decode handoff: its committed KV pages leave as the
@@ -1271,6 +1342,7 @@ class LLMEngine:
         bit-identically on another replica. Only a caught-up row
         (``uncached_len == 1`` with at least the first token sampled)
         extracts — mid-prefill rows keep chunking here."""
+        self._llama_layers_only("extract_request")
         seq = self._seqs.get(request_id)
         if seq is None:
             raise KeyError(f"unknown request {request_id!r}")
@@ -1319,6 +1391,7 @@ class LLMEngine:
         its next sampled token is a pure function of (seed, position),
         so the handoff is invisible in the token stream. Counted on
         ``kv_pages_transferred``."""
+        self._llama_layers_only("inject_request")
         rid = payload["request_id"]
         if rid in self._seqs or rid in self._outputs:
             raise KeyError(f"duplicate request_id {rid!r}")
@@ -1454,7 +1527,9 @@ class LLMEngine:
                 z((R,), jnp.int32), z((R,), jnp.int32),
                 self._zero_draft[0], self._zero_draft[1], self._base_key,
                 self.adapters.slab if self.adapters is not None else None,
-                z((T,), jnp.int32) if self.adapters is not None else None)
+                z((T,), jnp.int32) if self.adapters is not None else None,
+                jnp.full((R, PPS), NULL_PAGE, jnp.int32)
+                if self.pool.window_layers else None)
 
     def _zero_burst_args(self):
         """Zero-filled burst-step operands at the exact launch shapes."""
@@ -1849,6 +1924,9 @@ class LLMEngine:
         self.flight.record("step", self._now(), **f)
         sp.set(used_pages=f["used_pages"], num_pages=self.pool.capacity,
                max_num_seqs=self.max_num_seqs)
+        if self.pool.window_layers:
+            sp.set(window_pages_used=self.pool.window_pages_used,
+                   window_pages=self.pool.window_capacity)
         return list(touched.values())
 
     def run(self, max_steps=None):
@@ -2220,6 +2298,7 @@ class LLMEngine:
         tokens = np.zeros((T,), np.int32)
         positions = np.zeros((T,), np.int32)
         tbls = np.full((R, PPS), NULL_PAGE, np.int32)
+        tbls_w = tbls.copy() if self.pool.window_layers else None
         q_starts = np.full((R,), T, np.int32)   # pad rows: start past T
         q_lens = np.zeros((R,), np.int32)
         kv_lens = np.zeros((R,), np.int32)
@@ -2255,6 +2334,8 @@ class LLMEngine:
             tokens[q_start:q_start + q_len] = row_toks
             positions[q_start:q_start + q_len] = np.arange(lo, lo + q_len)
             tbls[i] = self.pool.padded_block_table(seq.seq_id, PPS)
+            if tbls_w is not None:
+                tbls_w[i] = self.pool.padded_window_table(seq.seq_id, PPS)
             q_starts[i] = q_start
             q_lens[i] = q_len
             kv_lens[i] = lo + q_len
@@ -2269,14 +2350,30 @@ class LLMEngine:
             spec_lens[i] = spec
             if slot_ids is not None and seq.adapter_slot:
                 slot_ids[q_start:q_start + q_len] = seq.adapter_slot
+        # by layer kind: the layers that see every key, and those that
+        # see a window (all of one width, LLMEngine.__init__)
+        window = self.pool.window
+        n_win = len(self.pool.window_layers)
+        n_full = len(self._kinds) - n_win
+
+        def walked(w):
+            return ragged_kv_tokens_read(
+                q_lens, kv_lens, q_block=self.q_block,
+                page_size=self.page_size, pages_per_seq=PPS, window=w)
+        live_kv = int(kv_lens.sum())
         sp.set(rows=len(plan.rows), prefill_tokens=prefill_tokens,
                decode_tokens=int(q_lens.sum()) - prefill_tokens,
                # what attention must read: every row's context
-               live_kv_tokens=int(kv_lens.sum()),
-               # what the ragged kernel's walk covers, a kv head
-               attn_kv_tokens_read=ragged_kv_tokens_read(
-                   q_lens, kv_lens, q_block=self.q_block,
-                   page_size=self.page_size, pages_per_seq=PPS))
+               live_kv_tokens=live_kv,
+               # the same summed over the layers, a window layer's rows
+               # counted up to window + chunk
+               attn_kv_tokens_live=n_full * live_kv + (n_win and n_win * int(
+                   np.minimum(kv_lens, window + q_lens).sum())),
+               # what the ragged kernel's walk covers, a kv head, a
+               # layer (the mean over layers where they differ)
+               attn_kv_tokens_read=((n_full and n_full * walked(None))
+                                    + (n_win and n_win * walked(window)))
+               // len(self._kinds))
         sp.phase("serve.dispatch")
         out, n_out, finite, new_kv, new_scales = self._ragged_jit(
             self._ragged_params, self.pool.kv, self.pool.kv_scales,
@@ -2288,12 +2385,20 @@ class LLMEngine:
             jnp.asarray(spec_lens), jnp.asarray(draft_tokens),
             jnp.asarray(draft_probs), self._base_key,
             self.adapters.slab if self.adapters is not None else None,
-            jnp.asarray(slot_ids) if slot_ids is not None else None)
+            jnp.asarray(slot_ids) if slot_ids is not None else None,
+            jnp.asarray(tbls_w) if tbls_w is not None else None)
         self.pool.kv = new_kv
         if new_scales is not None:
             self.pool.kv_scales = new_scales
         sp.phase("serve.wait")
-        return np.asarray(out), np.asarray(n_out), np.asarray(finite)
+        n_out = np.asarray(n_out)
+        if len(n_out) > R:
+            # routed layers' counts, behind the rows' (ragged_step)
+            pairs, touched, most = (int(x) for x in n_out[R:])
+            sp.set(moe_pairs_held=pairs, moe_experts_touched=touched,
+                   moe_max_expert_tokens=most)
+            n_out = n_out[:R]
+        return np.asarray(out), n_out, np.asarray(finite)
 
     def _launch_spec(self, plan, touched, sp):
         """One speculative round: draft sync + k proposal steps, then

@@ -39,6 +39,19 @@ a CoW copy first: an append can requantize the whole page in place
 so the engine restricts int8 prefix sharing to FULL pages, which are
 append-free, and ``cow_page`` copies the page's scale row with its data.
 
+Two page groups (``window_layers`` + ``window``): full-attention layers
+keep a row's pages for its life, as above; window layers (a query sees
+its last ``window`` keys only) hold a BOUNDED set a row in a second group
+with its own page count, free list and block tables. A window table is
+indexed by the same logical page as the full one; ``prepare_append``
+releases the pages that lie wholly under the window of the first token
+it is about to append (their slots then name the null page, and the
+kernel's windowed walk never reaches them), so a row holds at most
+``window + chunk`` tokens of window pages whatever its context.
+Admission, preemption with recompute, ``free`` and ``check_invariants``
+cover both groups; sharing (fork, pins, export/adopt, the host tier)
+knows one kind of page and is refused by name on such a pool.
+
 The device arrays themselves live in ``kv`` (one (K, V) pair per layer)
 and are updated *functionally* by the engine's jitted ragged step (the
 engine reassigns ``kv`` after each donated call); this class tracks the
@@ -118,9 +131,31 @@ class PagedKVPool:
 
     def __init__(self, num_layers, num_kv_heads, head_dim, *, num_pages,
                  page_size, dtype=jnp.float32, high_watermark=0.90,
-                 low_watermark=0.50, pinned_page_budget=0, mesh=None):
+                 low_watermark=0.50, pinned_page_budget=0, mesh=None,
+                 window_layers=(), window=None, window_pages=None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
+        # the second page group: which layers' pages live in it, how
+        # many keys a query of theirs sees, how many pages it has
+        self.window_layers = tuple(sorted(int(i) for i in window_layers))
+        self.window = None
+        self.window_num_pages = 0
+        if self.window_layers:
+            if window is None or int(window) < 1:
+                raise ValueError("window_layers need a window >= 1")
+            if window_pages is None or int(window_pages) < 2:
+                raise ValueError("window_layers need window_pages >= 2 "
+                                 "(page 0 is reserved)")
+            if self.window_layers[0] < 0 \
+                    or self.window_layers[-1] >= num_layers:
+                raise ValueError(f"window_layers {self.window_layers} "
+                                 f"outside 0..{num_layers - 1}")
+            if mesh is not None or jnp.dtype(dtype) == jnp.dtype(jnp.int8):
+                raise ValueError(
+                    "a pool with window layers is single-device and "
+                    "unquantized: a mesh and int8 pages know one group")
+            self.window = int(window)
+            self.window_num_pages = int(window_pages)
         # tensor-parallel pool: pages (and int8 scale rows) shard over
         # the mesh's model axis on dim 0 — the kv-head axis — so each
         # device holds Hkv/tp heads' pages. The jitted ragged step's
@@ -145,9 +180,13 @@ class PagedKVPool:
         self.low_watermark = low_watermark
         self.dtype = jnp.dtype(dtype)
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
-        shape = (num_kv_heads, num_pages, page_size, head_dim)
-        self.kv = [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-                   for _ in range(num_layers)]
+        self.kv = []
+        for i in range(num_layers):
+            n = self.window_num_pages if i in self.window_layers \
+                else num_pages
+            shape = (num_kv_heads, n, page_size, head_dim)
+            self.kv.append((jnp.zeros(shape, dtype),
+                            jnp.zeros(shape, dtype)))
         # per-(head, page) dequant scales for int8 pools; zero-init so a
         # fresh page's first append sets the scale from its own amax
         # instead of inheriting a fabricated range
@@ -181,6 +220,10 @@ class PagedKVPool:
         self._pin_counts: dict[int, int] = {}
         #: lifetime count of pinned chains evicted (budget or pressure)
         self.pin_evictions = 0
+        #: the window group's free list and tables: logical page ->
+        #: window-pool page, NULL_PAGE where released or not yet claimed
+        self._wfree = list(range(self.window_num_pages - 1, NULL_PAGE, -1))
+        self._wtables: dict[object, list[int]] = {}
 
     # ---- byte accounting (pool sizing / bench fields) ----
     @staticmethod
@@ -208,9 +251,18 @@ class PagedKVPool:
 
     @property
     def page_bytes(self) -> int:
-        return self.page_bytes_for(self.num_layers, self.num_kv_heads,
-                                   self.head_dim, self.page_size,
-                                   self.dtype)
+        """Bytes of one page of the full group (every layer's, without
+        window layers)."""
+        return self.page_bytes_for(
+            self.num_layers - len(self.window_layers), self.num_kv_heads,
+            self.head_dim, self.page_size, self.dtype)
+
+    @property
+    def window_page_bytes(self) -> int:
+        """Bytes of one page of the window group, over its layers."""
+        return self.page_bytes_for(len(self.window_layers),
+                                   self.num_kv_heads, self.head_dim,
+                                   self.page_size, self.dtype)
 
     @property
     def kv_bytes_per_token(self) -> float:
@@ -220,7 +272,8 @@ class PagedKVPool:
 
     @property
     def pool_bytes(self) -> int:
-        return self.page_bytes * self.num_pages
+        return self.page_bytes * self.num_pages \
+            + self.window_page_bytes * self.window_num_pages
 
     @property
     def model_parallel_degree(self) -> int:
@@ -254,6 +307,31 @@ class PagedKVPool:
     @property
     def utilization(self) -> float:
         return self.used_pages / self.capacity
+
+    @property
+    def window_capacity(self) -> int:
+        """Allocatable pages of the window group (0 without one)."""
+        return max(self.window_num_pages - 1, 0)
+
+    @property
+    def window_pages_used(self) -> int:
+        return self.window_capacity - len(self._wfree)
+
+    @staticmethod
+    def window_pages_per_row(window, chunk_tokens, page_size) -> int:
+        """The most window-group pages one row holds when it appends at
+        most ``chunk_tokens`` a step: the pages that cover the first
+        new token's window and the chunk, one more for their
+        misalignment with page boundaries."""
+        return -(-(window - 1 + chunk_tokens) // page_size) + 1
+
+    def window_row_bound(self, chunk_tokens: int) -> int:
+        """:meth:`window_pages_per_row` of this pool (0 without window
+        layers)."""
+        if not self.window_layers:
+            return 0
+        return self.window_pages_per_row(self.window, chunk_tokens,
+                                         self.page_size)
 
     @property
     def logical_pages(self) -> int:
@@ -291,7 +369,14 @@ class PagedKVPool:
         return -(-max(num_tokens, 0) // self.page_size)
 
     def can_allocate(self, num_tokens: int) -> bool:
-        return self.pages_for(num_tokens) <= len(self._free)
+        return self.pages_for(num_tokens) <= len(self._free) \
+            and self.window_can_hold(num_tokens)
+
+    def window_can_hold(self, num_tokens: int) -> bool:
+        """Whether the window group has the pages a NEW row's first
+        ``num_tokens`` tokens take (always, without window layers)."""
+        return not self.window_layers \
+            or self.pages_for(num_tokens) <= len(self._wfree)
 
     @property
     def pinned_pages(self) -> int:
@@ -391,10 +476,14 @@ class PagedKVPool:
         """Claim pages for a new sequence of ``num_tokens`` tokens."""
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
+        self._window_need(None, 0, num_tokens)     # raises if short
         pages = self._claim(self.pages_for(num_tokens),
                             f"allocate {num_tokens} tokens")
         self._tables[seq_id] = pages
         self._lens[seq_id] = num_tokens
+        if self.window_layers:
+            self._wtables[seq_id] = []
+            self._window_extend(seq_id, 0, num_tokens)
         return pages
 
     def fork(self, seq_id, parent_id, num_tokens: int | None = None
@@ -406,6 +495,7 @@ class PagedKVPool:
         ``seq_len(seq_id) == num_tokens`` committed tokens; its first
         append into a partially-filled shared tail page triggers a
         copy-on-write duplication (``prepare_append``)."""
+        self._one_group("fork")
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
         parent = self._tables[parent_id]
@@ -429,11 +519,73 @@ class PagedKVPool:
         """
         table = self._tables[seq_id]
         need = self.pages_for(new_len) - len(table)
+        old_len = self._lens[seq_id]
+        self._window_need(seq_id, old_len, new_len)     # raises if short
         fresh = self._claim(max(need, 0),
                             f"extend {seq_id!r} to {new_len} tokens")
         table.extend(fresh)
-        self._lens[seq_id] = max(new_len, self._lens[seq_id])
+        if self.window_layers:
+            self._window_extend(seq_id, old_len, new_len)
+        self._lens[seq_id] = max(new_len, old_len)
         return fresh
+
+    # ---- the window group ----
+    def _one_group(self, what):
+        if self.window_layers:
+            raise ValueError(
+                f"PagedKVPool.{what}: this pool has window layers, and "
+                f"page sharing, export and the host tier know one kind "
+                f"of page")
+
+    def _window_first(self, pos: int) -> int:
+        """Logical page of the first key the query at ``pos`` sees."""
+        return max(pos - self.window + 1, 0) // self.page_size
+
+    def _window_missing(self, seq_id, old_len, new_len):
+        """Logical pages the window group must map for ``seq_id`` to
+        append ``[old_len, new_len)`` and does not yet."""
+        wt = self._wtables.get(seq_id, [])
+        return [p for p in range(self._window_first(old_len),
+                                 self.pages_for(new_len))
+                if p >= len(wt) or wt[p] == NULL_PAGE]
+
+    def _window_need(self, seq_id, old_len, new_len):
+        if not self.window_layers:
+            return
+        need = len(self._window_missing(seq_id, old_len, new_len))
+        if need > len(self._wfree):
+            raise PoolExhausted(
+                f"window group: {seq_id!r} to {new_len} tokens needs "
+                f"{need} pages, {len(self._wfree)} free of "
+                f"{self.window_capacity}")
+
+    def _window_extend(self, seq_id, old_len, new_len):
+        wt = self._wtables[seq_id]
+        wt.extend([NULL_PAGE] * (self.pages_for(new_len) - len(wt)))
+        for p in self._window_missing(seq_id, old_len, new_len):
+            wt[p] = self._wfree.pop()
+
+    def _window_release(self, seq_id, old_len):
+        """Give back the pages that lie wholly under the window of the
+        token at ``old_len``, the first that will be appended next: no
+        later query sees them."""
+        wt = self._wtables[seq_id]
+        for p in range(min(self._window_first(old_len), len(wt))):
+            if wt[p] != NULL_PAGE:
+                self._wfree.append(wt[p])
+                wt[p] = NULL_PAGE
+
+    def window_block_table(self, seq_id) -> list[int]:
+        return list(self._wtables[seq_id])
+
+    def padded_window_table(self, seq_id, pages: int) -> list[int]:
+        """The window group's table at a fixed launch width; released
+        and unclaimed slots name the null page."""
+        wt = self._wtables[seq_id]
+        if len(wt) > pages:
+            raise ValueError(
+                f"{seq_id!r} spans {len(wt)} pages > launch width {pages}")
+        return wt + [NULL_PAGE] * (pages - len(wt))
 
     def prepare_append(self, seq_id, new_len: int) -> int:
         """Make ``[seq_len, new_len)`` safely writable for ``seq_id``:
@@ -448,6 +600,10 @@ class PagedKVPool:
         if new_len < old_len:
             raise ValueError(f"append cannot shrink {seq_id!r}: "
                              f"{old_len} -> {new_len}")
+        if self.window_layers:
+            # first, so that a row's own released pages can serve it
+            self._window_release(seq_id, old_len)
+            self._window_need(seq_id, old_len, new_len)
         need_fresh = max(self.pages_for(new_len) - len(table), 0)
         first = old_len // self.page_size
         last = self.pages_for(new_len)          # exclusive logical bound
@@ -499,6 +655,8 @@ class PagedKVPool:
         floor. Returns the number of pages actually recycled."""
         pages = self._tables.pop(seq_id)
         self._lens.pop(seq_id, None)
+        self._wfree.extend(p for p in self._wtables.pop(seq_id, ())
+                           if p != NULL_PAGE)
         return self._release_pages(pages)
 
     # ---- pinned prefix chains (LRU page cache over the pool) ----
@@ -511,6 +669,7 @@ class PagedKVPool:
         instead of re-prefilling. Re-pinning an existing chain refreshes
         its LRU recency. Returns False (and pins nothing) when the
         budget is 0 or the chain alone exceeds it."""
+        self._one_group("pin")
         if num_tokens % self.page_size != 0:
             raise ValueError(
                 f"pinned chains must be page-aligned: {num_tokens} "
@@ -614,6 +773,7 @@ class PagedKVPool:
         the prefill side of a KV handoff. Returns ``(num_tokens,
         layers)`` in the arena/adopt wire format. Read-only: refcounts,
         tables, and sharing are untouched."""
+        self._one_group("export_pages")
         if num_tokens is None:
             num_tokens = self._lens[seq_id]
         if num_tokens > self._lens[seq_id]:
@@ -637,6 +797,7 @@ class PagedKVPool:
         claimed even after LRU pin eviction. The two-tier pool overrides
         this to stage into the host arena instead (the sequence lands
         PARKED and rides the prefetch/restore path into HBM)."""
+        self._one_group("adopt_sequence")
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
         if len(layers) != self.num_layers:
@@ -689,6 +850,7 @@ class PagedKVPool:
         raises ``ValueError`` on geometry violations (the engine wraps
         shape/dtype drift in its structured mismatch error before this
         layer ever sees it)."""
+        self._one_group("restore_pinned_chain")
         if num_tokens % self.page_size != 0:
             raise ValueError(
                 f"restored chains must be page-aligned: {num_tokens} "
@@ -758,6 +920,7 @@ class PagedKVPool:
         into a new sequence — the cold-prompt analog of :meth:`fork`
         (zero data movement, refcount + 1 per page). Touches the
         chain's LRU recency."""
+        self._one_group("fork_pinned")
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
         pages, pinned_tokens = self._pins[chain_id]
@@ -804,6 +967,12 @@ class PagedKVPool:
                 f"rollback cannot grow {seq_id!r}: {cur} -> {new_len}")
         if new_len < 0:
             raise ValueError(f"negative rollback length {new_len}")
+        if self.window_layers and any(
+                p == NULL_PAGE for p in self._wtables[seq_id][
+                    self._window_first(new_len):self.pages_for(new_len)]):
+            raise ValueError(
+                f"rollback of {seq_id!r} to {new_len} reaches under its "
+                f"window: those pages were released")
         self._lens[seq_id] = new_len
 
     def padded_block_table(self, seq_id, pages: int) -> list[int]:
@@ -832,6 +1001,8 @@ class PagedKVPool:
             "pin_counts": dict(self._pin_counts),
             "num_sequences": len(self._tables),
             "offending_pages": sorted(set(offending_pages)),
+            "window_used_pages": self.window_pages_used,
+            "window_free_list_size": len(self._wfree),
         }
 
     def _invariant_fail(self, reason, pages=()):
@@ -928,7 +1099,42 @@ class PagedKVPool:
         if self.used_pages != len(mapped):
             fail(f"used_pages {self.used_pages} != {len(mapped)} "
                  f"mapped pages")
+        if self.window_layers:
+            self._check_window_invariants()
         return True
+
+    def _check_window_invariants(self):
+        """The window group: a page is held by one row or free, never
+        both and never the null page; every row maps exactly the pages
+        from its next token's window to its length's end that it was
+        granted (claimed pages may run ahead of the committed length,
+        never behind the window); held + free == capacity."""
+        fail = self._invariant_fail
+        if set(self._wtables) != set(self._tables):
+            fail("window tables and full tables name different sequences")
+        held = {}
+        for sid, wt in self._wtables.items():
+            first = self._window_first(self._lens[sid])
+            for i, p in enumerate(wt):
+                if p == NULL_PAGE:
+                    if first <= i < self.pages_for(self._lens[sid]):
+                        fail(f"window table {sid!r}: logical page {i} "
+                             f"inside the window is not mapped")
+                    continue
+                if p in held:
+                    fail(f"window page {p} held by {held[p]!r} and "
+                         f"{sid!r}", [p])
+                held[p] = sid
+        free = set(self._wfree)
+        if len(free) != len(self._wfree):
+            fail("window free list has duplicates")
+        if NULL_PAGE in free or NULL_PAGE in held:
+            fail("null page in the window group", [NULL_PAGE])
+        if free & set(held):
+            fail("window page both held and free", free & set(held))
+        if len(held) + len(free) != self.window_capacity:
+            fail(f"window page leak: {len(held)} held + {len(free)} free "
+                 f"!= capacity {self.window_capacity}")
 
 
 __all__ = ["InvariantViolation", "PagedKVPool", "PoolExhausted",

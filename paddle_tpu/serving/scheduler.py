@@ -377,7 +377,10 @@ class Scheduler:
                 # (a pool full of evictable prefix cache must still
                 # admit)
                 avail = self.pool.available_pages
-            if n_pages > avail:
+            # a pool with window layers prices the first chunk in its
+            # second page group too (kv_cache.py)
+            if n_pages > avail or not (
+                    parked or self.pool.window_can_hold(first_len)):
                 break
             # watermark admission control: above the high watermark stop
             # taking new work (leave headroom for running seqs to grow),
@@ -465,7 +468,10 @@ class Scheduler:
                 first_len = min(self.config.chunk_size, seq.total_len)
                 n_pages = self.pool.pages_for(first_len)
                 avail = self.pool.available_pages
-            if n_pages > avail:
+            # a pool with window layers prices the first chunk in its
+            # second page group too (kv_cache.py)
+            if n_pages > avail or not (
+                    parked or self.pool.window_can_hold(first_len)):
                 break
             busy = bool(self.running) or bool(admitted)
             if busy:

@@ -47,9 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from ..core.flags import define_flag
-from ..models.generation import (_logits, _rms_norm, _rope, _wmat,
-                                 extract_params, request_keys, sample_rows,
-                                 sampling_probs)
+from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
+                                 _wmat, extract_params, request_keys,
+                                 sample_rows, sampling_probs)
 from ..kernels.paged_attention import ragged_paged_attention
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 
@@ -96,10 +96,19 @@ def _ragged_packing(q_starts, q_lens, T):
 
 def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                      q_starts, q_lens, kv_lens, cfg, page_size, max_pages,
-                     q_block, interpret, *, adapters=None, slots=None):
+                     q_block, interpret, *, adapters=None, slots=None,
+                     kind=LayerKind(), moe_stats=None):
     """One fp decoder layer of the ragged forward: qkv proj -> rope ->
     page scatter append -> ragged attention -> o proj -> mlp. Returns
     ``(h, Kp, Vp)``.
+
+    ``kind`` (``models.generation.LayerKind``, static) says what the
+    layer is where it is not a Llama layer: RMSNorm over each q and k
+    head, rotary or none, a window handed to the kernel (``tbls`` and
+    the pools are then its page group's), a dense or a routed
+    feed-forward (``nn/moe_dropless.py``; a routed layer appends its
+    ``[3]`` counts to the list ``moe_stats``). Layers of every kind
+    trace into the one executable.
 
     This is THE fp layer body — the engine's ragged step (fp pools) and
     the draft worker's forward both call it, so draft/target numerics
@@ -130,8 +139,12 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
     k = _wmat(x, lyr["k"], lora=lo("k")).reshape(1, T, Hkv, d)
     v = _wmat(x, lyr["v"], lora=lo("v")).reshape(1, T, Hkv, d)
-    q = _rope(q, positions[None], cfg.rope_theta, d)
-    k = _rope(k, positions[None], cfg.rope_theta, d)
+    if kind.qk_norm:
+        q = _rms_norm(q, lyr["q_norm"], cfg.rms_norm_eps)
+        k = _rms_norm(k, lyr["k_norm"], cfg.rms_norm_eps)
+    if kind.rope:
+        q = _rope(q, positions[None], cfg.rope_theta, d)
+        k = _rope(k, positions[None], cfg.rope_theta, d)
     kt = jnp.transpose(k[0], (1, 0, 2))                  # [Hkv, T, d]
     vt = jnp.transpose(v[0], (1, 0, 2))
     # scatter every live token's K/V into its page slot; dead tokens
@@ -146,7 +159,7 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
         .reshape(Hkv, npages, ps, d)
     o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
                                kv_lens, q_block=q_block,
-                               interpret=interpret)
+                               interpret=interpret, window=kind.window)
     from ..core.flags import GLOBAL_FLAGS
     if GLOBAL_FLAGS.get("fusion_probe_barrier"):
         # trace-time injected regression (FLAGS_fusion_probe_barrier):
@@ -156,10 +169,33 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
         (o,) = jax.lax.optimization_barrier((o,))
     h = h + _wmat(o.reshape(1, T, H * d), lyr["o"], lora=lo("o"))
     x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
+    if kind.mlp == "sparse":
+        return h + _routed_mlp(lyr, x, live, cfg, interpret,
+                               moe_stats), Kp, Vp
     h = h + _wmat(jax.nn.silu(_wmat(x, lyr["gate"], lora=lo("gate")))
                   * _wmat(x, lyr["up"], lora=lo("up")),
                   lyr["down"], lora=lo("down"))
     return h, Kp, Vp
+
+
+def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
+    """The routed feed-forward of a ``sparse`` layer over the packed
+    step: every live token's picks among ALL the router's experts, the
+    part of their sum whose experts this model holds, and the shared
+    expert. ``x [1, T, hidden]``."""
+    from ..nn.moe_dropless import dropless_experts, route_sigmoid
+    idx, gates = route_sigmoid(
+        x[0], lyr["router"], lyr["router_bias"],
+        top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
+        norm_topk_prob=cfg.norm_topk_prob)
+    y, stats = dropless_experts(
+        x[0], idx, gates, live, lyr["experts_gate"], lyr["experts_up"],
+        lyr["experts_down"], first=cfg.expert_offset, interpret=interpret)
+    if moe_stats is not None:
+        moe_stats.append(stats)
+    shared = _wmat(jax.nn.silu(_wmat(x, lyr["shared_gate"]))
+                   * _wmat(x, lyr["shared_up"]), lyr["shared_down"])
+    return y[None] + shared
 
 
 def speculative_sample(target_logits, draft_tokens, draft_probs, spec_lens,

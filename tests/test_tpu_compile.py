@@ -104,9 +104,9 @@ def _paged():
              _s((R, pps), jnp.int32), _s((R,), jnp.int32)), 1)
 
 
-def _ragged(T, q_block, int8_kv, *, h=H, hkv=HKV, dh=DH, R=8, pages=1024):
+def _ragged(T, q_block, int8_kv, *, h=H, hkv=HKV, dh=DH, R=8, pages=1024,
+            pps=128, window=None):
     from paddle_tpu.kernels.paged_attention import ragged_paged_attention
-    pps = 128
     i32 = jnp.int32
     pool = _s((hkv, pages, PS, dh), jnp.int8 if int8_kv else jnp.bfloat16)
     shapes = [_s((T, h, dh)), pool, pool,
@@ -122,8 +122,19 @@ def _ragged(T, q_block, int8_kv, *, h=H, hkv=HKV, dh=DH, R=8, pages=1024):
     else:
         def fn(q, k, v, t, qs, ql, kl):
             return ragged_paged_attention(q, k, v, t, qs, ql, kl,
-                                          q_block=q_block)
+                                          q_block=q_block, window=window)
     return fn, tuple(shapes), 1
+
+
+def _grouped(k, n, held=16, rows=6144):
+    """A projection of the routed experts at K-EXAONE's widths: 512
+    token slots x 8 picks + a partly filled tile an expert = 6,144 rows."""
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    def fn(x, w, tile_group, live):
+        return grouped_matmul(x, w, tile_group, live)
+    return fn, (_s((rows, k)), _s((held, k, n)),
+                _s((rows // 128,), jnp.int32), _s((), jnp.int32)), 1
 
 
 def _adamw(n, dtype):
@@ -232,6 +243,17 @@ CASES = {
     # rows x 128 page slots over a pool of 6,144 pages)
     "ragged_t320_qb8_bf16_mistral7b": lambda: _ragged(
         320, 8, False, h=32, hkv=8, dh=128, R=32, pages=6144),
+    # the geometry of k-exaone-236b-a23b.mixed-len: 512 token slots, 64 q
+    # / 8 kv heads of 128, 32 rows x 272 page slots; a window layer over
+    # the window group's 801 pages, a full layer over 4,096; and the
+    # routed experts' up and down projections over 16 held experts
+    "ragged_t512_qb8_bf16_kexaone_window": lambda: _ragged(
+        512, 8, False, h=64, hkv=8, dh=128, R=32, pages=801, pps=272,
+        window=128),
+    "ragged_t512_qb8_bf16_kexaone_full": lambda: _ragged(
+        512, 8, False, h=64, hkv=8, dh=128, R=32, pages=4096, pps=272),
+    "grouped_matmul_kexaone_up": lambda: _grouped(6144, 2048),
+    "grouped_matmul_kexaone_down": lambda: _grouped(2048, 6144),
     "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
     "fused_adamw_bf16": lambda: _adamw(11_534_336, jnp.bfloat16),
     "dequant_matmul_int8": lambda: _dequant(8),
