@@ -410,6 +410,141 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
     return out.reshape(t, hq, dl)[..., :d]
 
 
+_APPEND_BUFS = 8      # pages in flight: a read, a patch and a write overlap
+
+
+def _append_kernel(slot_ref, slots_ref, x_ref, p_in, p_out, buf, rsem, wsem,
+                   run_ref, *, page_size, tokens, window, null_page):
+    ps, T, NB, W = page_size, tokens, _APPEND_BUFS, window
+
+    def page_of(t):
+        return slot_ref[t] // ps
+
+    # a run: the adjacent tokens of one pool page (a row's tokens are
+    # packed side by side in position order, so a page's are adjacent);
+    # dead tokens name the null page and start none
+    def _scan(t, n):
+        page = page_of(t)
+        prev = jnp.where(t > 0, page_of(jnp.maximum(t - 1, 0)), -1)
+        start = (page != prev) & (page != null_page)
+
+        @pl.when(start)
+        def _():
+            run_ref[n] = t
+        return n + start.astype(jnp.int32)
+
+    n_runs = jax.lax.fori_loop(0, T, _scan, jnp.int32(0))
+
+    def read(r):
+        return pltpu.make_async_copy(
+            p_in.at[:, page_of(run_ref[r])], buf.at[r % NB], rsem.at[r % NB])
+
+    def write(r):
+        return pltpu.make_async_copy(
+            buf.at[r % NB], p_out.at[:, page_of(run_ref[r])], wsem.at[r % NB])
+
+    def _start_read(r, carry):
+        read(r).start()
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(NB, n_runs), _start_read, None)
+    row = jax.lax.broadcasted_iota(jnp.int32, (W, ps), 1)
+
+    def _run(r, carry):
+        @pl.when((r >= 1) & (r - 1 + NB < n_runs))
+        def _():                  # the buffer run r - 1 wrote from is free
+            write(r - 1).wait()
+            read(r - 1 + NB).start()
+
+        t0 = run_ref[r]
+        # the run lies inside the W tokens from the 16-aligned start
+        # under t0; pick[w, j]: token tw + w lands on this page's row j
+        tw = pl.multiple_of((t0 // 16) * 16, 16)
+        pick = (slots_ref[pl.ds(tw, W), :] == page_of(t0) * ps + row) \
+            .astype(jnp.float32).astype(x_ref.dtype)
+        # rows picked by a 0/1 product: one term a sum, so exact (an
+        # f32 pool needs the f32 passes for that)
+        exact = jax.lax.Precision.HIGHEST \
+            if x_ref.dtype == jnp.float32 else None
+        rows = jax.lax.dot_general(
+            jnp.broadcast_to(pick, (x_ref.shape[0], W, ps)),
+            x_ref[:, pl.ds(tw, W), :], (((1,), (1,)), ((0,), (0,))),
+            precision=exact, preferred_element_type=jnp.float32)
+        new_row = jax.lax.dot_general(
+            pick, jnp.ones((W, x_ref.shape[2]), x_ref.dtype),
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0
+        read(r).wait()
+        buf[r % NB] = jnp.where(new_row, rows.astype(buf.dtype),
+                                buf[r % NB])
+        write(r).start()
+        return carry
+
+    jax.lax.fori_loop(0, n_runs, _run, None)
+
+    def _wait_write(r, carry):
+        write(r).wait()
+        return carry
+
+    jax.lax.fori_loop(jnp.maximum(n_runs - NB, 0), n_runs, _wait_write, None)
+
+
+# jitted on its own, as ``_ragged_call`` is: a step appends twice a
+# layer and should trace and lower the body once
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kv_append(pages, slot, x, *, interpret=False):
+    """A step's new K or V rows written into the paged pool where it
+    lies: token ``t``'s ``x[:, t]`` lands on pool row ``slot[t]``
+    (``page * page_size + position % page_size``) of every kv head.
+
+    pages: [num_kv_heads, num_pages, page_size, head_dim], donated by the
+        caller's step: the call aliases it to its result and touches
+        only the pages that gain a token (fetch, patch in VMEM, write
+        back; ``_APPEND_BUFS`` in flight). Time follows pages touched,
+        not the pool: a scatter along the slot axis made XLA turn the
+        whole pool slot-major and back, twice its bytes a call.
+    slot:  [T] int32. Tokens of one page are adjacent (a row's tokens
+        are packed in position order) and at most ``page_size``; a dead
+        token names a row of the null page
+        (``serving/kv_cache.py::NULL_PAGE``) and is dropped: the null
+        page is never written.
+    x:     [num_kv_heads, T, head_dim].
+    """
+    from ..serving.kv_cache import NULL_PAGE
+    hkv, _, ps, d = pages.shape
+    t = slot.shape[0]
+    slot = slot.astype(jnp.int32)
+    # a patch reads the new tokens from the 16-aligned start under its
+    # run's first (a packed dtype's sublane tile), so a page's worth
+    # beyond 16; a head under the 128 lanes is zero-padded to them for
+    # the chip's compiler and cut back, as ``_ragged_call`` pads it (two
+    # pool-sized passes: such a pool is relaid out for any Mosaic call)
+    window = 16 + -(-ps // 16) * 16
+    tp = -(-t // 16) * 16 + window - 16
+    dl = d if interpret else -(-d // 128) * 128
+    x = jnp.pad(x.astype(pages.dtype), ((0, 0), (0, tp - t), (0, dl - d)))
+    if dl != d:
+        pages = jnp.pad(pages, ((0, 0),) * 3 + ((0, dl - d),))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(1,),
+        in_specs=[pl.BlockSpec((tp, 1), lambda g, s: (0, 0)),
+                  pl.BlockSpec((hkv, tp, dl), lambda g, s: (0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((_APPEND_BUFS, hkv, ps, dl), pages.dtype),
+                        pltpu.SemaphoreType.DMA((_APPEND_BUFS,)),
+                        pltpu.SemaphoreType.DMA((_APPEND_BUFS,)),
+                        pltpu.SMEM((t,), jnp.int32)])
+    return pl.pallas_call(
+        functools.partial(_append_kernel, page_size=ps, tokens=t,
+                          window=window, null_page=NULL_PAGE),
+        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
+        grid_spec=grid_spec, input_output_aliases={3: 0},
+        interpret=interpret, name="kv_append",
+    )(slot, jnp.pad(slot, (0, tp - t), constant_values=-1)[:, None], x,
+      pages)[..., :d]
+
+
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                     scale=None, interpret=False, k_scales=None,
                     v_scales=None):
@@ -507,7 +642,7 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
     return jnp.asarray(out)
 
 
-__all__ = ["paged_attention", "paged_attention_reference",
+__all__ = ["kv_append", "paged_attention", "paged_attention_reference",
            "ragged_block_row", "ragged_kv_tokens_read",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_slab_pages"]

@@ -58,10 +58,9 @@ so the clamped dead-page revisits the ragged kernel uses for DMA
 elision stay safe. int8 pools keep the append OUTSIDE the kernel
 (``quant_append_fn`` — the running-amax requant must be visible to the
 attention gather, decode_megakernel's ``self_kv=False`` contract).
-The NULL/trash page (serving.kv_cache.NULL_PAGE) is the one permitted
-divergence from the jnp scatter: the scatter dumps dead-token rows
-there while the kernel preserves its committed bytes — both contents
-are unspecified by contract and never read back.
+The NULL/trash page (serving.kv_cache.NULL_PAGE) is left as it was, as
+``paged_attention.kv_append`` leaves it: dead-token rows are dropped,
+and what the page holds is unspecified by contract and never read back.
 
 int4 weights (and any mixed layouts) have no fused-weight geometry:
 :func:`fuse_layer_weights` returns None and the engine keeps the
@@ -284,7 +283,6 @@ def _reference_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts,
     pools)."""
     from ..models.generation import _lora_delta, _rms_norm, _wmat
     H, Hkv, d = num_heads, num_kv_heads, head_dim
-    ps = page_size
     T = h.shape[1]
     F = fused["gateup"].shape[-1] // 2
 
@@ -308,6 +306,7 @@ def _reference_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts,
     k = rope_apply(k, pre.cos, pre.sin)
     kt = jnp.transpose(k[0], (1, 0, 2))                  # [Hkv, T, d]
     vt = jnp.transpose(v[0], (1, 0, 2))
+    from .paged_attention import kv_append, ragged_paged_attention
     if quant_append_fn is not None:
         # int8 pools: append-first — the running-amax requant must be
         # visible to the attention gather (the engine owns the
@@ -315,12 +314,8 @@ def _reference_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts,
         Kp, k_scales, Vp, v_scales = quant_append_fn(
             Kp, k_scales, Vp, v_scales, kt, vt)
     else:
-        npages = Kp.shape[1]
-        Kp = Kp.reshape(Hkv, npages * ps, d).at[:, pre.slot].set(kt) \
-            .reshape(Hkv, npages, ps, d)
-        Vp = Vp.reshape(Hkv, npages * ps, d).at[:, pre.slot].set(vt) \
-            .reshape(Hkv, npages, ps, d)
-    from .paged_attention import ragged_paged_attention
+        Kp = kv_append(Kp, pre.slot, kt, interpret=attn_interpret)
+        Vp = kv_append(Vp, pre.slot, vt, interpret=attn_interpret)
     o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
                                kv_lens, q_block=q_block,
                                interpret=attn_interpret,
@@ -469,7 +464,7 @@ def _build_prefill_kernel(*, H, Hkv, grp, dh, ps, T, G, hb, qb,
             # clamped revisits (depends only on scratch + committed
             # rows), and the final visitor of each page has staged its
             # full valid range, so the pool converges to exactly the
-            # jnp scatter's bytes for every live page.
+            # jnp body's bytes for every live page.
             for j in range(hb):
                 hh = g * hb + j
                 pk = kpg_ref[j, 0].astype(jnp.float32)       # [ps, dh]
@@ -573,8 +568,8 @@ def fused_prefill_layer(fused, h, Kp, Vp, tbls, pre, q_starts, q_lens,
     quant_append_fn(Kp, Ks, Vp, Vs, kt, vt) -> (Kp, Ks, Vp, Vs): the
         int8 running-amax requant-append for this layer, run BEFORE
         attention (caller-owned). fp pools append internally — the jnp
-        body scatters at ``pre.slot``; the kernel writes pages through
-        aliased outputs.
+        body through ``kv_append`` at ``pre.slot``; the kernel writes
+        pages through aliased outputs.
     adapters/slots: the layer's LoRA slab + per-token slot ids (jnp
         body only; their presence routes away from the kernel).
     Returns ``(h, Kp, Vp, k_scales, v_scales)``.

@@ -66,7 +66,7 @@ import jax.numpy as jnp
 from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  _wmat, extract_params, layer_kinds,
                                  request_keys, sample_rows)
-from ..kernels.paged_attention import (ragged_kv_tokens_read,
+from ..kernels.paged_attention import (kv_append, ragged_kv_tokens_read,
                                        ragged_paged_attention)
 from ..profiler import spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
@@ -1031,16 +1031,12 @@ class LLMEngine:
                     else:
                         def append_fn(Kp, Vp, kc, vc):
                             slot = page * ps + off
-                            npages = Kp.shape[1]
                             kt = jnp.transpose(kc, (1, 0, 2))
                             vt = jnp.transpose(vc, (1, 0, 2))
-                            Kp = Kp.reshape(Hkv, npages * ps, d) \
-                                .at[:, slot].set(kt) \
-                                .reshape(Hkv, npages, ps, d)
-                            Vp = Vp.reshape(Hkv, npages * ps, d) \
-                                .at[:, slot].set(vt) \
-                                .reshape(Hkv, npages, ps, d)
-                            return Kp, Vp
+                            return (kv_append(Kp, slot, kt,
+                                              interpret=interpret),
+                                    kv_append(Vp, slot, vt,
+                                              interpret=interpret))
                         h, Kn, Vn, _, _ = fused_decode_model(
                             params["layers"], h, kv[0], kv[1], tbls,
                             att_len, eps=cfg.rms_norm_eps,
@@ -1098,7 +1094,7 @@ class LLMEngine:
                     else:
                         # the megakernel computes this token's k/v
                         # in-kernel (self-attention term in-register)
-                        # and returns them for the page scatter —
+                        # and returns them for the page append —
                         # lossless for fp pools
                         h, kc, vc = fused_decode_layer(
                             lyr, h, Kp, Vp, tbls, att_len,
@@ -1106,13 +1102,10 @@ class LLMEngine:
                             num_heads=H, self_kv=True,
                             interpret=mk_interpret)
                         slot = page * ps + off
-                        npages = Kp.shape[1]
                         kt = jnp.transpose(kc, (1, 0, 2))    # [Hkv, R, d]
                         vt = jnp.transpose(vc, (1, 0, 2))
-                        Kp = Kp.reshape(Hkv, npages * ps, d).at[:, slot] \
-                            .set(kt).reshape(Hkv, npages, ps, d)
-                        Vp = Vp.reshape(Hkv, npages * ps, d).at[:, slot] \
-                            .set(vt).reshape(Hkv, npages, ps, d)
+                        Kp = kv_append(Kp, slot, kt, interpret=interpret)
+                        Vp = kv_append(Vp, slot, vt, interpret=interpret)
                     new_kv.append((Kp, Vp))
                 hn = _rms_norm(h[None], params["norm"],
                                cfg.rms_norm_eps)[0]

@@ -50,7 +50,7 @@ from ..core.flags import define_flag
 from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  _wmat, extract_params, request_keys,
                                  sample_rows, sampling_probs)
-from ..kernels.paged_attention import ragged_paged_attention
+from ..kernels.paged_attention import kv_append, ragged_paged_attention
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 
 
@@ -99,7 +99,7 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                      q_block, interpret, *, adapters=None, slots=None,
                      kind=LayerKind(), moe_stats=None):
     """One fp decoder layer of the ragged forward: qkv proj -> rope ->
-    page scatter append -> ragged attention -> o proj -> mlp. Returns
+    page append -> ragged attention -> o proj -> mlp. Returns
     ``(h, Kp, Vp)``.
 
     ``kind`` (``models.generation.LayerKind``, static) says what the
@@ -147,16 +147,13 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
         k = _rope(k, positions[None], cfg.rope_theta, d)
     kt = jnp.transpose(k[0], (1, 0, 2))                  # [Hkv, T, d]
     vt = jnp.transpose(v[0], (1, 0, 2))
-    # scatter every live token's K/V into its page slot; dead tokens
-    # (slot padding / pad rows) land on the null page, never live data
+    # every live token's K/V goes to its page slot; dead tokens (slot
+    # padding / pad rows) name the null page, which the append skips
     page_idx = jnp.clip(positions // ps, 0, max_pages - 1)
     page = jnp.where(live, tbls[tok_row, page_idx], NULL_PAGE)
     slot = page * ps + positions % ps
-    npages = Kp.shape[1]
-    Kp = Kp.reshape(Hkv, npages * ps, d).at[:, slot].set(kt) \
-        .reshape(Hkv, npages, ps, d)
-    Vp = Vp.reshape(Hkv, npages * ps, d).at[:, slot].set(vt) \
-        .reshape(Hkv, npages, ps, d)
+    Kp = kv_append(Kp, slot, kt, interpret=interpret)
+    Vp = kv_append(Vp, slot, vt, interpret=interpret)
     o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
                                kv_lens, q_block=q_block,
                                interpret=interpret, window=kind.window)

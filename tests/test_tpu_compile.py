@@ -57,11 +57,12 @@ def on_tpu(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS", raising=False)
 
 
-def _compiled_text(fn, one_chip, *shapes):
+def _compiled_text(fn, one_chip, *shapes, donate=()):
     args = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         shapes)
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile() \
+        .as_text()
 
 
 def _s(shape, dtype=jnp.bfloat16):
@@ -124,6 +125,13 @@ def _ragged(T, q_block, int8_kv, *, h=H, hkv=HKV, dh=DH, R=8, pages=1024,
             return ragged_paged_attention(q, k, v, t, qs, ql, kl,
                                           q_block=q_block, window=window)
     return fn, tuple(shapes), 1
+
+
+def _append(T, *, hkv=HKV, dh=DH, pages=1024, dtype=jnp.bfloat16):
+    from paddle_tpu.kernels.paged_attention import kv_append
+    return (lambda P, slot, x: kv_append(P, slot, x),
+            (_s((hkv, pages, PS, dh), dtype), _s((T,), jnp.int32),
+             _s((hkv, T, dh), dtype)), 1)
 
 
 def _grouped(k, n, held=16, rows=6144):
@@ -252,6 +260,12 @@ CASES = {
         window=128),
     "ragged_t512_qb8_bf16_kexaone_full": lambda: _ragged(
         512, 8, False, h=64, hkv=8, dh=128, R=32, pages=4096, pps=272),
+    # the fp KV append: a head under the 128 lanes (lane-padded by the
+    # wrapper), a burst's 8 one-token rows, an f32 pool
+    "kv_append_t64_d64_tinyllama": lambda: _append(64),
+    "kv_append_t8_d128": lambda: _append(8, hkv=8, dh=128),
+    "kv_append_t320_d128_f32": lambda: _append(
+        320, hkv=8, dh=128, dtype=jnp.float32),
     "grouped_matmul_kexaone_up": lambda: _grouped(6144, 2048),
     "grouped_matmul_kexaone_down": lambda: _grouped(2048, 6144),
     "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
@@ -276,3 +290,74 @@ def test_kernel_compiles_for_v5e(name, one_chip, on_tpu):
     assert text.count("tpu_custom_call") >= n_calls, (
         f"{name}: expected >= {n_calls} tpu_custom_call in the compiled "
         f"text, found {text.count('tpu_custom_call')}")
+
+
+# ---- the fp KV append in front of the ragged kernel, pools donated, as
+# the serving step's layer body has them (spec_decode._ragged_fp_layer)
+
+def _scatter_append(P, slot, x, interpret=False):
+    """The expression ``kv_append`` replaced (PR 30): XLA gives this
+    scatter a slot-major operand, so the whole pool is transposed in and
+    back out for the kernel behind it."""
+    hkv, n, ps, d = P.shape
+    return P.reshape(hkv, n * ps, d).at[:, slot].set(x).reshape(P.shape)
+
+
+def _append_then_ragged(append, T, *, h, hkv, pages, pps, window=None,
+                        R=32, dh=128):
+    from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+    i32 = jnp.int32
+    pool = _s((hkv, pages, PS, dh))
+
+    def fn(Kp, Vp, q, kt, vt, slot, tbl, qs, ql, kl):
+        Kp, Vp = append(Kp, slot, kt), append(Vp, slot, vt)
+        return ragged_paged_attention(q, Kp, Vp, tbl, qs, ql, kl,
+                                      q_block=8, window=window), Kp, Vp
+    return fn, (pool, pool, _s((T, h, dh)), _s((hkv, T, dh)),
+                _s((hkv, T, dh)), _s((T,), i32), _s((R, pps), i32),
+                _s((R,), i32), _s((R,), i32), _s((R,), i32))
+
+
+def _pool_sized_copies(text, pool_elems):
+    import math
+    import re
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"=\s*\w+\[([\d,]+)\]\S*\s+copy\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) >= pool_elems:
+            found.append(line.strip()[:120])
+    return found
+
+
+# mistral-7b.chat-steady's pools and step, and the window group of
+# k-exaone-236b-a23b.mixed-len (801 pages: no multiple of 8)
+APPEND_GEOMETRY = {
+    "mistral7b": dict(T=320, h=32, hkv=8, pages=6144, pps=128),
+    "kexaone_window": dict(T=512, h=64, hkv=8, pages=801, pps=272,
+                           window=128),
+}
+
+
+@pytest.mark.parametrize("name", list(APPEND_GEOMETRY))
+def test_kv_append_leaves_the_pool_where_it_lies(name, one_chip, on_tpu):
+    from paddle_tpu.kernels.paged_attention import kv_append
+    geo = APPEND_GEOMETRY[name]
+    fn, shapes = _append_then_ragged(kv_append, **geo)
+    text = _compiled_text(fn, one_chip, *shapes, donate=(0, 1))
+    assert text.count("tpu_custom_call") >= 3
+    copies = _pool_sized_copies(text, geo["hkv"] * geo["pages"] * PS * 128)
+    assert not copies, f"{name}: pool-sized copies in the step: {copies}"
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    assert "(0, {}" in alias and "(1, {}" in alias, (
+        f"{name}: the donated pools are not both aliased to results: "
+        f"{alias[:200]}")
+
+
+def test_the_scatter_kv_append_replaced_turns_the_pool(one_chip, on_tpu):
+    """The guard above can see what it guards against: the old
+    expression compiles to two pool-sized copies a pool."""
+    geo = APPEND_GEOMETRY["mistral7b"]
+    fn, shapes = _append_then_ragged(_scatter_append, **geo)
+    text = _compiled_text(fn, one_chip, *shapes, donate=(0, 1))
+    copies = _pool_sized_copies(text, geo["hkv"] * geo["pages"] * PS * 128)
+    assert len(copies) == 4, copies
