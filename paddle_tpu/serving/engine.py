@@ -72,8 +72,8 @@ from ..profiler import spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 from .metrics import ServingMetrics
 from .scheduler import Scheduler, SchedulerConfig, Sequence, SequenceStatus
-from .spec_decode import (FINAL_TAG, _ragged_fp_layer, _ragged_packing,
-                          speculative_sample)
+from .spec_decode import (FINAL_TAG, StepOperands, _ragged_fp_layer,
+                          _ragged_packing, speculative_sample)
 
 
 class PrefixStoreMismatch(ValueError):
@@ -743,31 +743,40 @@ class LLMEngine:
         num_layers = cfg.num_hidden_layers
         prefill_fused = self.prefill_megakernel == "fused"
         kinds = self._kinds
+        operands = self._operands = StepOperands(
+            T, R, PPS, K, window=bool(self.pool.window_layers),
+            adapters=self.adapters is not None)
 
-        def ragged_step(params, kv, kv_scales, tokens, positions, tbls,
-                        q_starts, q_lens, kv_lens, sample_idx, temps,
-                        top_ks, top_ps, seeds, sample_pos, spec_lens,
-                        draft_tokens, draft_probs, base_key,
-                        adapters, adapter_slots, tbls_w):
+        def ragged_step(params, kv, kv_scales, ctl, draft_tokens,
+                        draft_probs, base_key, adapters):
+            # ctl: the launch's ONE int32 control buffer
+            # (spec_decode.StepOperands), unpacked here by static slices:
             # tokens/positions [T] packed row-wise (pad rows: q_len=0,
             # q_start=T); tbls [R, PPS]; kv_lens = committed + q_len per
             # row (the attention length AFTER this step's appends);
             # sample_idx [R, K+1] flat indices of each row's verify
             # positions (ordinary rows: K+1 copies of the last live
             # token). Sampling is fully in-graph: per-row knobs
-            # (temps/top_ks/top_ps), per-request PRNG streams
-            # (seeds/sample_pos off base_key), and — on speculative
-            # rounds — the rejection sampler over the draft's candidates
-            # (spec_lens/draft_tokens/draft_probs; all-zero on ordinary
-            # rounds, where the sampler degenerates to one direct draw
-            # from the last position's distribution).
-            # adapters/adapter_slots (paddle_tpu.tenancy): the LoRA
-            # slab pytree + per-token slot ids. None legs contribute
-            # ZERO operands (empty pytrees), so adapter-free engines
-            # lower byte-identical HLO; with a registry, which adapter
-            # a token wears is a gather — data, never shape.
-            # tbls_w: the window page group's tables (None, an empty
-            # pytree, for a model without window layers).
+            # (temps/top_ks/top_ps, the floats by their own bits),
+            # per-request PRNG streams (seeds/sample_pos off base_key),
+            # and — on speculative rounds — the rejection sampler over
+            # the draft's candidates (spec_lens/draft_tokens/draft_probs;
+            # all-zero on ordinary rounds, where the sampler degenerates
+            # to one direct draw from the last position's distribution).
+            # adapters (paddle_tpu.tenancy): the LoRA slab pytree (None,
+            # an empty pytree, without a registry); with one the buffer
+            # carries slot_ids, the per-token slot ids: which adapter a
+            # token wears is a gather — data, never shape.
+            # tbls_w: the window page group's tables, in the buffer for
+            # a model with window layers.
+            o = operands.unpack(ctl)
+            tokens, positions, tbls = o["tokens"], o["positions"], o["tbls"]
+            q_starts, q_lens, kv_lens = (o["q_starts"], o["q_lens"],
+                                         o["kv_lens"])
+            sample_idx, spec_lens = o["sample_idx"], o["spec_lens"]
+            temps, top_ks, top_ps = o["temps"], o["top_ks"], o["top_ps"]
+            seeds, sample_pos = o["seeds"], o["sample_pos"]
+            adapter_slots, tbls_w = o.get("slot_ids"), o.get("tbls_w")
             tok_row = live = pre = None
             moe_stats = []
             if prefill_fused:
@@ -938,7 +947,8 @@ class LLMEngine:
                 n_out = jnp.concatenate([
                     n_out, jnp.stack([st[:, 0].sum(), st[:, 1].sum(),
                                       st[:, 2].max()]).astype(n_out.dtype)])
-            return (out, n_out, finite, new_kv,
+            # the step's small results go home as one array too
+            return (operands.pack_results(out, n_out, finite), new_kv,
                     new_scales if quant_pool else None)
 
         def _append_quant(Kp, Ks, Vp, Vs, kt, vt, tbls, q_starts, q_lens,
@@ -1504,25 +1514,13 @@ class LLMEngine:
         return self.flight.dump(reason, t=self._now(), **detail)
 
     def _zero_step_args(self):
-        """Zero-filled ragged-step operands at the exact launch shapes
-        (the AOT lowering surface — never dispatched)."""
-        T, R, PPS = (self.step_token_budget, self.max_num_seqs,
-                     self.max_pages_per_seq)
-        K = self.spec_tokens
-        z = jnp.zeros
+        """The ragged step's arguments as ``_launch`` dispatches them, the
+        control buffer all pad rows (the AOT lowering surface — never
+        dispatched)."""
         return (self._ragged_params, self.pool.kv, self.pool.kv_scales,
-                z((T,), jnp.int32), z((T,), jnp.int32),
-                jnp.full((R, PPS), NULL_PAGE, jnp.int32),
-                jnp.full((R,), T, jnp.int32), z((R,), jnp.int32),
-                z((R,), jnp.int32), z((R, K + 1), jnp.int32),
-                z((R,), jnp.float32), z((R,), jnp.int32),
-                jnp.ones((R,), jnp.float32), z((R,), jnp.int32),
-                z((R,), jnp.int32), z((R,), jnp.int32),
-                self._zero_draft[0], self._zero_draft[1], self._base_key,
-                self.adapters.slab if self.adapters is not None else None,
-                z((T,), jnp.int32) if self.adapters is not None else None,
-                jnp.full((R, PPS), NULL_PAGE, jnp.int32)
-                if self.pool.window_layers else None)
+                jnp.asarray(self._operands.host()[0]), *self._zero_draft,
+                self._base_key,
+                self.adapters.slab if self.adapters is not None else None)
 
     def _zero_burst_args(self):
         """Zero-filled burst-step operands at the exact launch shapes."""
@@ -2270,44 +2268,37 @@ class LLMEngine:
     # ------------------------------------------------------------------
     def _launch(self, plan, sp, draft_tokens=None, draft_probs=None):
         """Assemble the fixed-shape operands for the plan and run the one
-        ragged-step executable. Returns ``(out [R, K+1], n_out [R],
-        finite [R])`` — ordinary rounds commit ``out[i, 0]`` (n_out is
-        1), speculative rounds commit ``out[i, :n_out[i]]``; a row with
-        ``finite[i] == False`` produced NaN/Inf logits and must be
-        aborted instead of committed (the in-graph isfinite guard).
+        ragged-step executable: one put, one call, one read-back
+        (``metrics.host_transfers`` counts the two transfers; a
+        speculative round's candidates are one more). Returns ``(out
+        [R, K+1], n_out [R], finite [R])`` — ordinary rounds commit
+        ``out[i, 0]`` (n_out is 1), speculative rounds commit
+        ``out[i, :n_out[i]]``; a row with ``finite[i] == False``
+        produced NaN/Inf logits and must be aborted instead of committed
+        (the in-graph isfinite guard).
 
         ``sp`` is the step's span: the launch is its phases
-        ``serve.assemble`` (numpy operands), ``serve.dispatch`` (the
-        puts and the call, until it returns) and ``serve.wait`` (the
-        host blocked on the tokens), and what the step carries is
-        counted on it from the operands built here."""
-        T, R, PPS = plan.token_budget, plan.num_slots, self.max_pages_per_seq
-        K = self.spec_tokens
+        ``serve.assemble`` (the rows written into views of the one
+        control buffer, ``spec_decode.StepOperands``),
+        ``serve.dispatch`` (the put and the call, until it returns) and
+        ``serve.wait`` (the host blocked on the one array of results),
+        and what the step carries is counted on it from the operands
+        built here."""
+        PPS, K = self.max_pages_per_seq, self.spec_tokens
         sp.phase("serve.assemble")
-        self.metrics.host_dispatches.inc()
+        m = self.metrics
+        m.host_dispatches.inc()
         if not self._step_launched:
             self._step_launched = True
-            self.metrics.decode_compiles.inc()
-        tokens = np.zeros((T,), np.int32)
-        positions = np.zeros((T,), np.int32)
-        tbls = np.full((R, PPS), NULL_PAGE, np.int32)
-        tbls_w = tbls.copy() if self.pool.window_layers else None
-        q_starts = np.full((R,), T, np.int32)   # pad rows: start past T
-        q_lens = np.zeros((R,), np.int32)
-        kv_lens = np.zeros((R,), np.int32)
-        sample_idx = np.zeros((R, K + 1), np.int32)
-        temps = np.zeros((R,), np.float32)
-        top_ks = np.zeros((R,), np.int32)
-        top_ps = np.ones((R,), np.float32)
-        seeds = np.zeros((R,), np.int32)
-        sample_pos = np.zeros((R,), np.int32)
-        spec_lens = np.zeros((R,), np.int32)
-        slot_ids = np.zeros((T,), np.int32) \
-            if self.adapters is not None else None
-        if draft_tokens is None:
-            # ordinary round: the prebuilt zero operands (never indexed
-            # below — every row has spec == 0)
-            draft_tokens, draft_probs = self._zero_draft
+            m.decode_compiles.inc()
+        # the rows go straight into views of the one control buffer
+        buf, o = self._operands.host()
+        tokens, positions, tbls = o["tokens"], o["positions"], o["tbls"]
+        q_starts, q_lens, kv_lens = o["q_starts"], o["q_lens"], o["kv_lens"]
+        sample_idx, spec_lens = o["sample_idx"], o["spec_lens"]
+        temps, top_ks, top_ps = o["temps"], o["top_ks"], o["top_ps"]
+        seeds, sample_pos = o["seeds"], o["sample_pos"]
+        tbls_w, slot_ids = o.get("tbls_w"), o.get("slot_ids")
         specs = plan.spec_lens
         prefill_tokens = 0
         for i, (seq, q_start, q_len) in enumerate(plan.rows):
@@ -2368,30 +2359,37 @@ class LLMEngine:
                                     + (n_win and n_win * walked(window)))
                // len(self._kinds))
         sp.phase("serve.dispatch")
-        out, n_out, finite, new_kv, new_scales = self._ragged_jit(
-            self._ragged_params, self.pool.kv, self.pool.kv_scales,
-            jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tbls),
-            jnp.asarray(q_starts), jnp.asarray(q_lens),
-            jnp.asarray(kv_lens), jnp.asarray(sample_idx),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            jnp.asarray(seeds), jnp.asarray(sample_pos),
-            jnp.asarray(spec_lens), jnp.asarray(draft_tokens),
-            jnp.asarray(draft_probs), self._base_key,
-            self.adapters.slab if self.adapters is not None else None,
-            jnp.asarray(slot_ids) if slot_ids is not None else None,
-            jnp.asarray(tbls_w) if tbls_w is not None else None)
+        if draft_tokens is None:
+            # ordinary round: the prebuilt zero operands on the device
+            draft_tokens, draft_probs = self._zero_draft
+        else:
+            # a speculative round's candidates are a put of their own
+            # (draft_probs [R, K, V] is the draft's device array), made
+            # here so that the call sees a device array on every round
+            draft_tokens = jax.device_put(draft_tokens)
+            m.host_transfers.inc()
+        # the host buffer goes to the call as it is: the executable's own
+        # argument handling makes the one put, 0.3 ms a step sooner than
+        # jax.device_put + the call (PERF.md section 6, PR 32)
+        back, new_kv, new_scales = self._ragged_jit(
+            self._ragged_params, self.pool.kv, self.pool.kv_scales, buf,
+            draft_tokens, draft_probs, self._base_key,
+            self.adapters.slab if self.adapters is not None else None)
+        m.host_transfers.inc()
         self.pool.kv = new_kv
         if new_scales is not None:
             self.pool.kv_scales = new_scales
         sp.phase("serve.wait")
-        n_out = np.asarray(n_out)
+        out, n_out, finite = self._operands.read_results(np.asarray(back))
+        m.host_transfers.inc()
+        R = len(finite)
         if len(n_out) > R:
             # routed layers' counts, behind the rows' (ragged_step)
             pairs, touched, most = (int(x) for x in n_out[R:])
             sp.set(moe_pairs_held=pairs, moe_experts_touched=touched,
                    moe_max_expert_tokens=most)
             n_out = n_out[:R]
-        return np.asarray(out), n_out, np.asarray(finite)
+        return out, n_out, finite
 
     def _launch_spec(self, plan, touched, sp):
         """One speculative round: draft sync + k proposal steps, then

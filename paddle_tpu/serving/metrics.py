@@ -206,6 +206,12 @@ class ServingMetrics:
                 # generation bursts, and prefix-cache hits served by a
                 # PINNED chain after its last sequence sharer left
                 "host_dispatches", "burst_launches", "pinned_prefix_hits",
+                # host<->device transfers the ragged launch made
+                # (_launch: one put of the control buffer and one
+                # read-back an ordinary round, so 2 x its dispatches; the
+                # draft's candidates one more a speculative round; the
+                # burst loop's own puts are not counted)
+                "host_transfers",
                 # fused ragged prefill (kernels/prefill_megakernel.py):
                 # steps that served >= 1 prefill-chunk row — the ragged
                 # step is ONE executable, so each such step is ONE

@@ -86,6 +86,80 @@ def _ragged_packing(q_starts, q_lens, T):
     return tok_row, live
 
 
+class StepOperands:
+    """The ragged step's small operands as ONE int32 buffer, and its
+    small results as one int32 array: what a launch moves between host
+    and device is one put and one read-back.
+
+    Laid out once at engine build from the step's fixed shapes (``T``
+    packed tokens, ``R`` row slots, ``PPS``-wide tables, ``K`` draft
+    tokens) and from which optional operands exist (``window``: the
+    window page group's tables ``tbls_w``; ``adapters``: the per-token
+    LoRA ``slot_ids``). Every operand is a named static slice; the float
+    ones travel as their own bits (an ``np.float32`` view on the host,
+    ``bitcast_convert_type`` in the graph). ``host()`` and ``unpack()``
+    are the two readings of the same slices."""
+
+    def __init__(self, T, R, PPS, K, *, window=False, adapters=False):
+        i32, f32 = np.int32, np.float32
+        # name, shape, dtype, what a pad row reads
+        fields = [("tokens", (T,), i32, 0), ("positions", (T,), i32, 0),
+                  ("tbls", (R, PPS), i32, NULL_PAGE),
+                  ("q_starts", (R,), i32, T),     # pad rows: start past T
+                  ("q_lens", (R,), i32, 0), ("kv_lens", (R,), i32, 0),
+                  ("sample_idx", (R, K + 1), i32, 0),
+                  ("temps", (R,), f32, 0.0), ("top_ks", (R,), i32, 0),
+                  ("top_ps", (R,), f32, 1.0), ("seeds", (R,), i32, 0),
+                  ("sample_pos", (R,), i32, 0), ("spec_lens", (R,), i32, 0)]
+        if window:
+            fields.append(("tbls_w", (R, PPS), i32, NULL_PAGE))
+        if adapters:
+            fields.append(("slot_ids", (T,), i32, 0))
+        self.R, self.K = R, K
+        self.fields, lo = {}, 0
+        for name, shape, dtype, _ in fields:
+            n = int(np.prod(shape))
+            self.fields[name] = (lo, lo + n, shape, dtype)
+            lo += n
+        self.size = lo
+        self._blank = np.zeros((lo,), i32)
+        for (*_, fill), view in zip(fields, self._views(self._blank)):
+            view[...] = fill
+
+    def _views(self, buf):
+        return [buf[lo:hi].view(dtype).reshape(shape)
+                for lo, hi, shape, dtype in self.fields.values()]
+
+    def host(self):
+        """A fresh buffer of pad rows and a dict of numpy views into it,
+        one an operand: rows written through a view land in the buffer."""
+        buf = self._blank.copy()
+        return buf, dict(zip(self.fields, self._views(buf)))
+
+    def unpack(self, buf):
+        """In the graph: the operands out of the device's copy of the
+        buffer, by static slices."""
+        ops = {}
+        for name, (lo, hi, shape, dtype) in self.fields.items():
+            x = buf[lo:hi].reshape(shape)
+            if dtype is np.float32:
+                x = jax.lax.bitcast_convert_type(x, jnp.float32)
+            ops[name] = x
+        return ops
+
+    def pack_results(self, out, n_out, finite):
+        """In the graph: ``out`` ``[R, K+1]``, ``n_out`` (``[R]``, the
+        routed layers' three counts behind it where there are any) and
+        ``finite`` ``[R]`` as one int32 array."""
+        return jnp.concatenate([out.reshape(-1), n_out,
+                                finite.astype(jnp.int32)])
+
+    def read_results(self, back):
+        """On the host: ``(out, n_out, finite)`` out of the one array."""
+        R, n = self.R, self.R * (self.K + 1)
+        return back[:n].reshape(R, self.K + 1), back[n:-R], back[-R:] != 0
+
+
 def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                      q_starts, q_lens, kv_lens, cfg, page_size, max_pages,
                      q_block, interpret, *, adapters=None, slots=None,

@@ -361,3 +361,57 @@ def test_the_scatter_kv_append_replaced_turns_the_pool(one_chip, on_tpu):
     text = _compiled_text(fn, one_chip, *shapes, donate=(0, 1))
     copies = _pool_sized_copies(text, geo["hkv"] * geo["pages"] * PS * 128)
     assert len(copies) == 4, copies
+
+
+# the serving launch's control buffer (spec_decode.StepOperands) at the
+# two serving cells' shapes, and with every optional operand aboard
+PROLOGUE_GEOMETRY = {
+    "mistral7b": dict(T=320, R=32, PPS=128, K=0),
+    "kexaone_window": dict(T=512, R=32, PPS=272, K=0, window=True),
+    "spec_lora_window": dict(T=320, R=32, PPS=128, K=2, window=True,
+                             adapters=True),
+}
+
+
+@pytest.mark.parametrize("name", list(PROLOGUE_GEOMETRY))
+def test_step_prologue_unpacks_the_one_buffer_in_place(name, one_chip):
+    """The ragged step's first lines: the operands out of the ONE int32
+    control buffer by static slices, the floats by their own bits, and
+    the step's small results back as one array. Compiled for the chip,
+    the prologue holds no transfer and no copy larger than the buffer
+    (slices and bitcasts of it, which XLA fuses into their readers)."""
+    from paddle_tpu.serving.spec_decode import StepOperands, _ragged_packing
+    geo = dict(PROLOGUE_GEOMETRY[name])
+    T, R, K = geo["T"], geo["R"], geo["K"]
+    lay = StepOperands(geo.pop("T"), geo.pop("R"), geo.pop("PPS"),
+                       geo.pop("K"), **geo)
+
+    def fn(ctl):
+        o = lay.unpack(ctl)
+        # readers as the step's: a per-token value by token id, the row
+        # masks, the sampler's floats
+        tok_row, live = _ragged_packing(o["q_starts"], o["q_lens"], T)
+        h = jnp.sin(o["tokens"][:, None].astype(jnp.float32)
+                    * jnp.arange(1, 9, dtype=jnp.float32)) * live[:, None]
+        scaled = h[o["sample_idx"].reshape(-1)].reshape(R, K + 1, 8) \
+            / jnp.maximum(o["temps"], 1e-6)[:, None, None]
+        finite = jnp.all(jnp.isfinite(scaled.reshape(R, -1)), -1) \
+            & (o["top_ps"] <= 1.0)
+        out = jnp.argmax(scaled, -1).astype(jnp.int32) + tok_row[:R, None]
+        rest = {k: v for k, v in o.items()
+                if k not in ("tokens", "temps", "top_ps")}
+        return lay.pack_results(out, o["spec_lens"] + 1, finite), rest
+
+    text = _compiled_text(fn, one_chip, _s((lay.size,), jnp.int32))
+    assert "bitcast-convert" in text or "bitcast(" in text
+    for op in ("infeed", "outfeed", "send(", "recv(", "all-gather",
+               "all-reduce", "host-compute"):
+        assert op not in text, f"{name}: {op} in the step's prologue"
+    # the buffer itself may be staged whole (a copy-start of its size)
+    import math
+    import re
+    big = _pool_sized_copies(text, lay.size + 1) + [
+        m.group(0)[:120] for m in re.finditer(
+            r"\(\w+\[([\d,]+)\]\S* [^\n]*copy-start\(", text)
+        if math.prod(map(int, m.group(1).split(","))) > lay.size]
+    assert not big, f"{name}: copies larger than the buffer: {big}"
