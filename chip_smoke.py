@@ -8,6 +8,10 @@ user calls, at TinyLlama-1.1B widths (vocab 32000, hidden 2048, ffn
 - serve: ``paddle_tpu.serving.LLMEngine`` over all 22 layers in bf16,
   default flags, a handful of requests admitted together; greedy tokens
   against ``Generator.generate`` on the same prompts.
+- latent: ``kernels/paged_attention.py::ragged_latent_attention`` alone at
+  dots.vlm1.inst's head sizes (128 heads against rows of 512 + 64 values
+  held in 640 lanes), a few pages, decode rows and a chunk in one launch,
+  behind ``kv_append``; against its ``jnp`` reference.
 - train: ``paddle.jit.TrainStep`` + AdamW + bf16 autocast + remat at
   b 1 x s 2048, as deep as one 16 GB chip holds; loss finite and falling
   on a repeated batch.
@@ -49,6 +53,9 @@ SERVE = dict(layers=(22, 2), max_len=(1024, 128),
 # Of 15.75 GiB: 10 layers take 13.3 GiB at b 1 and 13.9 GiB at b 2 (the
 # four-chip leg's single-device comparison); 11 take 14.5 and 15.1, 12
 # take 15.6. Both train legs share the depth, so 10.
+# the latent kernel: heads, row width held, value width, page slots a row
+LATENT = dict(heads=(128, 4), row=(640, 128), width=(576, 40),
+              v_width=(512, 32), pages=(64, 24))
 TRAIN = dict(layers=(10, 2), batch=(1, 1), seq=(2048, 128), steps=(4, 3))
 SHARDED = dict(layers=(10, 2), batch=(2, 2), seq=(2048, 128), steps=(3, 3),
                preset="dp=2,tp=2")
@@ -202,6 +209,72 @@ def serve_leg(paddle, dev, on_chip, rehearse):
 # ---------------------------------------------------------------------------
 # train legs
 # ---------------------------------------------------------------------------
+
+def latent_leg(on_chip, rehearse):
+    """The latent attention kernel behind the row append, at the
+    published head sizes: a decode row deep in its context, one that
+    ends mid-page, and a 40-token chunk, in one launch."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import (
+        kv_append, ragged_latent_attention, ragged_latent_attention_reference)
+    h, row, width, v_width, pps = (pick(LATENT, k, rehearse) for k in
+                                   ("heads", "row", "width", "v_width",
+                                    "pages"))
+    ps, qb = 16, 8
+    rng = np.random.default_rng(0)
+    q_lens = np.array([1, 1, 40, 0], np.int32)
+    kv_lens = np.array([pps * ps - 3, 37, 40 + 2 * ps, 0], np.int32)
+    slots = -(-q_lens // qb) * qb
+    t = int(slots.sum()) + qb
+    q_starts = np.where(q_lens > 0, np.cumsum(slots) - slots, t) \
+        .astype(np.int32)
+    n_pages = 1 + int((-(-kv_lens // ps)).sum())
+    tbl, at = np.zeros((len(q_lens), pps), np.int32), 1
+    for i, kl in enumerate(kv_lens):
+        n = -(-int(kl) // ps)
+        tbl[i, :n] = np.arange(at, at + n)
+        at += n
+    dt = jnp.bfloat16 if on_chip else jnp.float32
+
+    def padded(a):      # the row's padding lanes are zero, as the step's
+        return jnp.asarray(np.where(np.arange(row) < width, a, 0.0), dt)
+    pool = padded(rng.standard_normal((n_pages, ps, row)))
+    q = padded(rng.standard_normal((t, h, row)) * 0.3)
+    new = padded(rng.standard_normal((t, row)))
+    # this launch's tokens are appended first, as the layer body does
+    pos = np.zeros((t,), np.int64)
+    page = np.zeros((t,), np.int64)                  # dead: the null page
+    for i, (s0, ql, kl) in enumerate(zip(q_starts, q_lens, kv_lens)):
+        p = kl - ql + np.arange(ql)
+        pos[s0:s0 + ql] = p
+        page[s0:s0 + ql] = tbl[i, p // ps]
+    slot = jnp.asarray(page * ps + pos % ps, jnp.int32)
+    pool = kv_append(pool[None], slot, new[None], interpret=not on_chip)[0]
+    args = (q, pool, jnp.asarray(tbl), jnp.asarray(q_starts),
+            jnp.asarray(q_lens), jnp.asarray(kv_lens))
+    got = ragged_latent_attention(*args, v_width=v_width, scale=0.135,
+                                  q_block=qb, interpret=not on_chip)
+    want = ragged_latent_attention_reference(
+        q, pool, tbl, q_starts, q_lens, kv_lens, v_width=v_width,
+        scale=0.135)
+    live = np.zeros((t,), bool)
+    for s0, ql in zip(q_starts, q_lens):
+        live[s0:s0 + ql] = True
+    check(bool(jnp.isfinite(got).all()), "latent: non-finite output")
+    err = float(jnp.abs(got.astype(jnp.float32) - want)[live].max())
+    # bf16 products with f32 accumulation against an f32 oracle on the
+    # same bf16 inputs: outputs are means of N(0, 1) rows, sized ~0.1-1
+    tol = 3e-2 if on_chip else 1e-4
+    check(err <= tol, f"latent: kernel differs from its reference by "
+                      f"{err:.4f} (limit {tol})")
+    # the append wrote this launch's rows where the tables say
+    landed = np.asarray(pool.astype(jnp.float32))[page[live], (pos % ps)[live]]
+    check(np.array_equal(landed, np.asarray(new.astype(jnp.float32))[live]),
+          "latent: appended rows are not where the tables say")
+    print(json.dumps({"leg": "latent", "heads": h, "row": row,
+                      "width": width, "tokens": int(q_lens.sum()),
+                      "max_abs_err": err, "limit": tol}), flush=True)
+
 
 def train_steps(paddle, widths, layers, batch, seq, steps, sharding=None):
     """Build model + AdamW + TrainStep from the seed, take ``steps`` steps
@@ -363,6 +436,7 @@ def main(argv=None):
     else:
         serve_leg(paddle, dev, on_chip, args.rehearse)
         gc.collect()
+        latent_leg(on_chip, args.rehearse)
         train_leg(paddle, dev, on_chip, args.rehearse)
 
     if not on_chip or args.rehearse:

@@ -642,7 +642,243 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
     return jnp.asarray(out)
 
 
+# ---------------------------------------------------------------------------
+# latent (MLA) pages: one compressed row a token, every head against it
+# ---------------------------------------------------------------------------
+
+def _latent_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, q_ref, c_hbm,
+                   o_ref, c_buf, sem, m_ref, l_ref, acc_ref, *, page_size,
+                   q_block, heads, v_width):
+    g = pl.program_id(0)          # q block
+    row = row_ref[g]
+    q_len = ql_ref[row]
+    kv_len = kl_ref[row]
+    kv_start = kv_len - q_len
+    blk_off = g * q_block - qs_ref[row]
+    H = heads
+    slab = c_buf.shape[1]         # KV tokens a fetch
+    ppf = slab // page_size
+
+    live_block = (blk_off >= 0) & (blk_off < q_len)
+    horizon = jnp.where(
+        live_block, jnp.minimum(kv_len, kv_start + blk_off + q_block), 0)
+    n_pages = pl.cdiv(horizon, page_size)
+    n_slabs = pl.cdiv(horizon, slab)
+
+    def each_live_page(i, slot, op):
+        """``op`` on the copy of every live page of slab ``i``: one DMA
+        a page brings its ``page_size`` whole rows; pages of the last
+        slab past the block's last live page are not fetched."""
+        for j in range(ppf):
+            p = i * ppf + j
+
+            @pl.when(p < n_pages)
+            def _copy():
+                op(pltpu.make_async_copy(
+                    c_hbm.at[tbl_ref[row, p]],
+                    c_buf.at[slot, pl.ds(j * page_size, page_size)],
+                    sem.at[slot]))
+
+    def walk(nq):
+        """The block's first ``nq`` tokens (static) against the walk:
+        ``nq * heads`` query rows ride each product. A decode row is
+        ``nq = 1``: its block's other rows are slot padding, and their
+        share of the two products would be seven eighths of the work."""
+        rows = nq * H
+        m_ref[:rows] = jnp.full((rows, 1), _NEG_INF, jnp.float32)
+        l_ref[:rows] = jnp.zeros((rows, 1), jnp.float32)
+        acc_ref[:rows] = jnp.zeros((rows, v_width), jnp.float32)
+
+        @pl.when(n_slabs > 0)
+        def _first():
+            each_live_page(0, 0, lambda c: c.start())
+
+        # the last key each query row may see: its own position (causal),
+        # and for a slot's padding tokens nothing past the row's context
+        tok = blk_off + jax.lax.broadcasted_iota(
+            jnp.int32, (nq, H, 1), 0).reshape(rows, 1)
+        last = jnp.minimum(kv_start + tok, kv_len - 1)
+        # slabs wholly under the block's FIRST query's position are seen
+        # whole by every row of it (and were fetched whole): no mask, and
+        # most of a long context's walk
+        n_clear = jnp.minimum((kv_start + blk_off + 1) // slab, n_slabs)
+
+        def _slab(i, carry, masked):
+            slot = i % 2
+
+            @pl.when(i + 1 < n_slabs)
+            def _next():          # flies while slab i is multiplied
+                each_live_page(i + 1, 1 - slot, lambda c: c.start())
+
+            each_live_page(i, slot, lambda c: c.wait())
+            base = i * slab
+            c = c_buf[slot]                               # [slab, W]
+            # the whole row is the key; its first v_width values are
+            # also the value
+            v = c[:, :v_width]
+            s = jax.lax.dot_general(
+                q_ref[:rows], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [rows, slab]
+            if masked:
+                # rows past the horizon are pool positions no query of
+                # this block may see, or pages that were not fetched:
+                # their weight is exactly 0, and 0 x what lies there
+                # must stay 0
+                vpos = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (slab, v_width), 0)
+                v = jnp.where(vpos < horizon, v, 0)
+                pos = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, slab), 1)
+                s = jnp.where(pos <= last, s, _NEG_INF)
+            m_prev = m_ref[:rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            e = jnp.exp(s - m_new)
+            l_ref[:rows] = l_ref[:rows] * alpha \
+                + jnp.sum(e, axis=1, keepdims=True)
+            m_ref[:rows] = m_new
+            acc_ref[:rows] = acc_ref[:rows] * alpha + jax.lax.dot_general(
+                e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [rows, v_width]
+            return carry
+
+        jax.lax.fori_loop(
+            0, n_clear, functools.partial(_slab, masked=False), None)
+        jax.lax.fori_loop(
+            n_clear, n_slabs, functools.partial(_slab, masked=True), None)
+        o_ref[:rows] = (acc_ref[:rows] / jnp.maximum(l_ref[:rows], 1e-30)) \
+            .astype(o_ref.dtype)
+
+    if q_block == 1:
+        walk(1)
+        return
+    one = live_block & (q_len - blk_off == 1)
+
+    @pl.when(one)
+    def _decode():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        walk(1)
+
+    @pl.when(jnp.logical_not(one))
+    def _chunk():
+        walk(q_block)
+
+
+def ragged_latent_attention(q, c_pages, block_tables, q_starts, q_lens,
+                            kv_lens, *, v_width, scale, q_block=8,
+                            interpret=False):
+    """The ragged step's attention over a LATENT paged cache (multi-head
+    latent attention in its absorbed form): a token holds ONE row in the
+    pool, every head's key, and the row's first ``v_width`` values are
+    every head's value. ``score[t, h, j] = scale * q[t, h] . c[j]`` for
+    ``j <= t``'s position; ``out[t, h] = softmax(score) c[:, :v_width]``.
+
+    q:        [total_q_tokens, heads, W] — each head's query in the
+        row's coordinates (``[q_nope W_uk ; q_rope]``, zero where the
+        row is padded), packed row-wise as
+        :func:`ragged_paged_attention` packs them.
+    c_pages:  [num_pages, page_size, W] — no kv-head axis, no K and V.
+    block_tables, q_starts, q_lens, kv_lens: as the ragged kernel's.
+    Returns [total_q_tokens, heads, v_width]; padding rows hold finite
+    garbage and must be ignored by the caller.
+
+    The walk is the ragged kernel's (q blocks over slabs of
+    ``ragged_slab_pages`` pages, double-buffered, ending at the block's
+    causal horizon); what differs is the product: all ``heads`` of a q
+    block's tokens ride ONE ``[q_block * heads, W] x [W, slab]`` matmul a
+    slab, and a decode row's single live token its ``[heads, W]`` part
+    of it. Products run in the pool's dtype with float32 accumulation.
+    """
+    t, h, w = q.shape
+    _, page_size, wc = c_pages.shape
+    if wc != w:
+        raise ValueError(f"row width mismatch: q {w} vs pages {wc}")
+    if not 0 < v_width <= w:
+        raise ValueError(f"v_width {v_width} outside the row's {w}")
+    if t % q_block != 0:
+        raise ValueError(f"total_q_tokens {t} not a multiple of q_block "
+                         f"{q_block}")
+    return _latent_call(q, c_pages, block_tables, q_starts, q_lens, kv_lens,
+                        v_width=int(v_width), scale=float(scale),
+                        q_block=q_block, interpret=interpret)
+
+
+# jitted on its own, as ``_ragged_call`` is
+@functools.partial(jax.jit, static_argnames=("v_width", "scale", "q_block",
+                                             "interpret"))
+def _latent_call(q, c_pages, block_tables, q_starts, q_lens, kv_lens, *,
+                 v_width, scale, q_block, interpret):
+    t, h, w = q.shape
+    _, page_size, _ = c_pages.shape
+    pages_per_seq = block_tables.shape[1]
+    num_blocks = t // q_block
+    rows = q_block * h
+    q_starts = q_starts.astype(jnp.int32)
+    block_row = ragged_block_row(q_starts, num_blocks, q_block)
+    # the ragged kernel's slab: 512 and 1,024 tokens read 10-12 % faster
+    # on the chip and 2,048 slower (PERF.md section 6, PR 33)
+    slab = ragged_slab_pages(page_size, pages_per_seq) * page_size
+
+    def _q_map(g, *prefetch):
+        return (g, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(num_blocks,),
+        in_specs=[pl.BlockSpec((rows, w), _q_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rows, v_width), _q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, slab, w), c_pages.dtype),   # double-buffered
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, 1), jnp.float32),        # m
+            pltpu.VMEM((rows, 1), jnp.float32),        # l
+            pltpu.VMEM((rows, v_width), jnp.float32),  # acc
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=page_size,
+                          q_block=q_block, heads=h, v_width=v_width),
+        out_shape=jax.ShapeDtypeStruct((t * h, v_width), q.dtype),
+        grid_spec=grid_spec, interpret=interpret,
+        name="ragged_latent_attention",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+    )(block_row, q_starts, q_lens.astype(jnp.int32),
+      kv_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
+      # the softmax scale rides the queries: one multiply a query value
+      # here, not one a score in the body
+      (q * scale).astype(c_pages.dtype).reshape(t * h, w), c_pages)
+    return out.reshape(t, h, v_width)
+
+
+def ragged_latent_attention_reference(q, c_pages, block_tables, q_starts,
+                                      q_lens, kv_lens, *, v_width, scale):
+    """jnp oracle for :func:`ragged_latent_attention`: per sequence,
+    gather its rows densely and run a causally-masked softmax over its
+    chunk's queries; rows outside any live slot stay zero."""
+    t, h, w = q.shape
+    out = np.zeros((t, h, v_width), np.float32)
+    q_starts, q_lens, kv_lens = (np.asarray(x) for x in
+                                 (q_starts, q_lens, kv_lens))
+    for i in range(len(q_lens)):
+        ql, kl = int(q_lens[i]), int(kv_lens[i])
+        if ql == 0:
+            continue
+        qs = int(q_starts[i])
+        c = c_pages[block_tables[i]].astype(jnp.float32).reshape(-1, w)
+        s = jnp.einsum("qhw,sw->hqs", q[qs:qs + ql].astype(jnp.float32),
+                       c) * scale
+        pos = np.arange(s.shape[-1])
+        limit = (kl - ql + np.arange(ql))[None, :, None]
+        ok = (pos[None, None, :] <= limit) & (pos[None, None, :] < kl)
+        p = jax.nn.softmax(jnp.where(jnp.asarray(ok), s, _NEG_INF), axis=-1)
+        out[qs:qs + ql] = np.asarray(
+            jnp.einsum("hqs,sv->qhv", p, c[:, :v_width]))
+    return jnp.asarray(out)
+
+
 __all__ = ["kv_append", "paged_attention", "paged_attention_reference",
            "ragged_block_row", "ragged_kv_tokens_read",
+           "ragged_latent_attention", "ragged_latent_attention_reference",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_slab_pages"]

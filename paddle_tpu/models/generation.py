@@ -144,14 +144,18 @@ def host_dispatch_count() -> int:
 # pure forward math (mirrors models/llama.py layers; parity-tested)
 # ---------------------------------------------------------------------------
 
-def _rope(x, pos, theta, head_dim):
+def _rope(x, pos, theta, head_dim, inv_freq=None):
     """x: [b, s, h, d]; pos: [b, s] absolute positions.
 
     Interleaved adjacent-pair convention — must match the training
     model's op exactly (nn/functional/attention.py _rope_reference).
+    ``inv_freq [d/2]``: the frequencies where they are not ``theta``'s
+    own (a scaled rotary: ``models/deepseek_mla.py::yarn_inv_freq``).
     """
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                               dtype=jnp.float32)
+                                    / head_dim))
     ang = pos.astype(jnp.float32)[..., None] * inv_freq       # [b, s, d/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
@@ -250,6 +254,11 @@ class LayerKind:
     qk_norm: bool = False
     #: "dense" SwiGLU, or "sparse": routed experts + a shared expert
     mlp: str = "dense"
+    #: latent attention: a token caches ONE compressed row every head
+    #: reads (its sizes and rotary are the config's), not keys and
+    #: values a kv head; the pool is then a latent one
+    #: (serving/kv_cache.py ``latent_row``)
+    latent: bool = False
 
 
 def layer_kinds(cfg):

@@ -27,16 +27,39 @@ from ..kernels.grouped_matmul import grouped_matmul
 F32 = jnp.float32
 
 
-def route_sigmoid(x, router, bias, *, top_k, scaling, norm_topk_prob=True):
+def limit_to_groups(choice, n_group, topk_group):
+    """The group limit of a ``noaux_tc`` router: the ``[T, E]`` choice
+    scores' ``E`` experts are ``n_group`` equal groups of neighbours, a
+    group's score is the sum of its two largest choice scores, the
+    ``topk_group`` best groups are kept and the other groups' scores
+    become ``-inf`` (never chosen; the published code writes 0 there,
+    the same choice wherever the kept groups hold ``top_k`` positive
+    scores, as sigmoid scores with a zero bias always do)."""
+    t, e = choice.shape
+    groups = choice.reshape(t, n_group, e // n_group)
+    score = jnp.sum(jax.lax.top_k(groups, 2)[0], -1)          # [T, n_group]
+    _, kept = jax.lax.top_k(score, topk_group)
+    keep = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], kept].set(True)
+    return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(t, e)
+
+
+def route_sigmoid(x, router, bias, *, top_k, scaling, norm_topk_prob=True,
+                  n_group=1, topk_group=1):
     """Experts and gates of every token. ``x [T, h]``; ``router [h,
     router_width]``; ``bias [router_width]`` (the score-correction bias:
     it steers the CHOICE, the gate is the raw score). Scores in float32
     at full precision whatever ``x`` is stored in: a near-tie between
-    the k-th and (k+1)-th expert flips on less. Returns ``(idx [T, k]
-    int32, gates [T, k] float32)``."""
+    the k-th and (k+1)-th expert flips on less. ``n_group`` > 1 (static)
+    limits the choice to the ``topk_group`` best groups
+    (:func:`limit_to_groups`); 1 lowers what it lowered before. Returns
+    ``(idx [T, k] int32, gates [T, k] float32)``."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
                                precision="highest"))
-    _, idx = jax.lax.top_k(s + bias.astype(F32), top_k)
+    choice = s + bias.astype(F32)
+    if n_group > 1:
+        choice = limit_to_groups(choice, n_group, topk_group)
+    _, idx = jax.lax.top_k(choice, top_k)
     g = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
         g = g / jnp.sum(g, -1, keepdims=True)
@@ -114,4 +137,4 @@ def dropless_experts(x, idx, gates, live, gate_w, up_w, down_w, *, first,
 
 
 __all__ = ["buffer_rows", "dispatch_plan", "dropless_experts",
-           "route_sigmoid"]
+           "limit_to_groups", "route_sigmoid"]

@@ -363,6 +363,9 @@ class LLMEngine:
         self._kinds = layer_kinds(cfg)
         self._plain_layers = all(k == LayerKind() for k in self._kinds)
         windows = sorted({k.window for k in self._kinds if k.window})
+        #: latent layers (a token caches one compressed row every head
+        #: reads): the pool is then a latent one, all layers of the kind
+        self._latent = any(k.latent for k in self._kinds)
         if not self._plain_layers:
             refused = {
                 "quantized_mode": quantized_mode is not None,
@@ -383,6 +386,13 @@ class LLMEngine:
                                        or bool(pinned_prefix_pages)
                                        or fleet_prefix_cache is not None),
                 "layers with different windows": len(windows) > 1,
+                "latent layers beside layers that cache keys and values":
+                    self._latent and not all(k.latent for k in self._kinds),
+                "prefix_store / fleet_prefix_cache / pinned_prefix_pages "
+                "(with latent layers: they move pages as (K, V) blocks)":
+                    self._latent and (prefix_store is not None
+                                      or fleet_prefix_cache is not None
+                                      or bool(pinned_prefix_pages)),
             }
             if any(refused.values()):
                 raise ValueError(
@@ -488,6 +498,13 @@ class LLMEngine:
                 prefetch=bool(kv_prefetch),
                 prefetch_depth=self._kv_prefetch_depth,
                 spill_seed=kv_spill_seed)
+        elif self._latent:
+            # one compressed row a token a layer, one array a layer
+            self.pool = PagedKVPool(
+                cfg.num_hidden_layers, 1, cfg.latent_row,
+                num_pages=num_pages, page_size=page_size, dtype=dtype,
+                high_watermark=high_watermark, low_watermark=low_watermark,
+                latent_row=cfg.latent_row)
         else:
             # window layers keep their pages in a second group of the
             # pool (kv_cache.py), sized so that every row slot can hold
@@ -737,8 +754,9 @@ class LLMEngine:
         # under PADDLE_TPU_FORCE_PALLAS (int8_matmul's discipline)
         mk_interpret = interpret if self._interpret_explicit else None
         quant_pool = self.pool.quantized
-        H, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
+        # a latent model's sizes are its own (spec_decode._latent_attention)
+        H, Hkv, d = (None,) * 3 if self._latent else (
+            cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
         scope = self.megakernel_scope
         num_layers = cfg.num_hidden_layers
         prefill_fused = self.prefill_megakernel == "fused"
@@ -911,8 +929,15 @@ class LLMEngine:
                                   for li in range(num_layers)]
             else:
                 new_kv, new_scales = [], []
-                for li, (lyr, (Kp, Vp)) in enumerate(
+                for li, (lyr, pages) in enumerate(
                         zip(params["layers"], kv)):
+                    if kinds[li].latent:
+                        # a latent layer's pages are one array
+                        h, pages, _ = fp_layer(lyr, None, h, pages, None,
+                                               kinds[li])
+                        new_kv.append(pages)
+                        continue
+                    Kp, Vp = pages
                     ad = adapters[li] if adapters is not None else None
                     if not quant_pool:
                         h, Kp, Vp = fp_layer(lyr, ad, h, Kp, Vp, kinds[li])
@@ -2334,30 +2359,45 @@ class LLMEngine:
             spec_lens[i] = spec
             if slot_ids is not None and seq.adapter_slot:
                 slot_ids[q_start:q_start + q_len] = seq.adapter_slot
-        # by layer kind: the layers that see every key, and those that
-        # see a window (all of one width, LLMEngine.__init__)
-        window = self.pool.window
-        n_win = len(self.pool.window_layers)
-        n_full = len(self._kinds) - n_win
-
-        def walked(w):
-            return ragged_kv_tokens_read(
-                q_lens, kv_lens, q_block=self.q_block,
-                page_size=self.page_size, pages_per_seq=PPS, window=w)
         live_kv = int(kv_lens.sum())
         sp.set(rows=len(plan.rows), prefill_tokens=prefill_tokens,
                decode_tokens=int(q_lens.sum()) - prefill_tokens,
                # what attention must read: every row's context
-               live_kv_tokens=live_kv,
-               # the same summed over the layers, a window layer's rows
-               # counted up to window + chunk
-               attn_kv_tokens_live=n_full * live_kv + (n_win and n_win * int(
-                   np.minimum(kv_lens, window + q_lens).sum())),
-               # what the ragged kernel's walk covers, a kv head, a
-               # layer (the mean over layers where they differ)
-               attn_kv_tokens_read=((n_full and n_full * walked(None))
-                                    + (n_win and n_win * walked(window)))
-               // len(self._kinds))
+               live_kv_tokens=live_kv)
+        if self._latent:
+            # what the latent kernel's FLOPs stand on: over the layers,
+            # the rows and each row's query tokens, the keys each sees
+            # (the token at position p sees p + 1); and the bytes the
+            # pool really holds for the rows aboard: its pages in use
+            # now, this step's appends claimed, over every layer, the
+            # rows' lane padding included
+            ql, kl = q_lens.astype(np.int64), kv_lens.astype(np.int64)
+            sp.set(attn_qk_pairs=len(self._kinds) * int(
+                (ql * (kl - ql) + ql * (ql + 1) // 2).sum()),
+                latent_bytes_held=self.pool.used_pages
+                * self.pool.page_bytes)
+        else:
+            # by layer kind: the layers that see every key, and those
+            # that see a window (all of one width, LLMEngine.__init__)
+            window = self.pool.window
+            n_win = len(self.pool.window_layers)
+            n_full = len(self._kinds) - n_win
+
+            def walked(w):
+                return ragged_kv_tokens_read(
+                    q_lens, kv_lens, q_block=self.q_block,
+                    page_size=self.page_size, pages_per_seq=PPS, window=w)
+            sp.set(
+                # the rows' contexts summed over the layers, a window
+                # layer's rows counted up to window + chunk
+                attn_kv_tokens_live=n_full * live_kv + (
+                    n_win and n_win * int(
+                        np.minimum(kv_lens, window + q_lens).sum())),
+                # what the ragged kernel's walk covers, a kv head, a
+                # layer (the mean over layers where they differ)
+                attn_kv_tokens_read=((n_full and n_full * walked(None))
+                                     + (n_win and n_win * walked(window)))
+                // len(self._kinds))
         sp.phase("serve.dispatch")
         if draft_tokens is None:
             # ordinary round: the prebuilt zero operands on the device

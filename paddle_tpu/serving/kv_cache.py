@@ -52,8 +52,19 @@ Admission, preemption with recompute, ``free`` and ``check_invariants``
 cover both groups; sharing (fork, pins, export/adopt, the host tier)
 knows one kind of page and is refused by name on such a pool.
 
-The device arrays themselves live in ``kv`` (one (K, V) pair per layer)
-and are updated *functionally* by the engine's jitted ragged step (the
+A latent pool (``latent_row=W``; multi-head latent attention): a token
+holds ONE row of ``W`` values a layer, every head's key and value in
+compressed form, so a layer's pages are ONE array ``[num_pages,
+page_size, W]`` with no kv-head axis and no K/V pair
+(``kernels/paged_attention.py::ragged_latent_attention`` reads it).
+Allocation, sharing by refcount (fork, pins, copy-on-write), preemption,
+``free``, ``rollback`` and ``check_invariants`` are about pages and do
+not care what a page holds; what moves page DATA in the (K, V) wire
+format (export, adopt, the prefix store, the host tier, a mesh, int8)
+is refused by name.
+
+The device arrays themselves live in ``kv`` (one (K, V) pair per layer;
+one array per layer in a latent pool) and are updated *functionally* by the engine's jitted ragged step (the
 engine reassigns ``kv`` after each donated call); this class tracks the
 host-side ownership metadata plus the eager CoW/scale-reset fixups.
 """
@@ -66,9 +77,11 @@ import numpy as np
 
 def _copy_pages(kv, old_idx, new_idx):
     """Duplicate pool pages ``old_idx`` into ``new_idx`` across every
-    layer's (K, V) pair — the device side of copy-on-write."""
-    return [(K.at[:, new_idx].set(K[:, old_idx]),
-             V.at[:, new_idx].set(V[:, old_idx])) for K, V in kv]
+    layer's (K, V) pair — the device side of copy-on-write. A latent
+    pool's layer is one array with the pages leading."""
+    return [C.at[new_idx].set(C[old_idx]) if not isinstance(C, tuple)
+            else (C[0].at[:, new_idx].set(C[0][:, old_idx]),
+                  C[1].at[:, new_idx].set(C[1][:, old_idx])) for C in kv]
 
 
 _COPY_JIT = None
@@ -132,11 +145,27 @@ class PagedKVPool:
     def __init__(self, num_layers, num_kv_heads, head_dim, *, num_pages,
                  page_size, dtype=jnp.float32, high_watermark=0.90,
                  low_watermark=0.50, pinned_page_budget=0, mesh=None,
-                 window_layers=(), window=None, window_pages=None):
+                 window_layers=(), window=None, window_pages=None,
+                 latent_row=None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         # the second page group: which layers' pages live in it, how
         # many keys a query of theirs sees, how many pages it has
+        #: values a token holds a layer in a latent pool (the row as the
+        #: device lays it out, padding included); None = (K, V) pages
+        self.latent_row = None if latent_row is None else int(latent_row)
+        if self.latent_row is not None:
+            if window_layers or mesh is not None \
+                    or jnp.dtype(dtype) == jnp.dtype(jnp.int8):
+                raise ValueError(
+                    "a latent pool is single-device, unquantized and of "
+                    "one page group: window layers, a mesh and int8 pages "
+                    "know (K, V) pages")
+            if (num_kv_heads, head_dim) != (1, self.latent_row):
+                raise ValueError(
+                    f"a latent pool has no kv-head axis: pass "
+                    f"num_kv_heads 1 and head_dim {self.latent_row}, the "
+                    f"row, not ({num_kv_heads}, {head_dim})")
         self.window_layers = tuple(sorted(int(i) for i in window_layers))
         self.window = None
         self.window_num_pages = 0
@@ -182,6 +211,10 @@ class PagedKVPool:
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
         self.kv = []
         for i in range(num_layers):
+            if self.latent_row is not None:
+                self.kv.append(jnp.zeros(
+                    (num_pages, page_size, self.latent_row), dtype))
+                continue
             n = self.window_num_pages if i in self.window_layers \
                 else num_pages
             shape = (num_kv_heads, n, page_size, head_dim)
@@ -252,7 +285,11 @@ class PagedKVPool:
     @property
     def page_bytes(self) -> int:
         """Bytes of one page of the full group (every layer's, without
-        window layers)."""
+        window layers); a latent pool's page is one row a token a
+        layer."""
+        if self.latent_row is not None:
+            return self.num_layers * self.page_size * self.latent_row \
+                * self.dtype.itemsize
         return self.page_bytes_for(
             self.num_layers - len(self.window_layers), self.num_kv_heads,
             self.head_dim, self.page_size, self.dtype)
@@ -531,6 +568,13 @@ class PagedKVPool:
         return fresh
 
     # ---- the window group ----
+    def _kv_pairs(self, what):
+        if self.latent_row is not None:
+            raise ValueError(
+                f"PagedKVPool.{what}: this pool holds latent rows, one "
+                f"array a layer, and the page wire format is (K, V) "
+                f"blocks a kv head")
+
     def _one_group(self, what):
         if self.window_layers:
             raise ValueError(
@@ -725,6 +769,7 @@ class PagedKVPool:
         chains last-written): per chain, per layer, the K/V page blocks
         ``[Hkv, n_pages, page_size, head_dim]`` (plus the per-(head,
         page) scale columns for int8 pools) as host numpy arrays."""
+        self._kv_pairs("export_pinned")
         out = []
         for cid, (pages, num_tokens) in self._pins.items():
             idx = jnp.asarray(pages, jnp.int32)
@@ -755,6 +800,7 @@ class PagedKVPool:
         columns) — the HostKVArena ``layers`` format, which makes spill
         buffers, fleet transfers, and prefix publishes one wire
         format."""
+        self._kv_pairs("_read_pages")
         idx = jnp.asarray(pages, jnp.int32)
         out = []
         for li, (K, V) in enumerate(self.kv):
@@ -775,6 +821,7 @@ class PagedKVPool:
         layers)`` in the arena/adopt wire format. Read-only: refcounts,
         tables, and sharing are untouched."""
         self._one_group("export_pages")
+        self._kv_pairs("export_pages")
         if num_tokens is None:
             num_tokens = self._lens[seq_id]
         if num_tokens > self._lens[seq_id]:
@@ -799,6 +846,7 @@ class PagedKVPool:
         this to stage into the host arena instead (the sequence lands
         PARKED and rides the prefetch/restore path into HBM)."""
         self._one_group("adopt_sequence")
+        self._kv_pairs("adopt_sequence")
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
         if len(layers) != self.num_layers:
@@ -852,6 +900,7 @@ class PagedKVPool:
         shape/dtype drift in its structured mismatch error before this
         layer ever sees it)."""
         self._one_group("restore_pinned_chain")
+        self._kv_pairs("restore_pinned_chain")
         if num_tokens % self.page_size != 0:
             raise ValueError(
                 f"restored chains must be page-aligned: {num_tokens} "

@@ -50,7 +50,8 @@ from ..core.flags import define_flag
 from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  _wmat, extract_params, request_keys,
                                  sample_rows, sampling_probs)
-from ..kernels.paged_attention import kv_append, ragged_paged_attention
+from ..kernels.paged_attention import (kv_append, ragged_latent_attention,
+                                       ragged_paged_attention)
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 
 
@@ -173,8 +174,10 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     head, rotary or none, a window handed to the kernel (``tbls`` and
     the pools are then its page group's), a dense or a routed
     feed-forward (``nn/moe_dropless.py``; a routed layer appends its
-    ``[3]`` counts to the list ``moe_stats``). Layers of every kind
-    trace into the one executable.
+    ``[3]`` counts to the list ``moe_stats``), or latent attention
+    (:func:`_latent_attention`: ``Kp`` is then the layer's ONE pool
+    array, ``Vp`` None, and None comes back in its place). Layers of
+    every kind trace into the one executable.
 
     This is THE fp layer body — the engine's ragged step (fp pools) and
     the draft worker's forward both call it, so draft/target numerics
@@ -191,8 +194,6 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     None (the default) adds NO operands, so adapter-free engines lower
     byte-identical HLO."""
     ps = page_size
-    H, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
     T = h.shape[1]
 
     def lo(p):
@@ -200,6 +201,23 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
             return None
         A, B = adapters[p]
         return (A, B, slots)
+
+    def feed_forward(h):
+        x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
+        if kind.mlp == "sparse":
+            return h + _routed_mlp(lyr, x, live, cfg, interpret, moe_stats)
+        return h + _wmat(jax.nn.silu(_wmat(x, lyr["gate"], lora=lo("gate")))
+                         * _wmat(x, lyr["up"], lora=lo("up")),
+                         lyr["down"], lora=lo("down"))
+
+    slot = _page_slots(positions, tbls, tok_row, live, ps, max_pages)
+    if kind.latent:
+        h, Kp = _latent_attention(lyr, h, Kp, positions, slot, tbls,
+                                  q_starts, q_lens, kv_lens, cfg, q_block,
+                                  interpret)
+        return feed_forward(h), Kp, None
+    H, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                 cfg.head_dim)
 
     x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
     q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
@@ -213,25 +231,62 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
         k = _rope(k, positions[None], cfg.rope_theta, d)
     kt = jnp.transpose(k[0], (1, 0, 2))                  # [Hkv, T, d]
     vt = jnp.transpose(v[0], (1, 0, 2))
-    # every live token's K/V goes to its page slot; dead tokens (slot
-    # padding / pad rows) name the null page, which the append skips
-    page_idx = jnp.clip(positions // ps, 0, max_pages - 1)
-    page = jnp.where(live, tbls[tok_row, page_idx], NULL_PAGE)
-    slot = page * ps + positions % ps
     Kp = kv_append(Kp, slot, kt, interpret=interpret)
     Vp = kv_append(Vp, slot, vt, interpret=interpret)
     o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
                                kv_lens, q_block=q_block,
                                interpret=interpret, window=kind.window)
     h = h + _wmat(o.reshape(1, T, H * d), lyr["o"], lora=lo("o"))
-    x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
-    if kind.mlp == "sparse":
-        return h + _routed_mlp(lyr, x, live, cfg, interpret,
-                               moe_stats), Kp, Vp
-    h = h + _wmat(jax.nn.silu(_wmat(x, lyr["gate"], lora=lo("gate")))
-                  * _wmat(x, lyr["up"], lora=lo("up")),
-                  lyr["down"], lora=lo("down"))
-    return h, Kp, Vp
+    return feed_forward(h), Kp, Vp
+
+
+def _page_slots(positions, tbls, tok_row, live, page_size, max_pages):
+    """The pool row each packed token's cache entry goes to: every live
+    token's page slot; dead tokens (slot padding / pad rows) name the
+    null page, which the append skips."""
+    page_idx = jnp.clip(positions // page_size, 0, max_pages - 1)
+    page = jnp.where(live, tbls[tok_row, page_idx], NULL_PAGE)
+    return page * page_size + positions % page_size
+
+
+def _latent_attention(lyr, h, Cp, positions, slot, tbls, q_starts, q_lens,
+                      kv_lens, cfg, q_block, interpret):
+    """The attention half of a LATENT layer (``models/deepseek_mla.py``
+    writes the equations down), absorbed: queries through their low
+    rank and its norm; the token's compressed row ``[RMSNorm(c_kv) ;
+    rope(k_r)]`` appended ONCE to the layer's one pool array ``Cp
+    [pages, page_size, row]``; each head's ``q_nope`` carried into the
+    row's coordinates by ``w_uk``; ``ragged_latent_attention`` over the
+    rows; the weighted rows carried back out by ``w_uv``; ``o``.
+    ``slot [T]``: :func:`_page_slots`. Returns ``(h, Cp)``."""
+    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    T, W = h.shape[1], Cp.shape[-1]
+    inv_freq = jnp.asarray(cfg.rope_inv_freq())
+    x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
+    c_q = _rms_norm(_wmat(x, lyr["q_a"]), lyr["q_a_norm"], cfg.rms_norm_eps)
+    q = _wmat(c_q, lyr["q_b"]).reshape(1, T, H, nope + rope)
+    q_rope = _rope(q[..., nope:], positions[None], None, rope, inv_freq)
+    ckv = _wmat(x, lyr["kv_a"])                          # [1, T, r + rope]
+    c_kv = _rms_norm(ckv[..., :r], lyr["kv_a_norm"], cfg.rms_norm_eps)
+    k_r = _rope(ckv[..., None, r:], positions[None], None, rope,
+                inv_freq)[:, :, 0]
+    pad = W - r - rope                                   # the row's padding
+    row = jnp.concatenate(
+        [c_kv[0], k_r[0], jnp.zeros((T, pad), c_kv.dtype)], -1)
+    # the append kernel's pages carry a kv-head axis: one head here
+    Cp = kv_append(Cp[None], slot, row[None], interpret=interpret)[0]
+    # absorb: q_nope . k_nope_j = (q_nope w_uk) . c_kv_j
+    q_lat = jnp.einsum("thn,hnr->thr", q[0, ..., :nope], lyr["w_uk"])
+    q_row = jnp.concatenate(
+        [q_lat, q_rope[0].astype(q_lat.dtype),
+         jnp.zeros((T, H, pad), q_lat.dtype)], -1)       # [T, H, W]
+    o = ragged_latent_attention(
+        q_row, Cp, tbls, q_starts, q_lens, kv_lens, v_width=r,
+        scale=cfg.softmax_scale, q_block=q_block, interpret=interpret)
+    # un-absorb: o = (sum_j p_j c_kv_j) w_uv
+    o = jnp.einsum("thr,hrv->thv", o, lyr["w_uv"])
+    return h + _wmat(o.reshape(1, T, -1), lyr["o"]), Cp
 
 
 def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
@@ -243,7 +298,9 @@ def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
     idx, gates = route_sigmoid(
         x[0], lyr["router"], lyr["router_bias"],
         top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
-        norm_topk_prob=cfg.norm_topk_prob)
+        norm_topk_prob=cfg.norm_topk_prob,
+        n_group=getattr(cfg, "n_group", 1),
+        topk_group=getattr(cfg, "topk_group", 1))
     y, stats = dropless_experts(
         x[0], idx, gates, live, lyr["experts_gate"], lyr["experts_up"],
         lyr["experts_down"], first=cfg.expert_offset, interpret=interpret)

@@ -236,6 +236,24 @@ def _prefill_layer(d, h, hkv, dh, ffn, model_scope=False):
                 _s((R,), i32)), 1
 
 
+def _latent(T, row, *, h=128, R=32, pages=16384, pps=1040, append=False):
+    """dots-vlm1-inst.long-doc's attention: 128 heads against a latent
+    pool of one ``row``-wide row a token, 32 rows x 1,040 page slots;
+    with ``append``, the row's append in front, the pool donated."""
+    from paddle_tpu.kernels.paged_attention import (kv_append,
+                                                    ragged_latent_attention)
+    i32 = jnp.int32
+
+    def fn(C, q, x, slot, tbl, qs, ql, kl):
+        if append:
+            C = kv_append(C[None], slot, x[None])[0]
+        return ragged_latent_attention(q, C, tbl, qs, ql, kl, v_width=512,
+                                       scale=0.135, q_block=8), C
+    return fn, (_s((pages, PS, row)), _s((T, h, row)), _s((T, row)),
+                _s((T,), i32), _s((R, pps), i32), _s((R,), i32),
+                _s((R,), i32), _s((R,), i32)), 2 if append else 1
+
+
 CASES = {
     "flash_fwd_d64": lambda: _flash(DH, H, bwd=False),
     "flash_fwd_bwd_d64": lambda: _flash(DH, H, bwd=True),
@@ -266,6 +284,11 @@ CASES = {
     "kv_append_t8_d128": lambda: _append(8, hkv=8, dh=128),
     "kv_append_t320_d128_f32": lambda: _append(
         320, hkv=8, dh=128, dtype=jnp.float32),
+    # the geometry of dots-vlm1-inst.long-doc: the latent kernel over
+    # rows padded to 640 lanes, and the routed experts at hidden 7168
+    "latent_t512_qb8_row640_dotsvlm1": lambda: _latent(512, 640),
+    "grouped_matmul_dotsvlm1_up": lambda: _grouped(7168, 2048),
+    "grouped_matmul_dotsvlm1_down": lambda: _grouped(2048, 7168),
     "grouped_matmul_kexaone_up": lambda: _grouped(6144, 2048),
     "grouped_matmul_kexaone_down": lambda: _grouped(2048, 6144),
     "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
@@ -351,6 +374,30 @@ def test_kv_append_leaves_the_pool_where_it_lies(name, one_chip, on_tpu):
     assert "(0, {}" in alias and "(1, {}" in alias, (
         f"{name}: the donated pools are not both aliased to results: "
         f"{alias[:200]}")
+
+
+def test_latent_append_leaves_the_pool_where_it_lies(one_chip, on_tpu):
+    """The latent row's one append a layer, the pool donated: aliased to
+    the result through the kv-head axis the append kernel's pages carry
+    (a reshape), no pool-sized copy."""
+    fn, shapes, _ = _latent(512, 640, append=True)
+    text = _compiled_text(fn, one_chip, *shapes, donate=(0,))
+    assert text.count("tpu_custom_call") >= 2
+    copies = _pool_sized_copies(text, 16384 * PS * 640)
+    assert not copies, f"pool-sized copies in the step: {copies}"
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    assert "(0, {}" in alias, alias[:200]
+
+
+def test_the_chip_holds_a_576_wide_row_in_640_lanes(one_chip, on_tpu):
+    """Why the latent pool declares its rows padded (512 + 64 = 576 ->
+    640): the chip's compiler lays a 576-wide bf16 pool out as
+    ``memref<...x640xbf16>`` (HBM tiling (8, 128)) and refuses the
+    kernel's page slice of it, so an unpadded declaration would hold the
+    same bytes and not compile."""
+    fn, shapes, _ = _latent(512, 576)
+    with pytest.raises(Exception, match="aligned to tiling|640"):
+        _compiled_text(fn, one_chip, *shapes)
 
 
 def test_the_scatter_kv_append_replaced_turns_the_pool(one_chip, on_tpu):
