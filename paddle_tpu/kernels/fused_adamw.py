@@ -10,6 +10,14 @@ SMEM so a changing lr/step never retraces; betas/eps/weight_decay are
 compile-time constants. Block size is picked by the measured autotuner
 (kernels/autotune.py) when PADDLE_TPU_AUTOTUNE=1, and off-TPU callers get
 a pure-jnp fallback with identical math.
+
+Where the padding lives: the kernel runs over whole ``block_rows x 128``
+chunks. The engine lays its bucket out ONCE at ``BUCKET_ALIGN``, a multiple
+of every chunk the kernel may pick, and hands ``p``/``g``/``m``/``v`` at
+that length, so ``_run``'s ``jnp.pad`` and ``[:n]`` emit nothing there
+(``pad`` is 0, ``n`` is the whole). ``_run`` keeps both for callers that
+hand it an unaligned array (this module's tests, one-off updates): those
+pay four array-sized pads and three slices a call.
 """
 from __future__ import annotations
 
@@ -23,6 +31,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 512  # 8 f32 row-buffers live at once: ~2 MB of VMEM
+BLOCK_ROWS_CANDIDATES = (128, 256, 512, 1024)  # what the autotuner may pick
+# every chunk the kernel may run over divides this: a flat buffer of a
+# multiple of it needs no pad and no slice (optimizer/fused.py lays its
+# buckets out at it)
+BUCKET_ALIGN = max(BLOCK_ROWS_CANDIDATES) * LANES
 
 
 def _kernel(sc_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref, vo_ref, *,
@@ -72,7 +85,8 @@ def _run(p, g, m, v, scalars, block_rows, interpret, *, beta1, beta2, eps,
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
-        # in-place in HBM: the padded copies are consumed by their outputs
+        # in-place in HBM: an aligned bucket is updated where it lies (an
+        # unaligned caller's padded copies are consumed by their outputs)
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret, name="fused_adamw",
     )(scalars, p2, g2, m2, v2)
@@ -137,7 +151,7 @@ def _pick_block_rows(requested, p, run_fn, interpret, decoupled):
         key=("fused_adamw", n, str(p.dtype), bool(decoupled),
              bool(interpret)),
         requested={"block_rows": requested},
-        candidates=[{"block_rows": b} for b in (128, 256, 512, 1024)
+        candidates=[{"block_rows": b} for b in BLOCK_ROWS_CANDIDATES
                     if b * LANES <= max(n, 128 * LANES)],
         build_fn=lambda c: (lambda: run_fn(c["block_rows"])),
         traced=isinstance(p, jax.core.Tracer))

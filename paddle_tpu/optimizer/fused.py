@@ -17,6 +17,16 @@ per parameter). Optimizer state (moments/velocity) lives as persistent flat
 buffers per bucket; ``sync_to_param_state`` materializes per-param views for
 ``state_dict`` / checkpointing, and bucket rebuilds re-seed from them.
 
+A bucket is laid out once, when it is built: its length is the leaves' total
+rounded up to ``kernels.fused_adamw.BUCKET_ALIGN`` (a multiple of every chunk
+the Pallas kernel may run over) and everything that lives in or passes
+through the bucket has that length — the state spans at rest, the
+concatenated params and grads (a zero tail is the concat's last operand), the
+per-element aux vectors and masks. So the padding lives here, in the layout,
+not in the step: the kernel's own pad and slice find nothing to do, and no
+bucket-sized copy stands between the state and the kernel. Leaves are cut
+out by offset; nothing ever reads the tail.
+
 Fallbacks keep the per-param loop authoritative where flattening is wrong:
 multi-device (sharded/replicated) params or states — distributed/sharding.py
 owns those placements — and optimizers without ``_fused_flat_update``.
@@ -28,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..kernels.fused_adamw import BUCKET_ALIGN
 from .clip import ClipGradByGlobalNorm, ClipGradByValue
 
 # -- dispatch-count trace hook ---------------------------------------------
@@ -71,28 +82,41 @@ def _device_key(a) -> str:
     return "default"
 
 
-def _concat_flat(arrays):
+def bucket_length(total: int) -> int:
+    """A flat bucket's length: the leaves' ``total`` rounded up to the
+    kernel's largest chunk, fixed when the bucket is built."""
+    return -(-total // BUCKET_ALIGN) * BUCKET_ALIGN
+
+
+def _concat_flat(arrays, length):
+    """The leaves raveled into one flat span of ``length`` elements: a zero
+    tail is the concatenation's last operand, so the span arrives at the
+    bucket's length without a pass of its own."""
     # under a GSPMD partitioning scope each raveled span is constrained
     # replicated before the concat: the flat bucket is logically whole,
     # and the 0.4.x CPU SPMD partitioner miscompiles concatenate over
     # dim-0-sharded operands (distributed/gspmd.constrain_flat)
     from ..distributed.gspmd import constrain_flat
-    if len(arrays) == 1:
-        return constrain_flat(arrays[0].ravel())
-    return jnp.concatenate([constrain_flat(a.ravel()) for a in arrays])
+    parts = [constrain_flat(a.ravel()) for a in arrays]
+    tail = length - sum(p.shape[0] for p in parts)
+    if tail:
+        parts.append(jnp.zeros(tail, parts[0].dtype))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def per_element_vector(params, values, dtype=jnp.float32):
     """Per-ELEMENT vector over a bucket's flat span from per-PARAM values
-    (the lr_ratio / apply_decay_param_fun hooks become one broadcast)."""
-    return jnp.concatenate([
-        jnp.full((int(np.prod(tuple(p._data.shape))),), float(v), dtype)
-        for p, v in zip(params, values)])
+    (the lr_ratio / apply_decay_param_fun hooks become one broadcast),
+    bucket-long: zero over the tail."""
+    return _concat_flat(
+        [jnp.full(p._data.size, float(v), dtype)
+         for p, v in zip(params, values)],
+        bucket_length(sum(p._data.size for p in params)))
 
 
 class _Bucket:
     __slots__ = ("params", "idxs", "sizes", "shapes", "grad_dtype", "total",
-                 "state", "static", "aux", "fns", "masks")
+                 "length", "state", "static", "aux", "fns", "masks")
 
 
 class FusedOptimizerEngine:
@@ -179,6 +203,9 @@ class FusedOptimizerEngine:
         b.shapes = [tuple(p._data.shape) for p in b.params]
         b.sizes = [int(np.prod(s)) for s in b.shapes]
         b.total = sum(b.sizes)
+        # the layout, fixed here: every span of the bucket (state at rest,
+        # params and grads in the step, aux vectors, masks) is this long
+        b.length = bucket_length(b.total)
         b.grad_dtype = grad_dtypes[idxs[0]]
         b.static, b.aux = opt._fused_aux(b.params)
         b.fns = {}
@@ -194,7 +221,7 @@ class FusedOptimizerEngine:
                 v = (opt._state.get(id(p)) or {}).get(name)
                 parts.append(jnp.ravel(v).astype(dt) if v is not None
                              else jnp.ravel(init(p._data)).astype(dt))
-            b.state[name] = _concat_flat(parts)
+            b.state[name] = _concat_flat(parts, b.length)
         for p in b.params:
             opt._state.pop(id(p), None)
         self.state_dirty = True
@@ -313,7 +340,8 @@ class FusedOptimizerEngine:
         if mask is None:
             mask = jnp.asarray(np.concatenate(
                 [np.full(sz, ok, bool)
-                 for sz, ok in zip(b.sizes, present)]))
+                 for sz, ok in zip(b.sizes, present)]
+                + [np.zeros(b.length - b.total, bool)]))
             # bound the cache: flickering participation (MoE routing) can
             # produce combinatorially many patterns, each mask is a full
             # bucket-sized array — evict oldest-inserted beyond the cap
@@ -347,13 +375,19 @@ class FusedOptimizerEngine:
         vmin = clip.min if byval else 0.0
         vmax = clip.max if byval else 0.0
         l1 = opt._l1_decay
-        sizes, shapes = list(b.sizes), list(b.shapes)
+        sizes, shapes, length = list(b.sizes), list(b.shapes), b.length
 
         def body(p_arr, g_arr, state, aux, lr, t, scale, mask):
             from ..distributed.gspmd import stage_state
             state = {k: stage_state(v) for k, v in state.items()}
-            flat_p = _concat_flat(list(p_arr))
-            flat_g = _concat_flat(list(g_arr))
+            # The tail past the leaves is zero in p, g and every state
+            # span, and every rule the engine carries keeps it so: a zero
+            # grad scaled, clipped round zero or L1'd stays zero; SGD and
+            # Momentum give p - lr * (0 + wd * 0); Adam's moments stay 0
+            # and its step is 0 / (0 + eps), the decoupled decay wd * 0.
+            # Nothing reads it either way (the leaves are cut by offset).
+            flat_p = _concat_flat(list(p_arr), length)
+            flat_g = _concat_flat(list(g_arr), length)
             gdt = flat_g.dtype
             if use_scale:
                 flat_g = (flat_g.astype(jnp.float32) * scale).astype(gdt)

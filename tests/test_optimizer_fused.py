@@ -11,6 +11,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.core.flags import GLOBAL_FLAGS
+from paddle_tpu.kernels.fused_adamw import BUCKET_ALIGN
 
 F32_TOL = 1e-6
 BF16_TOL = 2e-2  # one bf16 ulp near 1.0 is ~8e-3
@@ -25,6 +26,16 @@ def fused_flag():
 MIXED_SPECS = ([((4, 8), "float32"), ((16,), "float32"), ((), "float32"),
                 ((3, 3, 2), "float32"), ((8, 4), "bfloat16"),
                 ((5,), "bfloat16")] * 3)
+
+
+# a bucket past one chunk of the kernel: the f32 total (139,097) is no
+# multiple of BUCKET_ALIGN (131,072), so the bucket rests at two chunks
+# with a tail of 123,047 zeros; the two norms and the scalar are what
+# breaks the alignment, as in every real model
+PAST_A_CHUNK = [((300, 450), "float32"), ((2048,), "float32"),
+                ((2048,), "float32"), ((), "float32"),
+                ((64, 33), "bfloat16"), ((17,), "bfloat16")]
+SPECS = {"mixed": MIXED_SPECS, "past_a_chunk": PAST_A_CHUNK}
 
 
 def _make_params(specs, seed=0):
@@ -79,15 +90,27 @@ CASES = {
     "adamw_byvalue": lambda ps: paddle.optimizer.AdamW(
         learning_rate=0.01, parameters=ps,
         grad_clip=paddle.nn.ClipGradByValue(0.3)),
+    # the kernel-eligible form (uniform hyperparameters), here through the
+    # jnp body; test_engine_uses_pallas_kernel_when_forced runs the kernel
+    "adamw_plain": lambda ps: paddle.optimizer.AdamW(
+        learning_rate=0.01, parameters=ps, weight_decay=0.05),
+    "momentum_plain": lambda ps: paddle.optimizer.Momentum(
+        learning_rate=0.1, momentum=0.9, parameters=ps),
 }
 
 
+def _flat_tail(bucket, flat):
+    assert flat.shape == (bucket.length,)
+    return np.asarray(flat[bucket.total:].astype("float32"))
+
+
+@pytest.mark.parametrize("specs", sorted(SPECS))
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_fused_matches_per_param(case, fused_flag):
-    factory = CASES[case]
-    _, fused_vals, fused_state, fused_opt = _run(factory, True)
-    _, ref_vals, ref_state, _ = _run(factory, False)
-    _assert_match(MIXED_SPECS, fused_vals, ref_vals)
+def test_fused_matches_per_param(case, specs, fused_flag):
+    factory, specs = CASES[case], SPECS[specs]
+    _, fused_vals, fused_state, fused_opt = _run(factory, True, specs=specs)
+    _, ref_vals, ref_state, _ = _run(factory, False, specs=specs)
+    _assert_match(specs, fused_vals, ref_vals)
     eng = fused_opt._fused_engine
     assert eng is not None and eng.active
     assert len(eng.buckets) == 2  # one f32, one bf16
@@ -119,11 +142,14 @@ def test_build_excludes_stop_gradient_and_missing_grads(fused_flag):
         assert np.array_equal(v, np.asarray(params[i].numpy()))
 
 
-def test_mid_run_grad_drop_masks_without_rebuild(fused_flag):
+@pytest.mark.parametrize("shape", [(4, 4), (150, 150)],
+                         ids=["inside_a_chunk", "past_a_chunk"])
+def test_mid_run_grad_drop_masks_without_rebuild(shape, fused_flag):
     """A param losing its grad mid-run (MoE expert off-route) takes the
-    masked-subset path: untouched value AND state, no bucket rebuild."""
+    masked-subset path: untouched value AND state, no bucket rebuild.
+    The mask is bucket-long (six leaves of 22,500 rest at two chunks)."""
     GLOBAL_FLAGS.set("fused_optimizer", True)
-    params = _make_params([((4, 4), "float32")] * 6, seed=2)
+    params = _make_params([(shape, "float32")] * 6, seed=2)
     opt = paddle.optimizer.Adam(learning_rate=0.01, parameters=params)
     opt.step()
     eng = opt._fused_engine
@@ -141,29 +167,111 @@ def test_mid_run_grad_drop_masks_without_rebuild(fused_flag):
     m3_after = np.asarray(opt._param_state(params[3])["moment1"])
     assert not np.array_equal(m3_before, m3_after)
     assert eng.buckets == buckets0  # masked, not rebuilt
+    b, = eng.buckets
+    assert b.masks and all(m.shape == (b.length,) for m in b.masks.values())
 
 
-def test_state_dict_roundtrip_across_paths(fused_flag):
+@pytest.mark.parametrize("specs", sorted(SPECS))
+def test_state_dict_roundtrip_across_paths(specs, fused_flag):
     """fused -> state_dict -> per-param continuation equals a pure
-    per-param run; the flat buffers and per-param views are one state."""
-    factory = CASES["adam_clip"]
+    per-param run; the flat buffers and per-param views are one state,
+    and the views keep the parameters' shapes whatever the bucket's
+    length."""
+    factory, specs = CASES["adam_clip"], SPECS[specs]
     # reference: 3 per-param steps
-    _, ref_vals, _, _ = _run(factory, False, steps=3)
+    _, ref_vals, _, _ = _run(factory, False, specs=specs, steps=3)
     # fused 2 steps, hand off through state_dict to a per-param optimizer
-    params, _, _, opt = _run(factory, True, steps=2)
+    params, _, _, opt = _run(factory, True, specs=specs, steps=2)
     sd = opt.state_dict()
+    for p in params:
+        for name in ("moment1", "moment2"):
+            assert tuple(sd[f"{p.name}.{name}"].shape) == tuple(p.shape)
     GLOBAL_FLAGS.set("fused_optimizer", False)
     opt2 = factory(params)
     opt2.set_state_dict(sd)
     opt2.step()
-    _assert_match(MIXED_SPECS,
+    _assert_match(specs,
                   [np.asarray(p.numpy(), np.float64) for p in params],
                   ref_vals)
 
 
-def test_trainstep_consumes_fused_buckets(fused_flag):
+@pytest.mark.parametrize("specs", sorted(SPECS))
+def test_rebuild_reseeds_the_padded_state(specs, fused_flag):
+    """A changed parameter set rebuilds the buckets: the new spans are
+    seeded from the per-param views of the old ones (offsets, never the
+    tail), at the new bucket's length, and the run goes on as the
+    per-param loop's does."""
+    factory, specs = CASES["adamw_plain"], SPECS[specs]
+
+    def run(fused):
+        GLOBAL_FLAGS.set("fused_optimizer", fused)
+        params = _make_params(specs)
+        held, params[1].grad = params[1].grad, None
+        opt = factory(params)
+        opt.step()
+        opt.step()
+        before = list(opt._fused_engine.buckets) if fused else None
+        params[1].grad = held  # joins the set: a rebuild, not a mask
+        opt.step()
+        if fused:
+            eng = opt._fused_engine
+            assert not set(map(id, eng.buckets)) & set(map(id, before))
+            assert id(params[1]) in {id(p) for b in eng.buckets
+                                     for p in b.params}
+            for b in eng.buckets:
+                for flat in b.state.values():
+                    assert not _flat_tail(b, flat).any()
+        return [np.asarray(p.numpy(), np.float64) for p in params]
+
+    _assert_match(specs, run(True), run(False))
+
+
+# every update rule the engine carries, with everything that touches the
+# flat grads (scale, clip, L1): the tail past the leaves rests at zero
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_bucket_tail_stays_zero(case, fused_flag, monkeypatch):
+    """Ten steps, one of them masked: state spans keep the bucket's length
+    and their tail is exactly zero; so is the tail of the new flat params
+    (read off the bucket's own jitted update)."""
+    params, _, _, opt = _run(CASES[case], True, specs=PAST_A_CHUNK, steps=6)
+    held, params[1].grad = params[1].grad, None
+    opt.step()  # the masked subset path
+    params[1].grad = held
+    for _ in range(3):
+        opt.step()
+    eng = opt._fused_engine
+    assert eng.active and len(eng.buckets) == 2
+    for b in eng.buckets:
+        assert b.length % BUCKET_ALIGN == 0 and b.length > b.total
+        for flat in b.state.values():
+            assert not _flat_tail(b, flat).any()
+        for vec in b.aux.values():
+            assert not _flat_tail(b, vec).any()
+    # the flat params exist only inside the update: run its body once
+    # more, un-jitted, and look at what the concatenation hands it
+    import jax
+    from paddle_tpu.optimizer import fused as F
+    seen = []
+    real = F._concat_flat
+
+    def spy(arrays, length):
+        seen.append(real(arrays, length))
+        return seen[-1]
+    monkeypatch.setattr(F, "_concat_flat", spy)
+    for b in eng.buckets:
+        b.fns.clear()
+    with jax.disable_jit():
+        opt.step()
+    assert len(seen) == 4  # flat p and flat g of two buckets
+    for b, flat_p in zip(eng.buckets, seen[::2]):
+        assert not _flat_tail(b, flat_p).any()
+
+
+@pytest.mark.parametrize("accumulate_steps", [1, 2])
+def test_trainstep_consumes_fused_buckets(accumulate_steps, fused_flag):
     """jit.TrainStep primes the engine: compiled losses match the
-    per-param compiled path and the flat state advances across steps."""
+    per-param compiled path (with K micro-batches accumulated too) and
+    the flat state advances across steps at the bucket's length."""
     x = paddle.to_tensor(np.random.default_rng(0)
                          .standard_normal((16, 8)).astype(np.float32))
 
@@ -173,7 +281,8 @@ def test_trainstep_consumes_fused_buckets(fused_flag):
         opt = paddle.optimizer.AdamW(
             learning_rate=1e-2, parameters=m.parameters(),
             grad_clip=paddle.nn.ClipGradByGlobalNorm(1.0))
-        step = paddle.jit.TrainStep(m, lambda x: (m(x) ** 2).mean(), opt)
+        step = paddle.jit.TrainStep(m, lambda x: (m(x) ** 2).mean(), opt,
+                                    accumulate_steps=accumulate_steps)
         return opt, step
 
     GLOBAL_FLAGS.set("fused_optimizer", True)
@@ -181,14 +290,20 @@ def test_trainstep_consumes_fused_buckets(fused_flag):
     fused_losses = [float(step_f(x).numpy()) for _ in range(5)]
     eng = opt_f._fused_engine
     assert eng is not None and eng.active
+    b, = eng.buckets
+    assert b.total == 36 and b.length > b.total
+    for flat in eng.state_arrays().values():
+        assert not _flat_tail(b, flat).any()
     GLOBAL_FLAGS.set("fused_optimizer", False)
     _, step_p = build()
     ref_losses = [float(step_p(x).numpy()) for _ in range(5)]
     np.testing.assert_allclose(fused_losses, ref_losses, atol=1e-5)
     assert fused_losses[-1] < fused_losses[0]
-    # flat state is real state: it round-trips through state_dict
+    # flat state is real state: it round-trips through state_dict, in
+    # the parameters' shapes
     sd = opt_f.state_dict()
-    assert any(".moment1" in k for k in sd)
+    m1 = {k: tuple(v.shape) for k, v in sd.items() if ".moment1" in k}
+    assert sorted(m1.values()) == [(4,), (8, 4)]
 
 
 def test_fused_adamw_pallas_kernel_parity():
@@ -217,17 +332,29 @@ def test_fused_adamw_pallas_kernel_parity():
                     atol=tol, rtol=tol)
 
 
-def test_engine_uses_pallas_kernel_when_forced(fused_flag, monkeypatch):
+@pytest.mark.parametrize("specs", [[((8, 16), "float32")] * 4, PAST_A_CHUNK],
+                         ids=["inside_a_chunk", "past_a_chunk"])
+def test_engine_uses_pallas_kernel_when_forced(specs, fused_flag,
+                                               monkeypatch):
     """PADDLE_TPU_FORCE_PALLAS=1 routes the AdamW bucket update through the
-    Pallas kernel (interpreter on CPU) with unchanged numerics."""
+    Pallas kernel (interpreter on CPU) with unchanged numerics; the bucket
+    arrives at the kernel's alignment, so its wrapper pads nothing."""
+    from paddle_tpu.kernels import fused_adamw as K
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    lengths = []
+    real = K._run
+
+    def spy(p, *args, **kw):
+        lengths.append(p.shape[0])
+        return real(p, *args, **kw)
+    monkeypatch.setattr(K, "_run", spy)
 
     def factory(ps):
         return paddle.optimizer.AdamW(learning_rate=0.01, parameters=ps,
                                       weight_decay=0.01)
 
-    specs = [((8, 16), "float32")] * 4
     _, forced_vals, _, _ = _run(factory, True, specs=specs, steps=2)
+    assert lengths and all(n % BUCKET_ALIGN == 0 for n in lengths)
     monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS")
     _, ref_vals, _, _ = _run(factory, False, specs=specs, steps=2)
     _assert_match(specs, forced_vals, ref_vals)

@@ -341,15 +341,21 @@ def _append_then_ragged(append, T, *, h, hkv, pages, pps, window=None,
                 _s((R,), i32), _s((R,), i32), _s((R,), i32))
 
 
-def _pool_sized_copies(text, pool_elems):
+def _sized_ops(text, op, elems):
+    """The compiled text's ``op`` instructions (inside fusions too) whose
+    result has at least ``elems`` elements."""
     import math
     import re
     found = []
     for line in text.splitlines():
-        m = re.search(r"=\s*\w+\[([\d,]+)\]\S*\s+copy\(", line)
-        if m and math.prod(map(int, m.group(1).split(","))) >= pool_elems:
+        m = re.search(r"=\s*\w+\[([\d,]+)\]\S*\s+" + op + r"\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) >= elems:
             found.append(line.strip()[:120])
     return found
+
+
+def _pool_sized_copies(text, pool_elems):
+    return _sized_ops(text, "copy", pool_elems)
 
 
 # mistral-7b.chat-steady's pools and step, and the window group of
@@ -408,6 +414,78 @@ def test_the_scatter_kv_append_replaced_turns_the_pool(one_chip, on_tpu):
     text = _compiled_text(fn, one_chip, *shapes, donate=(0, 1))
     copies = _pool_sized_copies(text, geo["hkv"] * geo["pages"] * PS * 128)
     assert len(copies) == 4, copies
+
+
+# ---- the fused optimizer's bucket update (optimizer/fused.py): leaves
+# whose total is no multiple of the kernel's chunk, as every real model's
+# (the two [2048] norms break the alignment)
+BUCKET_LEAVES = [(1024, 2048), (2048, 1024), (512, 2048), (2048,), (2048,)]
+
+
+def _engine_bucket():
+    """The real engine's one f32 AdamW bucket over BUCKET_LEAVES and its
+    jitted update, state donated as ``FusedOptimizerEngine._run`` and
+    ``TrainStep`` have it."""
+    import numpy as np
+    import paddle_tpu as paddle
+    params = []
+    for shape in BUCKET_LEAVES:
+        t = paddle.to_tensor(np.zeros(shape, np.float32))
+        t.stop_gradient = False
+        params.append(t)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=params,
+                                 weight_decay=0.01)
+    assert opt._prime_fused(params)
+    bucket, = opt._fused_engine.buckets
+    return bucket, opt._fused_engine._bucket_fn(
+        bucket, use_scale=False, donate=True, use_mask=False)
+
+
+def test_the_bucket_update_pads_and_slices_nothing(one_chip, on_tpu):
+    """The bucket is laid out once at the kernel's alignment: params and
+    grads are concatenated straight to that length, the moments rest at
+    it, so the step holds no bucket-sized pad or slice round the one
+    kernel call, and the donated moments are updated where they lie."""
+    from paddle_tpu.kernels.fused_adamw import BUCKET_ALIGN
+    bucket, fn = _engine_bucket()
+    assert bucket.total % BUCKET_ALIGN, "the leaves must not align"
+    assert bucket.length % BUCKET_ALIGN == 0
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    leaves = tuple(sds(p._data) for p in bucket.params)
+    state = {k: sds(v) for k, v in bucket.state.items()}
+    assert all(v.shape == (bucket.length,) for v in state.values())
+    f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = fn.lower(leaves, leaves, state, bucket.aux, f32, i32, f32, f32) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    half = bucket.total // 2
+    assert max(bucket.sizes) < half, "a leaf cut is no bucket pass"
+    for op in ("pad", "slice"):
+        found = _sized_ops(text, op, half)
+        assert not found, f"bucket-sized {op} in the update: {found}"
+    # flat arguments: the leaves, their grads, then moment1, moment2
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    n = len(leaves)
+    assert f"({2 * n}, {{}}" in alias and f"({2 * n + 1}, {{}}" in alias, (
+        f"the donated moments are not both aliased to results: "
+        f"{alias[:200]}")
+
+
+def test_an_unaligned_fused_adamw_pads_and_slices_the_bucket(one_chip,
+                                                             on_tpu):
+    """The guard above can see what it guards against: handed the same
+    leaves' total unpadded, as the engine handed it before the bucket
+    carried its own length, the kernel's wrapper pads p, g, m, v and
+    slices its three results, each a pass over the whole bucket."""
+    import math
+    n = sum(math.prod(shape) for shape in BUCKET_LEAVES)
+    fn, shapes, _ = _adamw(n, jnp.float32)
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert len(_sized_ops(text, "pad", n // 2)) == 4
+    assert len(_sized_ops(text, "slice", n // 2)) == 3
 
 
 # the serving launch's control buffer (spec_decode.StepOperands) at the
