@@ -386,12 +386,16 @@ def _logits(params, h, cfg):
 
 
 def _masked_logits(logits, temps, top_ks, top_ps):
-    """The per-row, branch-free sampling transform shared by every
-    sampler in the repo (Generator's ``_sample``, the serving engine's
-    ragged/burst steps, the speculative-decoding draft and verifier):
-    scale by temperature, then mask to the top-k largest logits, then to
-    the top-p nucleus — all as data-dependent ``where`` masks so rows
-    with different knobs ride ONE jitted launch.
+    """The per-row sampling transform shared by every sampler in the
+    repo (Generator's ``_sample``, the serving engine's ragged/burst
+    steps, the speculative-decoding draft and verifier): scale by
+    temperature, then mask to the top-k largest logits, then to the
+    top-p nucleus. Rows with different knobs ride ONE jitted launch,
+    which branches on the batch's knobs: the top-k sort runs only where
+    some row has ``top_k > 0``, the nucleus sort, softmax and cumsum only
+    where some row has ``top_p < 1``. A mask no row asks for is the
+    identity on every row, so each row's result is bit-identical
+    whichever branch the batch takes.
 
     logits [b, V]; temps [b] (> 0 — greedy rows are the caller's
     ``where``); top_ks [b] int32 (<= 0 disables; clamped to the vocab,
@@ -401,20 +405,53 @@ def _masked_logits(logits, temps, top_ks, top_ps):
     """
     V = logits.shape[-1]
     logits = logits.astype(jnp.float32) / temps[:, None]
-    # top-k: keep the k largest (the kth value itself stays, ties keep)
-    k_eff = jnp.clip(jnp.where(top_ks > 0, top_ks, V), 1, V)
-    kth = jnp.take_along_axis(jnp.sort(logits, -1)[:, ::-1],
-                              (k_eff - 1)[:, None], -1)
-    logits = jnp.where(logits < kth, -1e30, logits)
-    # top-p nucleus over the post-top-k logits (matches the legacy
-    # sequential masking order bit for bit when both knobs are set)
-    sorted_l = jnp.sort(logits, -1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_l, -1)
-    cum = jnp.cumsum(probs, -1)
-    cutoff_idx = jnp.sum(cum < top_ps[:, None], -1)      # [b]
-    cutoff = jnp.take_along_axis(sorted_l, cutoff_idx[:, None], -1)
-    apply_p = (top_ps < 1.0)[:, None]
-    return jnp.where(apply_p & (logits < cutoff), -1e30, logits)
+
+    def top_k(logits):
+        # keep the k largest (the kth value itself stays, ties keep)
+        k_eff = jnp.clip(jnp.where(top_ks > 0, top_ks, V), 1, V)
+        kth = jnp.take_along_axis(jnp.sort(logits, -1)[:, ::-1],
+                                  (k_eff - 1)[:, None], -1)
+        return jnp.where(logits < kth, -1e30, logits)
+
+    def top_p(logits):
+        # nucleus over the post-top-k logits (matches the legacy
+        # sequential masking order bit for bit when both knobs are set)
+        sorted_l = jnp.sort(logits, -1)[:, ::-1]
+        probs = jax.nn.softmax(sorted_l, -1)
+        cum = jnp.cumsum(probs, -1)
+        cutoff_idx = jnp.sum(cum < top_ps[:, None], -1)      # [b]
+        cutoff = jnp.take_along_axis(sorted_l, cutoff_idx[:, None], -1)
+        apply_p = (top_ps < 1.0)[:, None]
+        return jnp.where(apply_p & (logits < cutoff), -1e30, logits)
+
+    logits = jax.lax.cond(jnp.any(top_ks > 0), top_k, lambda x: x, logits)
+    return jax.lax.cond(jnp.any(top_ps < 1.0), top_p, lambda x: x, logits)
+
+
+def if_any_samples(temps, sampled, greedy, *operands):
+    """The gate of every per-row sampling epilogue: ``sampled(*operands)``
+    where some row aboard has ``temps > 0``, else ``greedy(*operands)``
+    (an argmax: no sort, softmax or random draw). A ``lax.cond`` inside
+    the one executable, on the rows' own knobs alone; pad rows carry
+    ``temps`` 0 and do not flip it. ``sampled`` must give a greedy row
+    what ``greedy`` gives it, so a row's result does not depend on what
+    it is batched with. Keep the call outside any ``vmap`` that batches
+    ``temps``: a batched predicate lowers to a select of both sides."""
+    return jax.lax.cond(jnp.any(temps > 0), sampled, greedy, *operands)
+
+
+def _greedy_probs(logits):
+    return jax.nn.one_hot(jnp.argmax(logits, -1), logits.shape[-1],
+                          dtype=jnp.float32)
+
+
+def _sampled_probs(logits, temps, top_ks, top_ps):
+    """:func:`sampling_probs` with no gate: for a batch known to hold a
+    sampling row (greedy rows beside it still read their one-hot)."""
+    safe_t = jnp.where(temps > 0, temps, 1.0)
+    probs = jax.nn.softmax(_masked_logits(logits, safe_t, top_ks, top_ps),
+                           -1)
+    return jnp.where((temps > 0)[:, None], probs, _greedy_probs(logits))
 
 
 def sampling_probs(logits, temps, top_ks, top_ps):
@@ -424,12 +461,9 @@ def sampling_probs(logits, temps, top_ks, top_ps):
     rule degenerate to argmax-equality on greedy rows, so spec-on greedy
     output is token-identical to spec-off (serving/spec_decode.py)."""
     logits = logits.astype(jnp.float32)
-    greedy = jax.nn.one_hot(jnp.argmax(logits, -1), logits.shape[-1],
-                            dtype=jnp.float32)
-    safe_t = jnp.where(temps > 0, temps, 1.0)
-    probs = jax.nn.softmax(_masked_logits(logits, safe_t, top_ks, top_ps),
-                           -1)
-    return jnp.where((temps > 0)[:, None], probs, greedy)
+    return if_any_samples(
+        temps, lambda lg: _sampled_probs(lg, temps, top_ks, top_ps),
+        _greedy_probs, logits)
 
 
 def request_keys(base_key, seeds, positions, tag):
@@ -451,15 +485,22 @@ def request_keys(base_key, seeds, positions, tag):
 
 
 def sample_rows(logits, keys, temps, top_ks, top_ps):
-    """Per-row sampling with per-row keys and knobs: greedy rows
+    """Per-row sampling with per-row keys and knobs, in one launch that
+    branches on the batch's knobs (:func:`if_any_samples`): greedy rows
     (temp <= 0) take argmax (the parity path), sampling rows draw
-    categorically from their own masked logits under their own key."""
-    greedy = jnp.argmax(logits, -1)
-    safe_t = jnp.where(temps > 0, temps, 1.0)
-    masked = _masked_logits(logits.astype(jnp.float32), safe_t, top_ks,
-                            top_ps)
-    sampled = jax.vmap(jax.random.categorical)(keys, masked)
-    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+    categorically from their own masked logits under their own key. A
+    row's token is bit-identical whatever it is batched with."""
+    def greedy(logits):
+        return jnp.argmax(logits, -1).astype(jnp.int32)
+
+    def sampled(logits):
+        safe_t = jnp.where(temps > 0, temps, 1.0)
+        masked = _masked_logits(logits.astype(jnp.float32), safe_t, top_ks,
+                                top_ps)
+        drawn = jax.vmap(jax.random.categorical)(keys, masked)
+        return jnp.where(temps > 0, drawn.astype(jnp.int32), greedy(logits))
+
+    return if_any_samples(temps, sampled, greedy, logits)
 
 
 def _sample(logits, key, temperature, top_k, top_p):
@@ -761,5 +802,5 @@ def generate(model, input_ids, max_len=512, **kwargs):
 
 
 __all__ = ["Generator", "generate", "extract_params",
-           "host_dispatch_count", "request_keys",
+           "host_dispatch_count", "if_any_samples", "request_keys",
            "resolve_megakernel_scope", "sample_rows", "sampling_probs"]

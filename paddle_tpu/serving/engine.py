@@ -260,6 +260,16 @@ def _segmented_quant_append(Pp, Ps, chunk, tbls, q_starts, q_lens, kv_lens,
     return jax.lax.fori_loop(0, bound, body, (Pp, Ps))
 
 
+def _sampler_counts(temps, top_ks, top_ps):
+    """What a launch's sampling epilogue must do, as counts on
+    ``serve.step`` from its per-row knobs (pad rows are greedy and
+    unmasked): it runs the sampler where a row samples, the top-k /
+    nucleus sorts where a row asks for the mask
+    (``generation.if_any_samples``, ``_masked_logits``)."""
+    return {"sampled_rows": int((temps > 0).sum()),
+            "masked_rows": int(((top_ks > 0) | (top_ps < 1.0)).sum())}
+
+
 class LLMEngine:
     """Continuous-batching serving engine over a paged KV pool."""
 
@@ -2363,7 +2373,8 @@ class LLMEngine:
         sp.set(rows=len(plan.rows), prefill_tokens=prefill_tokens,
                decode_tokens=int(q_lens.sum()) - prefill_tokens,
                # what attention must read: every row's context
-               live_kv_tokens=live_kv)
+               live_kv_tokens=live_kv,
+               **_sampler_counts(temps, top_ks, top_ps))
         if self._latent:
             # what the latent kernel's FLOPs stand on: over the layers,
             # the rows and each row's query tokens, the keys each sees
@@ -2567,6 +2578,7 @@ class LLMEngine:
         sp.set(rows=len(bplan.rows), prefill_tokens=0,
                decode_tokens=int(gen.sum()),
                live_kv_tokens=int(kv_lens.sum()),
+               **_sampler_counts(temps, top_ks, top_ps),
                # as the ragged walk would cover these rows, so that the
                # two counts stay a pair on every step
                attn_kv_tokens_read=ragged_kv_tokens_read(

@@ -48,8 +48,9 @@ import jax.numpy as jnp
 
 from ..core.flags import define_flag
 from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
-                                 _wmat, extract_params, request_keys,
-                                 sample_rows, sampling_probs)
+                                 _sampled_probs, _wmat, extract_params,
+                                 if_any_samples, request_keys, sample_rows,
+                                 sampling_probs)
 from ..kernels.paged_attention import (kv_append, ragged_latent_attention,
                                        ragged_paged_attention)
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
@@ -328,49 +329,76 @@ def speculative_sample(target_logits, draft_tokens, draft_probs, spec_lens,
     are the accepted draft candidates (``j = n_out - 1``) and
     ``out_tokens[r, j]`` is the residual resample (on rejection) or the
     bonus/plain sample — ``n_out`` tokens commit, in order.
+
+    The step does only what the rows aboard ask for
+    (``generation.if_any_samples``): where none samples, the tokens are
+    argmaxes and no distribution, sort or random draw is computed; where
+    one does, every row runs the sampler, a greedy row's one-hot giving
+    it the same argmax. A row's tokens do not depend on the branch.
     """
     R, K1, _V = target_logits.shape
     K = K1 - 1
-    # per-position target sampling distributions (greedy rows: one-hot)
-    p = jax.vmap(lambda lg: sampling_probs(lg, temps, top_ks, top_ps),
-                 in_axes=1, out_axes=1)(target_logits)     # [R, K+1, V]
     rows = jnp.arange(R)
-    if K > 0:
-        p_at = jnp.take_along_axis(p[:, :K], draft_tokens[..., None],
-                                   -1)[..., 0]             # [R, K]
-        q_at = jnp.take_along_axis(draft_probs, draft_tokens[..., None],
-                                   -1)[..., 0]
-        ratio = p_at / jnp.maximum(q_at, 1e-30)
-        # acceptance uniforms off the SAME stream derivation every
-        # sampler in the repo uses (request_keys) — one definition
-        u = jax.vmap(
-            lambda i: jax.vmap(jax.random.uniform)(
-                request_keys(base_key, seeds, sample_pos + i,
-                             ACCEPT_TAG)),
-            out_axes=1)(jnp.arange(K))                     # [R, K]
-        cand = jnp.arange(K)[None, :] < spec_lens[:, None]
-        accept = (u < ratio) & cand
+    cand = jnp.arange(K)[None, :] < spec_lens[:, None]
+
+    def leading(accept):
         # leading-accept run length: candidates commit strictly in order
-        n_acc = jnp.sum(jnp.cumprod(accept.astype(jnp.int32), -1), -1)
-    else:
-        n_acc = jnp.zeros((R,), jnp.int32)
-    rejected = n_acc < spec_lens
-    p_fin = p[rows, n_acc]                                 # [R, V]
-    if K > 0:
-        # first-rejection residual: max(p - q, 0) renormalized — the
-        # distribution that makes the committed token EXACTLY target-
-        # distributed. A zero residual (p == q) can only coincide with
-        # acceptance, so the p_fin fallback is never actually drawn.
-        q_fin = draft_probs[rows, jnp.minimum(n_acc, K - 1)]
-        res = jnp.maximum(p_fin - q_fin, 0.0)
-        rs = jnp.sum(res, -1, keepdims=True)
-        res = jnp.where(rs > 0, res / jnp.maximum(rs, 1e-30), p_fin)
-        dist = jnp.where(rejected[:, None], res, p_fin)
-    else:
-        dist = p_fin
-    fkeys = request_keys(base_key, seeds, sample_pos + n_acc, FINAL_TAG)
-    y = jax.vmap(jax.random.categorical)(fkeys, jnp.log(dist)) \
-        .astype(jnp.int32)
+        return jnp.sum(jnp.cumprod((accept & cand).astype(jnp.int32), -1),
+                       -1)
+
+    def greedy(target_logits):
+        # no row aboard samples: the rule above on one-hot distributions,
+        # taken directly. A candidate is accepted iff it is the target's
+        # argmax, and the residual / bonus / plain token is the argmax.
+        top = jnp.argmax(target_logits, -1).astype(jnp.int32)  # [R, K+1]
+        n_acc = leading(draft_tokens == top[:, :K])
+        return n_acc, top[rows, n_acc]
+
+    def sampled(target_logits):
+        # per-position target sampling distributions (greedy rows:
+        # one-hot); the knobs are closed over, so the masks' own gates
+        # (generation._masked_logits) stay conds under this vmap
+        p = jax.vmap(lambda lg: _sampled_probs(lg, temps, top_ks, top_ps),
+                     in_axes=1, out_axes=1)(
+                         target_logits.astype(jnp.float32))  # [R, K+1, V]
+        if K > 0:
+            p_at = jnp.take_along_axis(p[:, :K], draft_tokens[..., None],
+                                       -1)[..., 0]             # [R, K]
+            q_at = jnp.take_along_axis(draft_probs,
+                                       draft_tokens[..., None], -1)[..., 0]
+            ratio = p_at / jnp.maximum(q_at, 1e-30)
+            # acceptance uniforms off the SAME stream derivation every
+            # sampler in the repo uses (request_keys) — one definition
+            u = jax.vmap(
+                lambda i: jax.vmap(jax.random.uniform)(
+                    request_keys(base_key, seeds, sample_pos + i,
+                                 ACCEPT_TAG)),
+                out_axes=1)(jnp.arange(K))                     # [R, K]
+            n_acc = leading(u < ratio)
+        else:
+            n_acc = jnp.zeros((R,), jnp.int32)
+        p_fin = p[rows, n_acc]                                 # [R, V]
+        if K > 0:
+            # first-rejection residual: max(p - q, 0) renormalized — the
+            # distribution that makes the committed token EXACTLY target-
+            # distributed. A zero residual (p == q) can only coincide with
+            # acceptance, so the p_fin fallback is never actually drawn.
+            rejected = n_acc < spec_lens
+            q_fin = draft_probs[rows, jnp.minimum(n_acc, K - 1)]
+            res = jnp.maximum(p_fin - q_fin, 0.0)
+            rs = jnp.sum(res, -1, keepdims=True)
+            res = jnp.where(rs > 0, res / jnp.maximum(rs, 1e-30), p_fin)
+            dist = jnp.where(rejected[:, None], res, p_fin)
+        else:
+            dist = p_fin
+        fkeys = request_keys(base_key, seeds, sample_pos + n_acc, FINAL_TAG)
+        y = jax.vmap(jax.random.categorical)(fkeys, jnp.log(dist)) \
+            .astype(jnp.int32)
+        return n_acc, y
+
+    # the gate sits outside the vmap over the K + 1 positions: one
+    # predicate a step, on the rows' knobs alone
+    n_acc, y = if_any_samples(temps, sampled, greedy, target_logits)
     if K > 0:
         padded = jnp.pad(draft_tokens, ((0, 0), (0, 1)))
         out = jnp.where(jnp.arange(K + 1)[None, :] < n_acc[:, None],

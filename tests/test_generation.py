@@ -4,11 +4,17 @@ greedy decode = sliding-window full forward, sampling controls.
 Mirrors the reference's decode-kernel tests (masked_multihead_attention
 unit tests compare against a full-attention recompute).
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+import sampler_oracle as oracle
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config, Generator
+from paddle_tpu.models.generation import (_masked_logits, if_any_samples,
+                                          request_keys, sample_rows,
+                                          sampling_probs)
 
 
 def _model():
@@ -78,3 +84,76 @@ def test_eos_padding():
     row0_gen = out[0, 2:]
     after_eos = row0_gen[np.argmax(row0_gen == eos) + 1:]
     assert (after_eos == eos).all()  # finished row padded with eos
+
+
+# ---------------------------------------------------------------------------
+# the per-row samplers, gated on the batch's knobs (ISSUE 37), against the
+# ungated form they replaced: every row bit-equal, whatever it rides with
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("name", list(oracle.KNOBS))
+def test_gated_samplers_are_bit_equal_to_the_ungated(name, jit):
+    temps, ks, ps = oracle.knobs(name)
+    logits = oracle.logits_for(name, (6,), seed=3)
+    keys = request_keys(jax.random.key(5), jnp.arange(6, dtype=jnp.int32),
+                        jnp.arange(6, dtype=jnp.int32) + 2, 2)
+    wrap = jax.jit if jit else (lambda f: f)
+    safe_t = jnp.where(temps > 0, temps, 1.0)
+    for new, old, args in [
+            (_masked_logits, oracle.masked_logits, (logits, safe_t, ks, ps)),
+            (sampling_probs, oracle.sampling_probs, (logits, temps, ks, ps)),
+            (sample_rows, oracle.sample_rows, (logits, keys, temps, ks, ps))]:
+        got, want = np.asarray(wrap(new)(*args)), np.asarray(wrap(old)(*args))
+        assert got.dtype == want.dtype and np.array_equal(got, want), \
+            f"{new.__name__} differs from the ungated form under {name}"
+    # a greedy row reads its argmax beside whatever samples
+    toks = np.asarray(sample_rows(logits, keys, temps, ks, ps))
+    greedy = np.asarray(temps) <= 0
+    assert np.array_equal(toks[greedy],
+                          np.asarray(jnp.argmax(logits, -1))[greedy])
+
+
+def test_a_rows_sample_does_not_depend_on_its_batch():
+    """A sampling row alone, beside greedy rows (the long branch for
+    all) and beside rows with other masks: the same token and the same
+    distribution, bit for bit."""
+    temps, ks, ps = oracle.knobs("top_k_and_top_p")
+    logits = oracle.logits_for("top_k_and_top_p", (6,), seed=4)
+    keys = request_keys(jax.random.key(1), jnp.arange(6, dtype=jnp.int32),
+                        jnp.zeros((6,), jnp.int32), 2)
+    toks = np.asarray(sample_rows(logits, keys, temps, ks, ps))
+    probs = np.asarray(sampling_probs(logits, temps, ks, ps))
+    for i in range(6):
+        one = slice(i, i + 1)
+        assert toks[i] == int(sample_rows(logits[one], keys[one], temps[one],
+                                          ks[one], ps[one])[0])
+        assert np.array_equal(probs[i], np.asarray(sampling_probs(
+            logits[one], temps[one], ks[one], ps[one]))[0])
+
+
+@pytest.mark.parametrize("temps,want", [
+    ([0.0, 0.0, 0.0], "greedy"), ([0.0, 0.7, 0.0], "sampled"),
+    ([-1.0, 0.0, 0.0], "greedy")])
+def test_the_gate_reads_the_rows_temperatures_alone(temps, want):
+    got = if_any_samples(jnp.asarray(temps, jnp.float32),
+                         lambda: jnp.int32(1), lambda: jnp.int32(0))
+    assert ("sampled" if int(got) else "greedy") == want
+
+
+def test_the_gates_stay_conds_under_a_vmap_over_positions():
+    """The verifier maps the sampler over its K + 1 positions with the
+    knobs closed over: the predicates are then unbatched and the three
+    gates stay ``cond``s. With the knobs mapped too, they would become
+    selects that run both sides; the control shows this test sees that."""
+    temps, ks, ps = oracle.knobs("top_k_and_top_p")
+    logits = oracle.logits_for("top_k_and_top_p", (6, 3), seed=5)
+
+    def conds(fn, *args):
+        return str(jax.make_jaxpr(fn)(*args)).count(" cond[")
+
+    assert conds(jax.vmap(lambda lg: sampling_probs(lg, temps, ks, ps),
+                          in_axes=1), logits) == 3
+    assert conds(jax.vmap(lambda lg, t: sampling_probs(lg, t, ks, ps),
+                          in_axes=(1, 1)),
+                 logits, jnp.tile(temps[:, None], (1, 3))) < 3

@@ -277,6 +277,38 @@ def test_counts_agree_with_the_programs_counters(tiny_model):
     assert all(s.attrs["rows"] <= 3 for s in step_recs)
 
 
+@pytest.mark.parametrize("kw", [{}, {"burst_tokens": 4}],
+                         ids=["ragged", "burst"])
+def test_a_step_counts_the_rows_that_sample_and_mask(tiny_model, kw):
+    """``sampled_rows`` / ``masked_rows`` on ``serve.step`` are the live
+    rows with ``temperature`` > 0 and with a top-k or nucleus mask: a
+    step with one sampling row counts 1 and is no short step to
+    ``sampler_short_step_pct``; greedy steps are."""
+    from benchmark import run as harness
+    eng = _engine(tiny_model, max_num_seqs=4, **kw)
+    mark = _mark()
+    eng.add_request([5, 6, 7, 8], max_new_tokens=4, request_id="g")
+    eng.add_request([9, 8, 7], max_new_tokens=9, temperature=0.8,
+                    top_k=20, request_id="s")
+    eng.add_request([1, 2, 3], max_new_tokens=4, temperature=0.0,
+                    top_p=0.5, request_id="m")
+    eng.run(max_steps=100)
+    eng.add_request([4, 4, 4], max_new_tokens=3, request_id="g2")
+    eng.run(max_steps=100)
+    step_recs = [s for s in _since(mark, "serve.step") if "rows" in s.attrs]
+    got = [(s.attrs["sampled_rows"], s.attrs["masked_rows"])
+           for s in step_recs]
+    assert got[0] == (1, 2)                 # all three aboard
+    assert (1, 1) in got                    # "s" outlives the other two
+    assert got[-1] == (0, 0)                # "g2" alone
+    short = sum(n == 0 for n, _ in got)
+    assert 0 < short < len(got)
+    run = {"step_s": [0.0] * len(step_recs), "end_to_end": {},
+           "trace": None, "peaks": None}
+    assert harness.read_layer_metric("sampler_short_step_pct", run) \
+        == pytest.approx(100.0 * short / len(got))
+
+
 def test_a_requests_life_is_queue_then_prefill(tiny_model):
     """(c) one ``serve.queue`` and one ``serve.prefill`` a finished
     request, meeting at admission, and together the engine's own time to

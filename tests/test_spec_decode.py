@@ -23,11 +23,13 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as paddle
+import sampler_oracle as oracle
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config, Generator
-from paddle_tpu.models.generation import (_sample, request_keys,
-                                          sample_rows, sampling_probs)
+from paddle_tpu.models.generation import (_sample, if_any_samples,
+                                          request_keys, sample_rows,
+                                          sampling_probs)
 from paddle_tpu.serving import LLMEngine
-from paddle_tpu.serving.spec_decode import speculative_sample
+from paddle_tpu.serving.spec_decode import StepOperands, speculative_sample
 
 
 @pytest.fixture(scope="module")
@@ -372,6 +374,84 @@ def test_rejection_sampler_greedy_rows_degenerate_to_argmax():
     assert int(n[0]) == 3 and np.asarray(out)[0, :3].tolist() == [0, 1, 2]
     out, n = speculative_sample(tlog, dtok_bad, dprob_bad, *args)
     assert int(n[0]) == 2 and np.asarray(out)[0, :2].tolist() == [0, 1]
+
+
+# ---------------------------------------------------------------------------
+# the epilogue gated on the rows' knobs (ISSUE 37) against the ungated
+# form it replaced (tests/sampler_oracle.py): every row's tokens bit-equal
+# ---------------------------------------------------------------------------
+
+def _verify_operands(name, K, seed=0):
+    """Six rows' verify logits, candidates (half of them the target's
+    argmax, so that leading runs of every length occur), the draft's
+    distributions, and ``spec_lens`` from 0 to K."""
+    temps, ks, ps = oracle.knobs(name)
+    rng = np.random.default_rng(seed)
+    tlog = oracle.logits_for(name, (6, K + 1), seed=seed)
+    top = np.asarray(jnp.argmax(tlog, -1))[:, :K]
+    dtok = np.where(rng.random((6, K)) < 0.5, top,
+                    rng.integers(0, oracle.V, (6, K))).astype(np.int32)
+    dprobs = jax.nn.softmax(jnp.asarray(
+        rng.standard_normal((6, K, oracle.V)), jnp.float32), -1)
+    spec_lens = jnp.asarray(np.arange(6) % (K + 1), jnp.int32)
+    seeds = jnp.asarray(rng.integers(0, 2 ** 31 - 1, 6), jnp.int32)
+    pos = jnp.asarray(rng.integers(0, 100, 6), jnp.int32)
+    return (tlog, jnp.asarray(dtok), dprobs, spec_lens, temps, ks, ps,
+            jax.random.key(11), seeds, pos)
+
+
+@pytest.mark.parametrize("K", [0, 2])
+@pytest.mark.parametrize("name", list(oracle.KNOBS))
+def test_gated_verifier_commits_the_ungated_tokens(name, K):
+    """(a) all-greedy batches take the argmax rule, (b) mixed batches the
+    standing sampler: ``out`` and ``n_out`` equal the ungated form's for
+    every row, sampled rows included, plain and speculative."""
+    args = _verify_operands(name, K, seed=K + 1)
+    out, n_out = jax.jit(speculative_sample)(*args)
+    want, want_n = jax.jit(oracle.speculative_sample)(*args)
+    assert np.array_equal(np.asarray(n_out), np.asarray(want_n))
+    assert np.array_equal(np.asarray(out), np.asarray(want))
+    if K:       # the case means something: every run length occurred
+        assert len(set(np.asarray(n_out).tolist())) > 1
+
+
+def test_pad_rows_do_not_flip_the_gates():
+    """(c) a step's pad rows (``StepOperands``' blank buffer: ``temps``
+    0, ``top_ks`` 0, ``top_ps`` 1) ask for nothing: alone, and beside
+    live greedy rows, the step takes the argmax branch; one sampling row
+    among them takes the other."""
+    _, o = StepOperands(32, 8, 4, 0).host()
+    assert not (o["temps"] > 0).any() and not (o["top_ks"] > 0).any() \
+        and not (o["top_ps"] < 1.0).any()
+
+    def branch(temps):
+        return int(if_any_samples(jnp.asarray(temps), lambda: jnp.int32(1),
+                                  lambda: jnp.int32(0)))
+    assert branch(o["temps"]) == 0
+    o["q_lens"][:3] = 1                     # three live greedy rows
+    assert branch(o["temps"]) == 0
+    o["temps"][1] = 0.8
+    assert branch(o["temps"]) == 1
+
+
+def test_greedy_request_is_the_same_beside_a_sampling_one(tiny_model):
+    """(d) end to end: a greedy request's tokens alone (every step the
+    argmax branch) and co-scheduled with a sampling request (every
+    shared step the sampler) are the same tokens, Generator's."""
+    prompt = [3, 1, 4, 1, 5, 9]
+
+    def run(with_sampler):
+        eng = LLMEngine(tiny_model, max_len=64, page_size=4, max_num_seqs=4,
+                        seed=5)
+        eng.add_request(prompt, max_new_tokens=10, request_id="greedy")
+        if with_sampler:
+            eng.add_request([2, 7, 1, 8], max_new_tokens=12, temperature=0.8,
+                            top_k=50, top_p=0.9, seed=99,
+                            request_id="sampler")
+        return eng.run(max_steps=200)["greedy"].token_ids
+
+    alone = run(False)
+    assert alone == run(True) == _reference_tokens(tiny_model, prompt, 10)
 
 
 # ---------------------------------------------------------------------------

@@ -540,3 +540,94 @@ def test_step_prologue_unpacks_the_one_buffer_in_place(name, one_chip):
             r"\(\w+\[([\d,]+)\]\S* [^\n]*copy-start\(", text)
         if math.prod(map(int, m.group(1).split(","))) > lay.size]
     assert not big, f"{name}: copies larger than the buffer: {big}"
+
+
+# the serving step's epilogue at mistral-7b.chat-steady's shapes: the
+# non-finite guard, then the sampler over 32 rows of a 32,768 vocabulary
+EPILOGUE_R, EPILOGUE_V = 32, 32768
+
+
+def _epilogue_text(sampler, one_chip, K=0):
+    """``engine.py::ragged_step``'s last lines round ``sampler``
+    (``speculative_sample``'s signature), compiled for the chip."""
+    R, V = EPILOGUE_R, EPILOGUE_V
+    i32, f32 = jnp.int32, jnp.float32
+
+    def epilogue(logits, *rest):
+        finite = jnp.all(jnp.isfinite(logits.reshape(R, -1)), axis=-1)
+        return sampler(logits, *rest), finite
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return _compiled_text(
+        epilogue, one_chip, _s((R, K + 1, V)), _s((R, K), i32),
+        _s((R, K, V), f32), _s((R,), i32), _s((R,), f32), _s((R,), i32),
+        _s((R,), f32), key, _s((R,), i32), _s((R,), i32))
+
+
+def _straight_line(text):
+    """The lines of the compiled text that run on every call: the entry
+    computation's, and those of every computation it reaches (fusions,
+    calls, loop bodies, comparators) except through a ``conditional``'s
+    branches."""
+    import re
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    seen, todo, lines = set(), ["ENTRY"], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            lines.append(line)
+            unconditional = re.sub(
+                r"(branch_computations=\{[^}]*\}"
+                r"|(true|false)_computation=%?[\w.\-]+)", "", line)
+            todo += re.findall(r"=\{?%([\w.\-]+)", unconditional)
+    return lines
+
+
+def _vocabulary_sorts(lines):
+    import math
+    import re
+    found = []
+    for line in lines:
+        m = re.search(r"=\s*\(?\w+\[([\d,]+)\][^=]*\ssort\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) \
+                >= EPILOGUE_R * EPILOGUE_V:
+            found.append(line.strip()[:120])
+    return found
+
+
+def test_the_sampling_epilogue_sorts_only_inside_a_branch(one_chip):
+    """The step's sampler is gated on the rows' knobs by ``cond``s the
+    chip's compiler keeps: the compiled text holds ``conditional``s, the
+    entry's own among them, and both whole-vocabulary sorts sit inside
+    branch computations, none on the path every step runs. Had a
+    predicate been batched into a select, its sort would be on it."""
+    from paddle_tpu.serving.spec_decode import speculative_sample
+    text = _epilogue_text(speculative_sample, one_chip)
+    straight = _straight_line(text)
+    assert any(" conditional(" in line for line in straight)
+    assert len(_vocabulary_sorts(text.splitlines())) == 2
+    found = _vocabulary_sorts(straight)
+    assert not found, f"a vocabulary sort every step runs: {found}"
+
+
+def test_the_ungated_epilogue_sorts_on_every_step(one_chip):
+    """The guard above can see what it guards against: the sampler as it
+    stood before the gate (``tests/sampler_oracle.py``) compiles to two
+    whole-vocabulary sorts in the entry's straight line and no
+    ``conditional``."""
+    import sampler_oracle
+    text = _epilogue_text(sampler_oracle.speculative_sample, one_chip)
+    assert " conditional(" not in text
+    assert len(_vocabulary_sorts(_straight_line(text))) == 2
