@@ -1,0 +1,19 @@
+"""Share of the device's busy time in the traced window charged to
+attention, forward, backward and recomputed: the projections with
+rotary, the XLA composition (or flash kernel) and ``o`` with the
+residual add (``benchmark/device_phases.py``: the step executable's
+instruction -> phase table joined with the trace's per-instruction
+seconds)."""
+from benchmark import device_phases
+
+LAYER = "train step"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "train_tok_s"
+EXECUTABLE = "train.step"
+PHASES = ("attn.qkv", "attn.core", "attn.out")
+PASSES = None
+
+
+def read(run):
+    return device_phases.read(run, EXECUTABLE, PHASES, PASSES)

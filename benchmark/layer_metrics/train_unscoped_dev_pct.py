@@ -1,0 +1,19 @@
+"""Share of the device's busy time in the traced window charged to
+instructions no phase owns (the tape's gradient fan-in adds, the
+partitioner's collectives where it gave them no metadata): the
+instrument's own coverage (``benchmark/device_phases.py``: the step
+executable's instruction -> phase table joined with the trace's per-
+instruction seconds)."""
+from benchmark import device_phases
+
+LAYER = "train step"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "train_tok_s"
+EXECUTABLE = "train.step"
+PHASES = (device_phases.UNSCOPED,)
+PASSES = None
+
+
+def read(run):
+    return device_phases.read(run, EXECUTABLE, PHASES, PASSES)

@@ -23,11 +23,18 @@ DEFAULT_DIR = os.path.join(
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compilation cache on and return its directory."""
+    """Turn the persistent compilation cache on and return its directory.
+
+    The scopes a program was traced under are part of its key here (JAX
+    leaves metadata out by default): an executable cached by a tree with
+    other ``jax.named_scope``s, in a directory both share, would load
+    with that tree's instruction metadata, and ``profiler/phases.py``
+    would read its phases, or none, off this tree's step."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed
-    import jax
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR
 
@@ -35,6 +42,7 @@ def enable_compile_cache() -> str:
 def disable_compile_cache() -> None:
     """Undo :func:`enable_compile_cache`. A cache placed from outside is
     the operator's and stays."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
     if not os.environ.get(ENV_VAR):
-        import jax
         jax.config.update("jax_compilation_cache_dir", None)

@@ -17,7 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import autograd
+from . import autograd, phase_scope
 from .flags import GLOBAL_FLAGS
 from .tensor import Tensor
 
@@ -35,6 +35,13 @@ def _maybe_amp_cast(name, vals):
     if not _amp_state.enabled:
         return vals
     return amp_cast_inputs(name, vals)
+
+
+def _under_phase(name, vjp_fn):
+    def scoped_vjp(cts):
+        with phase_scope.reenter(name):
+            return vjp_fn(cts)
+    return scoped_vjp
 
 
 # Set by paddle_tpu.profiler while a Profiler is active: (begin_fn, end_fn)
@@ -126,9 +133,12 @@ def _eager_apply_inner(name: str, pure_fn, args: tuple, kwargs: dict):
         from ..amp.auto_cast import _state as _amp_s
         amp_snap = (_amp_s.enabled, _amp_s.dtype, _amp_s.level,
                     _amp_s.white, _amp_s.black)
+        # and the open phase (phase_scope.py): the re-run forward and
+        # its backward are charged where the first forward was
+        phase_snap = phase_scope.open_phase()
 
         def vjp_fn(cts, _g=g, _packed=packed, _unpack=unpack,
-                   _amp=amp_snap):
+                   _amp=amp_snap, _phase=phase_snap):
             arrays = []
             for p in _packed:
                 u = _unpack(p)
@@ -138,13 +148,21 @@ def _eager_apply_inner(name: str, pure_fn, args: tuple, kwargs: dict):
             saved = (_s.enabled, _s.dtype, _s.level, _s.white, _s.black)
             (_s.enabled, _s.dtype, _s.level, _s.white, _s.black) = _amp
             try:
-                _, inner = jax.vjp(_g, *arrays)
+                with phase_scope.reenter(_phase, remat=True):
+                    _, inner = jax.vjp(_g, *arrays)
             finally:
                 (_s.enabled, _s.dtype, _s.level, _s.white,
                  _s.black) = saved
-            return inner(cts)
+            with phase_scope.reenter(_phase):
+                return inner(cts)
     else:
         out, vjp_fn = jax.vjp(g, *diff_arrays)
+        # vjp_fn runs later, outside any ``with`` of the forward: JAX
+        # keeps on the linearised equations the scopes opened INSIDE
+        # ``g`` only, so the phase open round this op is carried here
+        open_phase = phase_scope.open_phase()
+        if open_phase is not None:
+            vjp_fn = _under_phase(open_phase, vjp_fn)
 
     edges = []
     for t in diff_tensors:

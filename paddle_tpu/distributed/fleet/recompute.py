@@ -16,6 +16,7 @@ import jax
 from ...amp.auto_cast import amp_state as _amp_state
 from ...autograd.py_layer import PyLayer, PyLayerContext
 from ...core import autograd as _ag
+from ...core import phase_scope
 from ...core import random as _rng
 from ...core.autograd import enable_grad, no_grad
 from ...core.tensor import Tensor
@@ -39,6 +40,9 @@ class RecomputeFunction(PyLayer):
         # recompute.py:128 RecomputeFunction.forward -> amp_state()).
         st = _amp_state()
         ctx.amp = (st.enabled, st.dtype, st.level, st.white, st.black)
+        # and the open phase (core/phase_scope.py), for the same reason:
+        # the replay is charged where the forward was, marked recomputed
+        ctx.phase = phase_scope.open_phase()
         ctx.inputs = args
         ctx.tensor_indices = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
         with no_grad():
@@ -62,7 +66,7 @@ class RecomputeFunction(PyLayer):
         saved_amp = (st.enabled, st.dtype, st.level, st.white, st.black)
         (st.enabled, st.dtype, st.level, st.white, st.black) = ctx.amp
         try:
-            with enable_grad():
+            with enable_grad(), phase_scope.reenter(ctx.phase, remat=True):
                 out = ctx.fn(*detached)
         finally:
             (st.enabled, st.dtype, st.level, st.white, st.black) = saved_amp

@@ -15,7 +15,7 @@ the three phases:
   their buffers back to XLA (the batch is consumed exactly once).
 
 ``PIPELINE_METRICS`` mirrors serving/metrics.py: a ``snapshot()`` dict
-(``input_stall_ms``, ``h2d_bytes_per_s``, ``steps_in_flight``; read by
+(``input_stall_ms``, ``steps_in_flight``, ``step_dispatches``; read by
 tests/test_async_pipeline.py) plus instant events on the native profiler timeline when one is recording.
 """
 from __future__ import annotations
@@ -40,22 +40,18 @@ class PipelineMetrics:
     spans and serving gauges.
     """
 
-    def __init__(self, now_fn=time.monotonic):
-        self._now = now_fn
+    def __init__(self):
         self.reset()
 
     def reset(self):
-        self._t0 = self._now()
         self.batches_staged = 0
-        self.h2d_bytes = 0
         self.input_stall_ms = 0.0
         self.steps_in_flight = 0
         self.max_steps_in_flight = 0
         self.step_dispatches = 0
 
-    def record_staged(self, nbytes):
+    def record_staged(self):
         self.batches_staged += 1
-        self.h2d_bytes += int(nbytes)
 
     def record_stall(self, ms):
         self.input_stall_ms += float(ms)
@@ -72,17 +68,12 @@ class PipelineMetrics:
         self.step_dispatches += 1
 
     def snapshot(self) -> dict:
-        from ..core.async_scalar import host_sync_count
-        dt = max(self._now() - self._t0, 1e-9)
         return {
             "batches_staged": self.batches_staged,
-            "h2d_bytes": self.h2d_bytes,
-            "h2d_bytes_per_s": self.h2d_bytes / dt,
             "input_stall_ms": self.input_stall_ms,
             "steps_in_flight": self.steps_in_flight,
             "max_steps_in_flight": self.max_steps_in_flight,
             "step_dispatches": self.step_dispatches,
-            "host_syncs": host_sync_count(),
         }
 
 
@@ -149,21 +140,17 @@ class DevicePrefetchIterator:
 
     # ---- staging ----
     def _stage(self, batch):
-        nbytes = 0
-
         def put(x):
-            nonlocal nbytes
             if not isinstance(x, Tensor):
                 return x
             t = Tensor(jax.device_put(x._data, self._device))
-            nbytes += t._data.nbytes
             if self._mark:
                 t._staged_h2d = True
             return t
 
         out = jax.tree.map(put, batch,
                            is_leaf=lambda x: isinstance(x, Tensor))
-        self._metrics.record_staged(nbytes)
+        self._metrics.record_staged()
         return out
 
     # ---- consumption ----

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from ..core import autograd as _ag
 from ..core import random as _rng
 from ..core.tensor import Tensor
+from ..profiler import phases
 from ..profiler.spans import span
 
 
@@ -764,27 +765,29 @@ class TrainStep:
                 self._mesh, shard_cfg.pipe, pipe_M)
                 if shard_cfg is not None and shard_cfg.pipe > 1
                 else nullcontext())
-            if shard_cfg is not None or self.capture_hlo:
-                # HLO forensics: keep the compiled module + its
-                # collective mix inspectable (tests/test_gspmd.py,
-                # tests/test_pipeline_parallel.py; jit/hlo_forensics.py
-                # fusion stats over it in tests/test_hlo_forensics.py).
-                # One extra lower+compile, paid only
-                # on the first call of a sharded (or capture_hlo)
-                # specialization.
-                try:
-                    with policy_ctx, shard_ctx, pipe_ctx:
-                        hlo = self._cache[key].lower(*args).compile() \
-                            .as_text()
-                    self.last_hlo_text = hlo
-                    self.last_hlo_collectives = \
-                        _gspmd.collective_counts(hlo)
-                except Exception:
-                    self.last_hlo_text = None
-                    self.last_hlo_collectives = None
+            # the launch's shapes, read before the call donates them
+            specs = phases.launch_specs(args)
             with policy_ctx, shard_ctx, pipe_ctx, \
                     compile_event("train.compile") as ev:
                 out = self._cache[key](*args)
+            # ONE handle on the compiled step, taken after its first
+            # call: lowering over the launch's own shapes hits JAX's
+            # in-memory caches (no second trace, no second compile).
+            # profiler/phases.py keeps it for device time by phase; a
+            # sharded (or capture_hlo) specialization also reads its
+            # text for HLO forensics (tests/test_gspmd.py,
+            # tests/test_pipeline_parallel.py; jit/hlo_forensics.py
+            # fusion stats over it in tests/test_hlo_forensics.py).
+            with policy_ctx, shard_ctx, pipe_ctx, \
+                    span("train.register"):         # what it costs, once
+                compiled = self._cache[key].lower(*specs).compile()
+            phases.register(
+                "train.step" if not self._compiled_keys
+                else f"train.step:{len(self._compiled_keys)}", compiled)
+            if shard_cfg is not None or self.capture_hlo:
+                self.last_hlo_text = compiled.as_text()
+                self.last_hlo_collectives = \
+                    _gspmd.collective_counts(self.last_hlo_text)
             self._compiled_keys.add(key)
             self.last_compile_ms = ev.ms
             self.compile_ms_total += ev.ms

@@ -44,9 +44,25 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(
     r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([0-9,]*)\]")
 
-#: one instruction definition: `%name = <shape-or-tuple> opname(`
+#: one instruction definition: `%name = <shape-or-tuple> opname(`. The
+#: opcode is the first ` word(` after the `=`: a tuple shape's members
+#: are followed by `[`, and a TPU layout's tile (`{1,0:T(8,128)}`) by no
+#: space, so neither is taken for it
 _DEF_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%[\w.\-]+ = (?:\([^)]*\)|\S+) ([\w\-]+)\(")
+    r"^\s*(?:ROOT\s+)?(?P<sigil>%?)(?P<name>[\w.\-]+) = .*? "
+    r"(?P<op>[a-z][\w\-]*)\(")
+
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+
+#: `, metadata={op_name="..." source_file="..." ...}` of a def line
+_METADATA_RE = re.compile(r",? ?\bmetadata=\{[^}]*\}")
+
+#: the names a def line refers to: operands, and the computations it
+#: calls. A module printed with the `%` sigil marks them; one printed
+#: without it gives bare words, of which an attribute's key (`slice=`)
+#: is none and the rest are told from shapes and values by being the
+#: name of an instruction (:func:`instruction_metadata`)
+_REF_RE = re.compile(r"(?<![\w.\-%])(%?)([\w.\-]+)(?![\w.\-=])")
 
 _FUSION_KIND_RE = re.compile(r"kind=(k\w+)")
 
@@ -91,7 +107,7 @@ def fusion_stats(hlo_text: str) -> dict:
     fusion_kinds: dict[str, int] = {}
     for line in hlo_text.splitlines():
         m = _DEF_RE.match(line)
-        if m is None or m.group(1) != "fusion":
+        if m is None or m.group("op") != "fusion":
             continue
         fusion_bytes.append(shape_bytes(line.split(", calls=")[0]))
         km = _FUSION_KIND_RE.search(line)
@@ -104,7 +120,7 @@ def fusion_stats(hlo_text: str) -> dict:
         if m is None:
             continue
         instructions += 1
-        if m.group(1) not in _FREE_OPS:
+        if m.group("op") not in _FREE_OPS:
             kernels += 1
     return {
         "fusion_count": len(fusion_bytes),
@@ -114,6 +130,52 @@ def fusion_stats(hlo_text: str) -> dict:
         "fusion_bytes_max": max(fusion_bytes, default=0),
         "fusion_kinds": dict(sorted(fusion_kinds.items())),
     }
+
+
+def instruction_metadata(hlo_text: str):
+    """``(name, opcode, op_name, refs)`` of every instruction definition
+    of the module, whatever computation holds it: the entry's, a fusion's, a
+    scanned loop's body, a ``conditional``'s branches (instruction names
+    are unique in a module). ``op_name`` is what the instruction's
+    ``metadata={op_name="jit(step)/.../<scope>/<primitive>"}`` carries:
+    the ``jax.named_scope`` stack it was traced under, None where the
+    compiler wrote none. A fusion's is its root's. ``refs``: the names
+    the definition refers to, in order: its operands and, in a module
+    printed with the ``%`` sigil, the computations it calls."""
+    defs = [(m, line) for m, line in
+            ((_DEF_RE.match(line), line) for line in hlo_text.splitlines())
+            if m is not None]
+    names = {m.group("name") for m, _ in defs}
+    sigils = any(m.group("sigil") for m, _ in defs)
+    for m, line in defs:
+        meta = _OP_NAME_RE.search(line, m.end())
+        refs = tuple(
+            ref for sigil, ref in
+            _REF_RE.findall(_METADATA_RE.sub("", line[m.end():]))
+            if (sigil if sigils else ref in names))
+        yield m.group("name"), m.group("op"), meta and meta.group(1), refs
+
+
+#: the module's index of source locations, which ``stack_frame_id`` in
+#: an instruction's metadata points into: sections of the text's head
+_FRAME_SECTIONS = ("FileNames", "FunctionNames", "FileLocations",
+                   "StackFrames")
+
+
+def strip_metadata(hlo_text: str) -> str:
+    """The module's text less every ``metadata={...}`` and less the
+    index of source locations they point into: two programs that differ
+    in scopes and source lines alone strip to the same bytes."""
+    out, skipping = [], False
+    for line in _METADATA_RE.sub("", hlo_text).splitlines(keepends=True):
+        if line.strip() in _FRAME_SECTIONS:
+            skipping = True
+        elif skipping and not line.strip():
+            skipping = False
+            continue
+        if not skipping:
+            out.append(line)
+    return "".join(out)
 
 
 #: the launch-accounting marker: rsqrt appears in this stack's decode
@@ -225,5 +287,5 @@ def mixed_launch_stats(program_text: str, *, num_layers,
     }
 
 
-__all__ = ["fusion_stats", "launch_stats", "mixed_launch_stats",
-           "shape_bytes"]
+__all__ = ["fusion_stats", "instruction_metadata", "launch_stats",
+           "mixed_launch_stats", "shape_bytes", "strip_metadata"]

@@ -20,6 +20,7 @@ from dataclasses import dataclass
 from .. import nn
 from ..nn import functional as F
 from .. import tensor as T
+from ..profiler import phases
 
 
 @dataclass
@@ -174,24 +175,30 @@ class LlamaAttention(nn.Layer):
                 rope_cs=None):
         b, s, _ = hidden_states.shape
         hd = self.config.head_dim
-        q = self.q_proj(hidden_states).reshape([b, s, self.num_heads, hd])
-        k = self.k_proj(hidden_states).reshape([b, s, self.num_kv_heads, hd])
-        v = self.v_proj(hidden_states).reshape([b, s, self.num_kv_heads, hd])
-        q, k = apply_rotary_pos_emb(q, k, position_ids, self.config.rope_theta,
-                                    rope_cs)
+        with phases.phase("attn.qkv"):
+            q = self.q_proj(hidden_states).reshape(
+                [b, s, self.num_heads, hd])
+            k = self.k_proj(hidden_states).reshape(
+                [b, s, self.num_kv_heads, hd])
+            v = self.v_proj(hidden_states).reshape(
+                [b, s, self.num_kv_heads, hd])
+            q, k = apply_rotary_pos_emb(q, k, position_ids,
+                                        self.config.rope_theta, rope_cs)
         # GQA k/v go to attention with their native head count — both the
         # composed SDPA body and the Pallas flash kernel pair query head j
         # with kv head j // group internally, so the repeated [b, s, hq, d]
         # k/v copies never hit HBM.
         # Causal LM: the causal mask always applies; attn_mask (e.g. padding)
         # is merged on top, never a replacement for it.
-        if self.config.use_flash_attention and attn_mask is None:
-            out, _ = F.flash_attention(q, k, v, causal=True)
-        else:
-            out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
-                                                 is_causal=True)
-        out = out.reshape([b, s, self.num_heads * hd])
-        return self.o_proj(out)
+        with phases.phase("attn.core"):
+            if self.config.use_flash_attention and attn_mask is None:
+                out, _ = F.flash_attention(q, k, v, causal=True)
+            else:
+                out = F.scaled_dot_product_attention(
+                    q, k, v, attn_mask=attn_mask, is_causal=True)
+        with phases.phase("attn.out"):
+            out = out.reshape([b, s, self.num_heads * hd])
+            return self.o_proj(out)
 
 
 class LlamaMLP(nn.Layer):
@@ -204,6 +211,7 @@ class LlamaMLP(nn.Layer):
         self.up_proj = nn.Linear(h, i, bias_attr=False)
         self.down_proj = nn.Linear(i, h, bias_attr=False)
 
+    @phases.scoped("mlp")
     def forward(self, x):
         return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
 
@@ -218,9 +226,15 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, hidden_states, position_ids=None, attn_mask=None,
                 rope_cs=None):
-        h = hidden_states + self.self_attn(
-            self.input_layernorm(hidden_states), position_ids, attn_mask, rope_cs)
-        return h + self.mlp(self.post_attention_layernorm(h))
+        with phases.phase("norm"):
+            x = self.input_layernorm(hidden_states)
+        x = self.self_attn(x, position_ids, attn_mask, rope_cs)
+        with phases.phase("attn.out"):      # the residual add
+            h = hidden_states + x
+        with phases.phase("norm"):
+            x = self.post_attention_layernorm(h)
+        with phases.phase("mlp"):
+            return h + self.mlp(x)
 
 
 class LlamaModel(nn.Layer):
@@ -242,10 +256,13 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, attn_mask=None):
         from ..nn.scan_stack import LayerStack, effective_remat_policy
-        h = self.embed_tokens(input_ids)
+        with phases.phase("embed"):
+            h = self.embed_tokens(input_ids)
         # Build the RoPE cos/sin tables once and share across all layers.
         pos = position_ids if position_ids is not None else input_ids.shape[1]
-        rope_cs = F.rope_tables(pos, self.config.head_dim, self.config.rope_theta)
+        with phases.phase("attn.qkv"):
+            rope_cs = F.rope_tables(pos, self.config.head_dim,
+                                    self.config.rope_theta)
         policy = effective_remat_policy(self.config.remat)
         if isinstance(self.layers, LayerStack):
             h = self.layers(h, position_ids, attn_mask, rope_cs,
@@ -261,7 +278,8 @@ class LlamaModel(nn.Layer):
         else:
             for layer in self.layers:
                 h = layer(h, position_ids, attn_mask, rope_cs)
-        return self.norm(h)
+        with phases.phase("head"):
+            return self.norm(h)
 
 
 class LlamaForCausalLM(nn.Layer):
@@ -290,22 +308,26 @@ class LlamaForCausalLM(nn.Layer):
             # the residual stream and the loss collapses to ~0.
             w = (self.model.embed_tokens.weight if self.lm_head is None
                  else self.lm_head.weight)
-            loss = F.fused_linear_cross_entropy(
-                h[:, :-1].reshape([-1, self.config.hidden_size]), w,
-                labels[:, 1:].reshape([-1]),
-                chunk_size=self.config.loss_chunk_size,
-                transpose_weight=self.lm_head is None)
+            with phases.phase("loss"):
+                loss = F.fused_linear_cross_entropy(
+                    h[:, :-1].reshape([-1, self.config.hidden_size]), w,
+                    labels[:, 1:].reshape([-1]),
+                    chunk_size=self.config.loss_chunk_size,
+                    transpose_weight=self.lm_head is None)
             return None, loss
-        if self.lm_head is None:
-            logits = T.matmul(h, self.model.embed_tokens.weight, transpose_y=True)
-        else:
-            logits = self.lm_head(h)
+        with phases.phase("head"):
+            if self.lm_head is None:
+                logits = T.matmul(h, self.model.embed_tokens.weight,
+                                  transpose_y=True)
+            else:
+                logits = self.lm_head(h)
         if labels is None:
             return logits
         # same causal shift as the chunked path
-        loss = F.cross_entropy(
-            logits[:, :-1].reshape([-1, self.config.vocab_size]),
-            labels[:, 1:].reshape([-1]), reduction="mean")
+        with phases.phase("loss"):
+            loss = F.cross_entropy(
+                logits[:, :-1].reshape([-1, self.config.vocab_size]),
+                labels[:, 1:].reshape([-1]), reduction="mean")
         return logits, loss
 
     def flops_per_token(self, seq_len, remat_policy=None):

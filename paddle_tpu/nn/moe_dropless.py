@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.grouped_matmul import grouped_matmul
+from ..profiler import phases
 
 F32 = jnp.float32
 
@@ -44,6 +45,7 @@ def limit_to_groups(choice, n_group, topk_group):
     return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(t, e)
 
 
+@phases.scoped("moe.route")
 def route_sigmoid(x, router, bias, *, top_k, scaling, norm_topk_prob=True,
                   n_group=1, topk_group=1):
     """Experts and gates of every token. ``x [T, h]``; ``router [h,
@@ -74,6 +76,7 @@ def buffer_rows(tokens, top_k, held, tm):
     return -(-worst // tm) * tm + held * tm
 
 
+@phases.scoped("moe.dispatch")
 def dispatch_plan(idx, live, *, first, held, tm):
     """Where each token-expert pair goes. ``idx [T, k]`` global expert
     ids; ``live [T]`` bool (a dead slot of a packed step routes
@@ -118,22 +121,25 @@ def dropless_experts(x, idx, gates, live, gate_w, up_w, down_w, *, first,
     experts with at least one token, most tokens any one expert got)."""
     held = gate_w.shape[0]
     plan = dispatch_plan(idx, live, first=first, held=held, tm=tm)
-    xs = x[plan["src"]]                                       # [M, h]
+    with phases.phase("moe.dispatch"):
+        xs = x[plan["src"]]                                   # [M, h]
 
     def mm(a, w):
         return grouped_matmul(a, w, plan["tile_group"], plan["live_tiles"],
                               tm=tm, interpret=interpret)
-    act = (jax.nn.silu(mm(xs, gate_w).astype(F32))
-           * mm(xs, up_w).astype(F32)).astype(x.dtype)
-    out = mm(act, down_w)                                     # [M, h]
+    with phases.phase("moe.experts"):
+        act = (jax.nn.silu(mm(xs, gate_w).astype(F32))
+               * mm(xs, up_w).astype(F32)).astype(x.dtype)
+        out = mm(act, down_w)                                 # [M, h]
     # every row of ``out`` is finite (dead tiles are zeros, a live tile's
     # padding rows read a real token), so a gate of 0 masks a pair away
     # (an elementwise product: a dot would round the f32 gates on the chip)
-    y = jnp.sum(out[plan["pair_row"]].astype(F32)
-                * jnp.where(plan["here"], gates, 0.0)[..., None], 1)
-    c = plan["counts"]
-    stats = jnp.stack([jnp.sum(c), jnp.sum(c > 0), jnp.max(c)])
-    return y.astype(x.dtype), stats.astype(jnp.int32)
+    with phases.phase("moe.combine"):
+        y = jnp.sum(out[plan["pair_row"]].astype(F32)
+                    * jnp.where(plan["here"], gates, 0.0)[..., None], 1)
+        c = plan["counts"]
+        stats = jnp.stack([jnp.sum(c), jnp.sum(c > 0), jnp.max(c)])
+        return y.astype(x.dtype), stats.astype(jnp.int32)
 
 
 __all__ = ["buffer_rows", "dispatch_plan", "dropless_experts",

@@ -122,6 +122,11 @@ class Optimizer:
                   if p.grad is not None and not p.stop_gradient]
         grads = [p.grad._data for p in params]
         lr = self.get_lr()
+        from ..profiler import phases
+        with phases.phase("optimizer"):
+            self._apply(params, grads, lr)
+
+    def _apply(self, params, grads, lr):
         if params and self._fused_enabled():
             from .fused import FusedOptimizerEngine
             if self._fused_engine is None:

@@ -39,7 +39,7 @@ from jax.profiler import TraceAnnotation
 
 from ..core import dispatch as _dispatch
 from ..core import native as _nv
-from . import spans
+from . import phases, spans
 from .spans import span
 
 

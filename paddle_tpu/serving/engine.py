@@ -68,7 +68,7 @@ from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  request_keys, sample_rows)
 from ..kernels.paged_attention import (kv_append, ragged_kv_tokens_read,
                                        ragged_paged_attention)
-from ..profiler import spans
+from ..profiler import phases, spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 from .metrics import ServingMetrics
 from .scheduler import Scheduler, SchedulerConfig, Sequence, SequenceStatus
@@ -868,35 +868,42 @@ class LLMEngine:
                         slots=adapter_slots, scope=scope,
                         num_layers=num_layers)
                     return h2, Kp, Ks, Vp, Vs
-                x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
-                q = _wmat(x, lyr["q"], lora=lo(ad, "q")) \
-                    .reshape(1, T, H, d)
-                k = _wmat(x, lyr["k"], lora=lo(ad, "k")) \
-                    .reshape(1, T, Hkv, d)
-                v = _wmat(x, lyr["v"], lora=lo(ad, "v")) \
-                    .reshape(1, T, Hkv, d)
-                q = _rope(q, positions[None], cfg.rope_theta, d)
-                k = _rope(k, positions[None], cfg.rope_theta, d)
-                kt = jnp.transpose(k[0], (1, 0, 2))         # [Hkv, T, d]
-                vt = jnp.transpose(v[0], (1, 0, 2))
-                Kp, Ks, Vp, Vs = _append_quant(
-                    Kp, Ks, Vp, Vs, kt, vt, tbls, q_starts, q_lens,
-                    kv_lens)
-                o = ragged_paged_attention(
-                    q[0], Kp, Vp, tbls, q_starts, q_lens, kv_lens,
-                    q_block=qb, interpret=interpret,
-                    k_scales=Ks, v_scales=Vs)
-                h = h + _wmat(o.reshape(1, T, H * d), lyr["o"],
-                              lora=lo(ad, "o"))
-                x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
-                h = h + _wmat(
-                    jax.nn.silu(_wmat(x, lyr["gate"],
-                                      lora=lo(ad, "gate")))
-                    * _wmat(x, lyr["up"], lora=lo(ad, "up")),
-                    lyr["down"], lora=lo(ad, "down"))
+                with phases.phase("norm"):
+                    x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
+                with phases.phase("attn.qkv"):
+                    q = _wmat(x, lyr["q"], lora=lo(ad, "q")) \
+                        .reshape(1, T, H, d)
+                    k = _wmat(x, lyr["k"], lora=lo(ad, "k")) \
+                        .reshape(1, T, Hkv, d)
+                    v = _wmat(x, lyr["v"], lora=lo(ad, "v")) \
+                        .reshape(1, T, Hkv, d)
+                    q = _rope(q, positions[None], cfg.rope_theta, d)
+                    k = _rope(k, positions[None], cfg.rope_theta, d)
+                with phases.phase("attn.core"):
+                    kt = jnp.transpose(k[0], (1, 0, 2))     # [Hkv, T, d]
+                    vt = jnp.transpose(v[0], (1, 0, 2))
+                    Kp, Ks, Vp, Vs = _append_quant(
+                        Kp, Ks, Vp, Vs, kt, vt, tbls, q_starts, q_lens,
+                        kv_lens)
+                    o = ragged_paged_attention(
+                        q[0], Kp, Vp, tbls, q_starts, q_lens, kv_lens,
+                        q_block=qb, interpret=interpret,
+                        k_scales=Ks, v_scales=Vs)
+                with phases.phase("attn.out"):
+                    h = h + _wmat(o.reshape(1, T, H * d), lyr["o"],
+                                  lora=lo(ad, "o"))
+                with phases.phase("norm"):
+                    x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
+                with phases.phase("mlp"):
+                    h = h + _wmat(
+                        jax.nn.silu(_wmat(x, lyr["gate"],
+                                          lora=lo(ad, "gate")))
+                        * _wmat(x, lyr["up"], lora=lo(ad, "up")),
+                        lyr["down"], lora=lo(ad, "down"))
                 return h, Kp, Ks, Vp, Vs
 
-            h = params["embed"][tokens][None]               # [1, T, hid]
+            with phases.phase("embed"):
+                h = params["embed"][tokens][None]           # [1, T, hid]
             if scope == "model":
                 # scan-over-layers: pools (and the LoRA slab views)
                 # stack inside the jit, the SAME layer bodies as the
@@ -958,18 +965,20 @@ class LLMEngine:
                                                    Vp, Vs)
                     new_scales.append((Ks, Vs))
                     new_kv.append((Kp, Vp))
-            h = _rms_norm(h, params["norm"], cfg.rms_norm_eps)
-            verify = h[0, sample_idx.reshape(-1)]       # [R*(K+1), hid]
-            logits = _logits(params, verify, cfg) \
-                .reshape(R, K + 1, -1)                  # [R, K+1, V]
+            with phases.phase("head"):
+                h = _rms_norm(h, params["norm"], cfg.rms_norm_eps)
+                verify = h[0, sample_idx.reshape(-1)]   # [R*(K+1), hid]
+                logits = _logits(params, verify, cfg) \
+                    .reshape(R, K + 1, -1)              # [R, K+1, V]
             # non-finite guard: one in-graph isfinite all-reduce per
             # ragged row over its verify logits — a NaN/Inf surfaces at
             # commit time as a per-row flag the host turns into a
             # structured abort, instead of argmax/categorical silently
             # sampling token 0 from garbage. Pad rows (q_len == 0)
             # always read finite: their logits are null-page noise.
-            finite = jnp.all(jnp.isfinite(logits.reshape(R, -1)), axis=-1) \
-                | (q_lens <= 0)
+            with phases.phase("guard"):
+                finite = jnp.all(jnp.isfinite(logits.reshape(R, -1)),
+                                 axis=-1) | (q_lens <= 0)
             out, n_out = speculative_sample(
                 logits, draft_tokens, draft_probs, spec_lens, temps,
                 top_ks, top_ps, base_key, seeds, sample_pos)
@@ -978,10 +987,12 @@ class LLMEngine:
                 # the transfer the host makes anyway: pairs computed
                 # here and held experts touched, summed over layers, and
                 # the most tokens any one expert got
-                st = jnp.stack(moe_stats)
-                n_out = jnp.concatenate([
-                    n_out, jnp.stack([st[:, 0].sum(), st[:, 1].sum(),
-                                      st[:, 2].max()]).astype(n_out.dtype)])
+                with phases.phase("moe.combine"):
+                    st = jnp.stack(moe_stats)
+                    n_out = jnp.concatenate([
+                        n_out,
+                        jnp.stack([st[:, 0].sum(), st[:, 1].sum(),
+                                   st[:, 2].max()]).astype(n_out.dtype)])
             # the step's small results go home as one array too
             return (operands.pack_results(out, n_out, finite), new_kv,
                     new_scales if quant_pool else None)
@@ -2323,7 +2334,8 @@ class LLMEngine:
         sp.phase("serve.assemble")
         m = self.metrics
         m.host_dispatches.inc()
-        if not self._step_launched:
+        first = not self._step_launched
+        if first:
             self._step_launched = True
             m.decode_compiles.inc()
         # the rows go straight into views of the one control buffer
@@ -2398,17 +2410,21 @@ class LLMEngine:
                 return ragged_kv_tokens_read(
                     q_lens, kv_lens, q_block=self.q_block,
                     page_size=self.page_size, pages_per_seq=PPS, window=w)
-            sp.set(
-                # the rows' contexts summed over the layers, a window
-                # layer's rows counted up to window + chunk
-                attn_kv_tokens_live=n_full * live_kv + (
-                    n_win and n_win * int(
-                        np.minimum(kv_lens, window + q_lens).sum())),
-                # what the ragged kernel's walk covers, a kv head, a
-                # layer (the mean over layers where they differ)
-                attn_kv_tokens_read=((n_full and n_full * walked(None))
-                                     + (n_win and n_win * walked(window)))
-                // len(self._kinds))
+            # a child span of its own, so the device's idle time under
+            # these always-on counts has a name in a trace's breakdown
+            with spans.span("serve.assemble.counts"):
+                sp.set(
+                    # the rows' contexts summed over the layers, a window
+                    # layer's rows counted up to window + chunk
+                    attn_kv_tokens_live=n_full * live_kv + (
+                        n_win and n_win * int(
+                            np.minimum(kv_lens, window + q_lens).sum())),
+                    # what the ragged kernel's walk covers, a kv head, a
+                    # layer (the mean over layers where they differ)
+                    attn_kv_tokens_read=(
+                        (n_full and n_full * walked(None))
+                        + (n_win and n_win * walked(window)))
+                    // len(self._kinds))
         sp.phase("serve.dispatch")
         if draft_tokens is None:
             # ordinary round: the prebuilt zero operands on the device
@@ -2422,11 +2438,14 @@ class LLMEngine:
         # the host buffer goes to the call as it is: the executable's own
         # argument handling makes the one put, 0.3 ms a step sooner than
         # jax.device_put + the call (PERF.md section 6, PR 32)
-        back, new_kv, new_scales = self._ragged_jit(
-            self._ragged_params, self.pool.kv, self.pool.kv_scales, buf,
-            draft_tokens, draft_probs, self._base_key,
-            self.adapters.slab if self.adapters is not None else None)
+        args = (self._ragged_params, self.pool.kv, self.pool.kv_scales, buf,
+                draft_tokens, draft_probs, self._base_key,
+                self.adapters.slab if self.adapters is not None else None)
+        specs = phases.launch_specs(args) if first else None
+        back, new_kv, new_scales = self._ragged_jit(*args)
         m.host_transfers.inc()
+        if first:
+            self._register_step(specs)
         self.pool.kv = new_kv
         if new_scales is not None:
             self.pool.kv_scales = new_scales
@@ -2441,6 +2460,18 @@ class LLMEngine:
                    moe_max_expert_tokens=most)
             n_out = n_out[:R]
         return out, n_out, finite
+
+    def _register_step(self, specs):
+        """Hand the step executable's compiled handle to
+        ``profiler/phases.py`` (device time by phase), once, after the
+        first launch: lowering over the launch's own shapes hits JAX's
+        in-memory caches, so this traces and compiles nothing and leaves
+        ``decode_cache_size()`` where it was. The registry keeps the
+        handle, which holds no weights, past this engine's life."""
+        name = "serve.step" if self.engine_id is None \
+            else f"serve.step:{self.engine_id}"
+        with spans.span("serve.register"):      # what it costs, once
+            phases.register(name, self._ragged_jit.lower(*specs).compile())
 
     def _launch_spec(self, plan, touched, sp):
         """One speculative round: draft sync + k proposal steps, then

@@ -53,6 +53,7 @@ from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  sampling_probs)
 from ..kernels.paged_attention import (kv_append, ragged_latent_attention,
                                        ragged_paged_attention)
+from ..profiler import phases
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 
 
@@ -204,14 +205,20 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
         return (A, B, slots)
 
     def feed_forward(h):
-        x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
+        with phases.phase("norm"):
+            x = _rms_norm(h, lyr["ln2"], cfg.rms_norm_eps)
         if kind.mlp == "sparse":
-            return h + _routed_mlp(lyr, x, live, cfg, interpret, moe_stats)
-        return h + _wmat(jax.nn.silu(_wmat(x, lyr["gate"], lora=lo("gate")))
-                         * _wmat(x, lyr["up"], lora=lo("up")),
-                         lyr["down"], lora=lo("down"))
+            y = _routed_mlp(lyr, x, live, cfg, interpret, moe_stats)
+            with phases.phase("moe.combine"):       # the residual add
+                return h + y
+        with phases.phase("mlp"):
+            return h + _wmat(
+                jax.nn.silu(_wmat(x, lyr["gate"], lora=lo("gate")))
+                * _wmat(x, lyr["up"], lora=lo("up")),
+                lyr["down"], lora=lo("down"))
 
-    slot = _page_slots(positions, tbls, tok_row, live, ps, max_pages)
+    with phases.phase("attn.core"):
+        slot = _page_slots(positions, tbls, tok_row, live, ps, max_pages)
     if kind.latent:
         h, Kp = _latent_attention(lyr, h, Kp, positions, slot, tbls,
                                   q_starts, q_lens, kv_lens, cfg, q_block,
@@ -220,24 +227,28 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     H, Hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
 
-    x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
-    q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
-    k = _wmat(x, lyr["k"], lora=lo("k")).reshape(1, T, Hkv, d)
-    v = _wmat(x, lyr["v"], lora=lo("v")).reshape(1, T, Hkv, d)
-    if kind.qk_norm:
-        q = _rms_norm(q, lyr["q_norm"], cfg.rms_norm_eps)
-        k = _rms_norm(k, lyr["k_norm"], cfg.rms_norm_eps)
-    if kind.rope:
-        q = _rope(q, positions[None], cfg.rope_theta, d)
-        k = _rope(k, positions[None], cfg.rope_theta, d)
-    kt = jnp.transpose(k[0], (1, 0, 2))                  # [Hkv, T, d]
-    vt = jnp.transpose(v[0], (1, 0, 2))
-    Kp = kv_append(Kp, slot, kt, interpret=interpret)
-    Vp = kv_append(Vp, slot, vt, interpret=interpret)
-    o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
-                               kv_lens, q_block=q_block,
-                               interpret=interpret, window=kind.window)
-    h = h + _wmat(o.reshape(1, T, H * d), lyr["o"], lora=lo("o"))
+    with phases.phase("norm"):
+        x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
+    with phases.phase("attn.qkv"):
+        q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
+        k = _wmat(x, lyr["k"], lora=lo("k")).reshape(1, T, Hkv, d)
+        v = _wmat(x, lyr["v"], lora=lo("v")).reshape(1, T, Hkv, d)
+        if kind.qk_norm:
+            q = _rms_norm(q, lyr["q_norm"], cfg.rms_norm_eps)
+            k = _rms_norm(k, lyr["k_norm"], cfg.rms_norm_eps)
+        if kind.rope:
+            q = _rope(q, positions[None], cfg.rope_theta, d)
+            k = _rope(k, positions[None], cfg.rope_theta, d)
+    with phases.phase("attn.core"):
+        kt = jnp.transpose(k[0], (1, 0, 2))              # [Hkv, T, d]
+        vt = jnp.transpose(v[0], (1, 0, 2))
+        Kp = kv_append(Kp, slot, kt, interpret=interpret)
+        Vp = kv_append(Vp, slot, vt, interpret=interpret)
+        o = ragged_paged_attention(q[0], Kp, Vp, tbls, q_starts, q_lens,
+                                   kv_lens, q_block=q_block,
+                                   interpret=interpret, window=kind.window)
+    with phases.phase("attn.out"):
+        h = h + _wmat(o.reshape(1, T, H * d), lyr["o"], lora=lo("o"))
     return feed_forward(h), Kp, Vp
 
 
@@ -264,30 +275,37 @@ def _latent_attention(lyr, h, Cp, positions, slot, tbls, q_starts, q_lens,
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     T, W = h.shape[1], Cp.shape[-1]
     inv_freq = jnp.asarray(cfg.rope_inv_freq())
-    x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
-    c_q = _rms_norm(_wmat(x, lyr["q_a"]), lyr["q_a_norm"], cfg.rms_norm_eps)
-    q = _wmat(c_q, lyr["q_b"]).reshape(1, T, H, nope + rope)
-    q_rope = _rope(q[..., nope:], positions[None], None, rope, inv_freq)
-    ckv = _wmat(x, lyr["kv_a"])                          # [1, T, r + rope]
-    c_kv = _rms_norm(ckv[..., :r], lyr["kv_a_norm"], cfg.rms_norm_eps)
-    k_r = _rope(ckv[..., None, r:], positions[None], None, rope,
-                inv_freq)[:, :, 0]
-    pad = W - r - rope                                   # the row's padding
-    row = jnp.concatenate(
-        [c_kv[0], k_r[0], jnp.zeros((T, pad), c_kv.dtype)], -1)
-    # the append kernel's pages carry a kv-head axis: one head here
-    Cp = kv_append(Cp[None], slot, row[None], interpret=interpret)[0]
-    # absorb: q_nope . k_nope_j = (q_nope w_uk) . c_kv_j
-    q_lat = jnp.einsum("thn,hnr->thr", q[0, ..., :nope], lyr["w_uk"])
-    q_row = jnp.concatenate(
-        [q_lat, q_rope[0].astype(q_lat.dtype),
-         jnp.zeros((T, H, pad), q_lat.dtype)], -1)       # [T, H, W]
-    o = ragged_latent_attention(
-        q_row, Cp, tbls, q_starts, q_lens, kv_lens, v_width=r,
-        scale=cfg.softmax_scale, q_block=q_block, interpret=interpret)
-    # un-absorb: o = (sum_j p_j c_kv_j) w_uv
-    o = jnp.einsum("thr,hrv->thv", o, lyr["w_uv"])
-    return h + _wmat(o.reshape(1, T, -1), lyr["o"]), Cp
+    with phases.phase("norm"):
+        x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
+    with phases.phase("attn.qkv"):
+        c_q = _rms_norm(_wmat(x, lyr["q_a"]), lyr["q_a_norm"],
+                        cfg.rms_norm_eps)
+        q = _wmat(c_q, lyr["q_b"]).reshape(1, T, H, nope + rope)
+        q_rope = _rope(q[..., nope:], positions[None], None, rope, inv_freq)
+        ckv = _wmat(x, lyr["kv_a"])                      # [1, T, r + rope]
+        c_kv = _rms_norm(ckv[..., :r], lyr["kv_a_norm"], cfg.rms_norm_eps)
+        k_r = _rope(ckv[..., None, r:], positions[None], None, rope,
+                    inv_freq)[:, :, 0]
+        pad = W - r - rope                               # the row's padding
+        row = jnp.concatenate(
+            [c_kv[0], k_r[0], jnp.zeros((T, pad), c_kv.dtype)], -1)
+    with phases.phase("attn.core"):
+        # the append kernel's pages carry a kv-head axis: one head here
+        Cp = kv_append(Cp[None], slot, row[None], interpret=interpret)[0]
+    with phases.phase("attn.qkv"):
+        # absorb: q_nope . k_nope_j = (q_nope w_uk) . c_kv_j
+        q_lat = jnp.einsum("thn,hnr->thr", q[0, ..., :nope], lyr["w_uk"])
+        q_row = jnp.concatenate(
+            [q_lat, q_rope[0].astype(q_lat.dtype),
+             jnp.zeros((T, H, pad), q_lat.dtype)], -1)   # [T, H, W]
+    with phases.phase("attn.core"):
+        o = ragged_latent_attention(
+            q_row, Cp, tbls, q_starts, q_lens, kv_lens, v_width=r,
+            scale=cfg.softmax_scale, q_block=q_block, interpret=interpret)
+    with phases.phase("attn.out"):
+        # un-absorb: o = (sum_j p_j c_kv_j) w_uv
+        o = jnp.einsum("thr,hrv->thv", o, lyr["w_uv"])
+        return h + _wmat(o.reshape(1, T, -1), lyr["o"]), Cp
 
 
 def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
@@ -307,11 +325,14 @@ def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
         lyr["experts_down"], first=cfg.expert_offset, interpret=interpret)
     if moe_stats is not None:
         moe_stats.append(stats)
-    shared = _wmat(jax.nn.silu(_wmat(x, lyr["shared_gate"]))
-                   * _wmat(x, lyr["shared_up"]), lyr["shared_down"])
-    return y[None] + shared
+    with phases.phase("mlp"):
+        shared = _wmat(jax.nn.silu(_wmat(x, lyr["shared_gate"]))
+                       * _wmat(x, lyr["shared_up"]), lyr["shared_down"])
+    with phases.phase("moe.combine"):
+        return y[None] + shared
 
 
+@phases.scoped("sample")
 def speculative_sample(target_logits, draft_tokens, draft_probs, spec_lens,
                        temps, top_ks, top_ps, base_key, seeds, sample_pos):
     """The in-graph rejection sampler: target logits at ``k+1`` verify

@@ -486,8 +486,10 @@ def test_train_step_spans_and_one_compile_a_specialization():
     for i, s in enumerate(steps):
         kids = [r.name for r in sorted(
             (r for r in recs if r.parent_id == s.id), key=lambda r: r.t0_ns)]
-        assert kids == ["train.gather",
-                        "train.dispatch" if i else "train.compile"]
+        # a specialization's first call also hands its compiled handle
+        # to profiler/phases.py, once (PR 39)
+        assert kids == ["train.gather"] + (
+            ["train.dispatch"] if i else ["train.compile", "train.register"])
     wide = paddle.to_tensor(np.arange(48).reshape(2, 24) % 128,
                             dtype="int64")
     step(wide)

@@ -631,3 +631,60 @@ def test_the_ungated_epilogue_sorts_on_every_step(one_chip):
     text = _epilogue_text(sampler_oracle.speculative_sample, one_chip)
     assert " conditional(" not in text
     assert len(_vocabulary_sorts(_straight_line(text))) == 2
+
+
+def test_the_chips_compiler_keeps_the_phases_on_the_ragged_step(one_chip,
+                                                                on_tpu):
+    """Device time by phase stands on the optimised HLO's metadata
+    (``profiler/phases.py``): the ragged step at Mistral-7B's widths
+    (one layer of it, hidden 4096, 32 q / 8 kv heads of 128, ffn 14336),
+    compiled for the chip, names a phase on its ``fusion`` and ``copy``
+    instructions (at least 95 % of them at the cell's depth; what the
+    compiler left without metadata is charged to its reader), and
+    charges its kernels where
+    the trace's families are read (``ragged_paged_attention`` and
+    ``kv_append`` to ``attn.core``)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.hlo_forensics import instruction_metadata
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.profiler import phases
+    from paddle_tpu.serving import LLMEngine
+    paddle.seed(3)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=4096, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=1, num_attention_heads=32, num_key_value_heads=8,
+        max_position_embeddings=2048, rope_theta=1e6,
+        tie_word_embeddings=False)).bfloat16().eval()
+    eng = LLMEngine(model, max_len=2048, max_num_seqs=32, page_size=16,
+                    num_pages=256)
+    del model
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._zero_step_args())
+    text = eng._ragged_jit.lower(*specs).compile().as_text()
+    table, _, placed = phases.parse(text)
+    xla = [(n, o) for n, op, o, _ in instruction_metadata(text)
+           if op in ("fusion", "copy")]
+    bare = [(n, o) for n, o in xla if table[n][0] is None]
+    # those the first-reader rule placed are the compiler's own (no
+    # traced op's metadata), and the table keeps them apart
+    by_rule = [(n, o) for n, o in xla if n in placed]
+    print("placed by the first-reader rule:", len(by_rule), "of", len(xla))
+    assert all(o is None or "/" not in o for _, o in by_rule), by_rule
+    assert len(by_rule) <= 0.25 * len(xla), (len(by_rule), len(xla))
+    in_layer = sum(table[n][0] in ("norm", "attn.qkv", "attn.core",
+                                   "attn.out", "mlp") for n, _ in xla)
+    assert in_layer > 40
+    # what no phase owns is the step's own prologue and epilogue (the
+    # control buffer unpacked, the packing's searchsorted, the results
+    # packed): it carries metadata, which names no phase, and does not
+    # grow with depth. At the cell's 12 layers it is under 5 % by count
+    assert all(o and "phase." not in o for _, o in bare), bare
+    assert len(bare) <= 16, bare
+    assert len(bare) <= 0.05 * (len(xla) + 11 * in_layer), (len(bare),
+                                                            len(xla))
+    kernels = {n: table[n][0] for n in table
+               if "ragged_paged_attention" in n or "kv_append" in n}
+    assert kernels and set(kernels.values()) == {"attn.core"}, kernels
+    assert {"embed", "norm", "attn.qkv", "attn.core", "attn.out", "mlp",
+            "head", "guard", "sample"} <= {p for p, _ in table.values()}
