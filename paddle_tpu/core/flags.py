@@ -215,10 +215,13 @@ define_flag("tensor_operants_mode", str, "eager",
 define_flag("jit_engine_type", str, "xla",
             "compiled-path engine (xla; the reference lists executor/pir)")
 define_flag("fused_optimizer", bool, True,
-            "multi-tensor fused optimizer path: dtype-bucketed flat "
-            "updates with buffer donation (optimizer/fused.py) — one "
-            "compiled dispatch per (dtype, device) bucket instead of one "
-            "per parameter; False restores the per-parameter loop")
+            "EAGER opt.step() only: multi-tensor fused optimizer path, "
+            "dtype-bucketed flat updates with buffer donation "
+            "(optimizer/fused.py) — one compiled dispatch per (dtype, "
+            "device) bucket instead of one per parameter; False restores "
+            "the per-parameter loop. A compiled jit.TrainStep does not "
+            "read it: one dispatch either way, every leaf updated where "
+            "it lies")
 define_flag("async_pipeline", bool, True,
             "async training pipeline: DataLoader(use_buffer_reader=True) "
             "stages batches onto the device in a background thread "

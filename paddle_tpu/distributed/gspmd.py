@@ -14,7 +14,8 @@ logical 2-D device mesh::
     embed        -> P("model", None)        vocab-sharded
     lm_head      -> P(None, "model")        vocab-sharded
     norms/biases -> P()                     replicated
-    ZeRO         -> optimizer flat buckets  P("data") (1-D state spans)
+    ZeRO         -> optimizer state         P("data") on a leaf's first
+                                            whole, divisible dimension
 
 The annotations ride the EXISTING single ``jax.jit`` executables —
 ``jit.TrainStep`` (training) and ``LLMEngine``'s ragged step (serving)
@@ -41,7 +42,6 @@ the jnp/interpret bodies partition fine (docs/DISTRIBUTED.md).
 from __future__ import annotations
 
 import re
-import warnings
 
 import numpy as np
 import jax
@@ -364,46 +364,35 @@ def kv_scale_sharding(mesh) -> NamedSharding:
 
 def opt_state_shardings(opt_arrays, param_shardings_by_key, mesh,
                         zero=False) -> dict:
-    """Shardings for TrainStep's optimizer-state dict.
+    """Shardings for TrainStep's optimizer-state dict: one array a leaf
+    and state name, keyed ``{pkey}.{name}``.
 
-    Fused flat buckets (``fused{i}.{name}``, 1-D spans over a dtype
-    bucket) shard over the data axis when ``zero`` — ZeRO-1's
-    state-memory split, with GSPMD placing the gather where the updated
-    params are consumed. Per-param fallback state (``{pkey}.{name}``)
-    mirrors its parameter's sharding when shapes line up (moments live
-    where the param lives), else replicates."""
+    State mirrors its parameter's sharding when shapes line up (moments
+    live where the param lives), else replicates. Under ``zero`` it is
+    split over the data axis besides, on the first dimension that its
+    parameter leaves whole and the data degree divides: ZeRO-1's
+    state-memory split, a leaf at a time. The update is elementwise, so
+    the partitioner runs each data rank's share of it where that share
+    of the moments lies and gathers the updated parameter where it is
+    consumed."""
     dp = mesh.shape.get(DATA_AXIS, 1)
-    tp = mesh.shape.get(MODEL_AXIS, 1)
-    pp = mesh.shape.get(PIPELINE_AXIS, 1)
-    if zero and (tp > 1 or pp > 1):
-        # the 0.4.x CPU SPMD partitioner shifts flat spans when a
-        # data-sharded 1-D state mixes with a model OR pipeline axis in
-        # the same program (see constrain_flat; zero x pp corrupts the
-        # loss the same way zero x tp does — pinned by
-        # tests/test_pipeline_parallel.py); until a chip run
-        # revalidates the combination, zero keeps the state replicated
-        # off dp-only meshes
-        warnings.warn(
-            "gspmd: zero + model/pipeline-parallel combined keeps "
-            "optimizer state replicated on this backend (flat-span "
-            "partitioner defect, docs/DISTRIBUTED.md); use a dp-only "
-            "mesh for the ZeRO state split", stacklevel=2)
-        zero = False
     out = {}
     for k, v in opt_arrays.items():
         spec = P()
-        if k.startswith("fused"):
-            if zero and dp > 1 and v.ndim == 1 and v.shape[0] % dp == 0:
-                spec = P(DATA_AXIS)
-        else:
-            pkey = k.split(".", 1)[0]
-            ps = param_shardings_by_key.get(pkey)
-            if ps is not None and hasattr(v, "shape"):
-                try:
-                    if ps.shard_shape(tuple(v.shape)):
-                        spec = ps.spec
-                except Exception:
-                    spec = P()
+        ps = param_shardings_by_key.get(k.split(".", 1)[0])
+        shape = local = tuple(getattr(v, "shape", ()))
+        if ps is not None and shape:
+            try:
+                local, spec = ps.shard_shape(shape), ps.spec
+            except Exception:       # shapes do not line up: replicate
+                pass
+        if zero and dp > 1:
+            dims = list(spec) + [None] * (len(shape) - len(spec))
+            for d, held in enumerate(dims):
+                if held is None and local[d] % dp == 0:
+                    dims[d] = DATA_AXIS
+                    spec = P(*dims)
+                    break
         out[k] = NamedSharding(mesh, spec)
     return out
 
@@ -421,49 +410,10 @@ def replicated(mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-# ---------------------------------------------------------------------------
-# partitioning scope + the flat-span workaround
-# ---------------------------------------------------------------------------
-#: mesh stack bound while a GSPMD-annotated program is being traced —
-#: lets code deep inside the trace (the fused optimizer's flat-bucket
-#: concat, TrainStep's grad accumulator) know the active mesh without
-#: threading it through every signature
-_MESH_STACK: list = []
-
-
-class partitioning_scope:
-    def __init__(self, mesh, zero=False):
-        self.mesh = mesh
-        self.zero = bool(zero)
-
-    def __enter__(self):
-        _MESH_STACK.append(self)
-        return self.mesh
-
-    def __exit__(self, *exc):
-        _MESH_STACK.pop()
-        return False
-
-
-def active_mesh():
-    return _MESH_STACK[-1].mesh if _MESH_STACK else None
-
-
-def flat_state_sharded() -> bool:
-    """True while tracing a program whose fused flat optimizer state may
-    arrive data-sharded: the ZeRO preset on a pure data mesh (every other
-    mesh keeps the state replicated, see :func:`opt_state_shardings`)."""
-    if not _MESH_STACK or not _MESH_STACK[-1].zero:
-        return False
-    shape = _MESH_STACK[-1].mesh.shape
-    return (shape.get(DATA_AXIS, 1) > 1 and shape.get(MODEL_AXIS, 1) <= 1
-            and shape.get(PIPELINE_AXIS, 1) <= 1)
-
-
 #: (mesh, n_stages, n_microbatches) bound while TrainStep traces a
 #: pp>1 program — LayerStack.forward switches to the pipelined scan
 #: when this is set, without threading pipeline degrees through every
-#: model signature (same pattern as _MESH_STACK above)
+#: model signature
 _PIPELINE_STACK: list = []
 
 
@@ -561,45 +511,6 @@ def pipeline_permute_counts(hlo_text: str, pipe: int) -> dict:
     return {"ring": ring, "other": other, "total": ring + other}
 
 
-def stage_state(x):
-    """Stage a ZeRO-sharded flat state span replicated for the bucket
-    update when the TENSOR-parallel axis is also active. On a pure data
-    mesh the sharded-state compute is left alone (the ZeRO split rides
-    straight through the update); with model > 1 the same 0.4.x CPU
-    partitioner defect corrupts the mixed sharded-state x replicated-
-    grad elementwise chain, so the state gathers at body entry and the
-    step's out_shardings re-slice it — state stays sharded AT REST
-    either way. The pipeline axis counts as "another axis active" for
-    the same reason the model axis does: zero x pp mixes dp-sharded 1-D
-    state with stage-sharded params in one program."""
-    mesh = active_mesh()
-    if mesh is None or (mesh.shape.get(MODEL_AXIS, 1) <= 1
-                        and mesh.shape.get(PIPELINE_AXIS, 1) <= 1):
-        return x
-    return constrain_flat(x)
-
-
-def constrain_flat(x):
-    """Constrain a raveled flat span to REPLICATED under the active
-    partitioning mesh (identity otherwise).
-
-    Two jobs in one: (a) semantics — flat optimizer/grad spans are
-    logically whole buffers that mixed col/row-sharded leaves flow
-    into, so the concat boundary is where the partitioner must gather;
-    (b) a workaround — this container's jaxlib (0.4.x CPU SPMD
-    partitioner) MISCOMPILES ``concatenate`` when an operand's reshape
-    arrives dim-0-sharded, producing silently wrong values
-    (tests/test_gspmd.py pins the parity that catches it). Constraining
-    each part replicated before the concat sidesteps the bad lowering
-    on every backend.
-    """
-    mesh = active_mesh()
-    if mesh is None or not isinstance(x, jax.core.Tracer):
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*([None] * x.ndim))))
-
-
 # ---------------------------------------------------------------------------
 # HLO forensics
 # ---------------------------------------------------------------------------
@@ -627,9 +538,7 @@ __all__ = [
     "config_from_flags", "build_mesh", "param_spec",
     "named_param_shardings", "shard_serving_params", "kv_pool_sharding",
     "kv_scale_sharding", "opt_state_shardings", "batch_sharding",
-    "replicated", "collective_counts", "partitioning_scope",
-    "active_mesh", "flat_state_sharded", "constrain_flat", "stage_state",
-    "pipeline_scope",
+    "replicated", "collective_counts", "pipeline_scope",
     "active_pipeline", "stage_param_bytes",
     "predicted_pipeline_permutes", "pipeline_permute_counts",
 ]

@@ -400,10 +400,10 @@ class TrainStep:
 
     ``accumulate_steps=K`` runs micro-batch gradient accumulation INSIDE
     the compiled step: the batch splits into K equal micro-batches along
-    axis 0 and a ``lax.scan`` threads a dtype-bucketed flat gradient
-    accumulator through K forward+backward replays (the body is traced
+    axis 0 and a ``lax.scan`` threads one gradient accumulator a leaf
+    through K forward+backward replays (the body is traced
     once — HLO stays O(1) in K), then applies ONE optimizer update from
-    the mean gradients. The accumulator never leaves the device and the
+    the mean gradients. The accumulators never leave the device and the
     host still issues exactly one dispatch per optimizer step, so a K×
     effective batch fits in the activation memory of a batch/K step.
     Numerically the update equals a single K×-batch step for mean-shaped
@@ -476,16 +476,9 @@ class TrainStep:
         self._param_names = {k: by_id.get(id(p), k)
                              for k, p in self._params.items()}
 
-    def _fused_eng(self):
-        eng = getattr(self.optimizer, "_fused_engine", None)
-        return eng if (eng is not None and eng.active) else None
-
     def _opt_state_arrays(self):
-        eng = self._fused_eng()
-        if eng is not None:
-            # fused path: optimizer state IS the engine's flat per-bucket
-            # buffers — O(#dtype buckets) donated inputs, not O(n_params)
-            return eng.state_arrays()
+        """The optimizer's state as the step's donated input: one array
+        a leaf and state name, keyed ``"{param key}.{name}"``."""
         out = {}
         for i, p in self._params.items():
             st = self.optimizer._state.get(id(p))
@@ -495,18 +488,12 @@ class TrainStep:
         return out
 
     def _install_opt_state(self, arrays):
-        eng = self._fused_eng()
-        if eng is not None:
-            eng.install_state(arrays)
-            return
-        for i, p in self._params.items():
-            st = {}
-            prefix = f"{i}."
-            for k, v in arrays.items():
-                if k.startswith(prefix):
-                    st[k[len(prefix):]] = v
-            if st:
-                self.optimizer._state[id(p)] = st
+        state = {}
+        for key, v in arrays.items():
+            i, name = key.split(".", 1)
+            state.setdefault(i, {})[name] = v
+        for i, st in state.items():
+            self.optimizer._state[id(self._params[i])] = st
 
     def __call__(self, *batch):
         """One optimizer step. The call is the span ``train.step`` (the
@@ -594,13 +581,11 @@ class TrainStep:
         key = tuple((a.shape, str(a.dtype)) for a in batch_arrays) \
             + (check_finite, donate_batch, K, remat, cfg_key)
 
+        # An eager ``opt.step()`` since the last call left the state flat
+        # in the optimizer's engine: take it down to the leaves, where this
+        # step keeps it (one attribute read when there is nothing to take).
+        self.optimizer._flat_state_to_params()
         if key not in self._cache:
-            # Ensure optimizer state exists with final shapes: run one throwaway
-            # state init by touching _param_state via a zero-grad apply is
-            # avoided; instead let the traced call create state lazily inside
-            # the trace — it becomes constants. To keep state as *inputs*, we
-            # pre-create it here by calling the state initializer explicitly.
-            self._prime_state()
             param_t = dict(self._params)
             buffer_t = {f"b:{k}": v for k, v in buffers.items()}
             opt = self.optimizer
@@ -609,11 +594,31 @@ class TrainStep:
             step_holder = {}
             mesh = None
             batch_sh = None
+            place = None
             if shard_cfg is not None:
                 mesh = _gspmd.build_mesh(shard_cfg)
                 self._mesh = mesh
                 batch_sh = tuple(_gspmd.batch_sharding(a, mesh)
                                  for a in batch_arrays)
+                p_sh = _gspmd.named_param_shardings(
+                    {k: (self._param_names[k], tuple(p._data.shape))
+                     for k, p in self._params.items()}, mesh)
+
+                def place(k, prm, st):
+                    prm._data = jax.device_put(prm._data, p_sh[k])
+                    sh = _gspmd.opt_state_shardings(
+                        {f"{k}.{n}": v for n, v in st.items()}, p_sh,
+                        mesh, zero=shard_cfg.zero)
+                    for n, v in st.items():
+                        st[n] = jax.device_put(v, sh[f"{k}.{n}"])
+            # Under a mesh the state goes where the step keeps it BEFORE
+            # the first call, a leaf and its moments at a time. A freshly
+            # built model sits whole on device 0; handed to the jit as it
+            # is, the first call holds that original, its resharded
+            # (donated) copy and the program's temporaries together — at
+            # real sizes that does not fit the device the model was built
+            # on. Nor would every moment, created whole there at once.
+            self._prime_state(place)
 
             def pure_step(param_arrays, opt_arrays, buffer_arrays, step_i, lr, rng, *b_arrays):
                 if mesh is not None:
@@ -627,9 +632,6 @@ class TrainStep:
                 inst_p = _Installed(param_t)
                 inst_b = _Installed(buffer_t)
                 saved_state = {pid: dict(st) for pid, st in opt._state.items()}
-                eng = getattr(opt, "_fused_engine", None)
-                saved_eng = eng.snapshot() if eng is not None and eng.active \
-                    else None
                 saved_step, saved_lr = opt._step_count, opt._lr
                 saved_grads = {k: p.grad for k, p in param_t.items()}
                 try:
@@ -669,8 +671,6 @@ class TrainStep:
                         return new_params, new_opt, new_buffers, loss_arr
                 finally:
                     opt._state = saved_state
-                    if saved_eng is not None:
-                        eng.restore(saved_eng)
                     opt._step_count, opt._lr = saved_step, saved_lr
                     for k, p in param_t.items():
                         p.grad = saved_grads[k]
@@ -682,14 +682,11 @@ class TrainStep:
             jit_kw = {}
             if mesh is not None:
                 # GSPMD: the regime IS this annotation set — params by
-                # the name-driven rule table, fused flat optimizer
-                # buckets on the data axis under ZeRO (per-param state
-                # mirrors its param), batch on data, scalars/rng/buffers
+                # the name-driven rule table, every leaf's optimizer
+                # state where its param lives (split over the data axis
+                # besides under ZeRO), batch on data, scalars/rng/buffers
                 # replicated. Identical in/out shardings keep the
                 # param/opt donation valid on TPU.
-                p_sh = _gspmd.named_param_shardings(
-                    {k: (self._param_names[k], tuple(p._data.shape))
-                     for k, p in self._params.items()}, mesh)
                 o_sh = _gspmd.opt_state_shardings(
                     self._opt_state_arrays(), p_sh, mesh,
                     zero=shard_cfg.zero)
@@ -698,17 +695,6 @@ class TrainStep:
                 out_sh = (p_sh, o_sh, b_sh, rep)
                 if check_finite:
                     out_sh = out_sh + (rep,)
-                # Put the state where the step keeps it BEFORE the first
-                # call (parameters one at a time). A freshly built model sits
-                # whole on device 0; handed to the jit as it is, the first
-                # call holds that original, its resharded (donated) copy
-                # and the program's temporaries together — at real sizes
-                # that does not fit the device the model was built on.
-                for k, prm in self._params.items():
-                    prm._data = jax.device_put(prm._data, p_sh[k])
-                self._install_opt_state(
-                    {k: jax.device_put(v, o_sh[k])
-                     for k, v in self._opt_state_arrays().items()})
                 jit_kw = dict(
                     in_shardings=(p_sh, o_sh, b_sh, rep, rep, rep)
                     + batch_sh,
@@ -756,9 +742,6 @@ class TrainStep:
             # to the pipeline gauges instead of reading as one slow step.
             from ..profiler import compile_event
             sp.phase(None)
-            shard_ctx = (_gspmd.partitioning_scope(self._mesh,
-                                                   zero=shard_cfg.zero)
-                         if shard_cfg is not None else nullcontext())
             # pp>1: LayerStack.forward switches to the stage-sliced
             # pipelined scan while this scope is bound around the trace
             pipe_ctx = (_gspmd.pipeline_scope(
@@ -767,7 +750,7 @@ class TrainStep:
                 else nullcontext())
             # the launch's shapes, read before the call donates them
             specs = phases.launch_specs(args)
-            with policy_ctx, shard_ctx, pipe_ctx, \
+            with policy_ctx, pipe_ctx, \
                     compile_event("train.compile") as ev:
                 out = self._cache[key](*args)
             # ONE handle on the compiled step, taken after its first
@@ -778,7 +761,7 @@ class TrainStep:
             # text for HLO forensics (tests/test_gspmd.py,
             # tests/test_pipeline_parallel.py; jit/hlo_forensics.py
             # fusion stats over it in tests/test_hlo_forensics.py).
-            with policy_ctx, shard_ctx, pipe_ctx, \
+            with policy_ctx, pipe_ctx, \
                     span("train.register"):         # what it costs, once
                 compiled = self._cache[key].lower(*specs).compile()
             phases.register(
@@ -835,14 +818,13 @@ class TrainStep:
 
         Splits each batch array into K equal micro-batches along axis 0
         and ``lax.scan``s one forward+backward per micro-batch — the tape
-        replay is traced ONCE, so HLO stays O(1) in K. The carry is a
-        dtype-bucketed FLAT gradient accumulator (one buffer per param
-        dtype, the layout the fused optimizer's buckets consume), plus
-        the running loss; XLA double-buffers the carry in place across
-        iterations, so the accumulator never leaves the device. On exit
-        the mean grads are sliced back onto ``p.grad`` and the caller
-        runs ONE optimizer update — host dispatches per optimizer step
-        are unchanged from K=1.
+        replay is traced ONCE, so HLO stays O(1) in K. The carry is one
+        gradient accumulator a leaf, in the leaf's own shape and (under a
+        mesh) sharding, plus the running loss; XLA double-buffers the
+        carry in place across iterations, so the accumulators never leave
+        the device. On exit the mean grads land on ``p.grad`` and the
+        caller runs ONE optimizer update — host dispatches per optimizer
+        step are unchanged from K=1.
 
         Participation mirrors the K=1 path: an abstract probe
         (``jax.eval_shape`` of one micro-batch's forward+backward, no
@@ -854,8 +836,6 @@ class TrainStep:
         index so stateful randomness (dropout) would not replay one
         traced key K times.
         """
-        import numpy as _np
-
         order = [(k, p) for k, p in param_t.items()
                  if jnp.issubdtype(jnp.result_type(p._data), jnp.inexact)]
         micro = tuple(
@@ -878,17 +858,9 @@ class TrainStep:
         grad_shapes = jax.eval_shape(
             _probe, tuple(jax.ShapeDtypeStruct(m.shape[1:], m.dtype)
                           for m in micro))
-        groups: dict = {}
-        for name, p in order:
-            if name not in grad_shapes:
-                continue  # never receives a grad: optimizer skips it
-            aval = grad_shapes[name]
-            shape = tuple(aval.shape)
-            groups.setdefault(str(aval.dtype), []).append(
-                (name, int(_np.prod(shape)) if shape else 1, shape,
-                 aval.dtype))
-        init = ({dts: jnp.zeros(sum(e[1] for e in g), jnp.dtype(dts))
-                 for dts, g in groups.items()},
+        # params that never receive a grad are absent: optimizer skips them
+        init = ({name: jnp.zeros(aval.shape, aval.dtype)
+                 for name, aval in grad_shapes.items()},
                 jnp.zeros((), jnp.float32))
 
         def body(carry, xs):
@@ -900,33 +872,18 @@ class TrainStep:
                 loss = loss_fn(*[Tensor(a) for a in mbs])
                 loss.backward()
             new_acc = {}
-            from ..distributed.gspmd import constrain_flat
-            for dts, g in groups.items():
-                parts = []
-                for name, sz, _, dt in g:
-                    grad = param_t[name].grad
-                    parts.append(constrain_flat(
-                        jnp.ravel(grad._data).astype(dt))
-                        if grad is not None else jnp.zeros(sz, dt))
-                flat = parts[0] if len(parts) == 1 \
-                    else jnp.concatenate(parts)
-                new_acc[dts] = acc[dts] + flat
+            for name, a in acc.items():
+                grad = param_t[name].grad
+                new_acc[name] = a if grad is None \
+                    else a + grad._data.astype(a.dtype)
             for _, p in order:
                 p.grad = None
             return (new_acc, loss_acc + loss._data.astype(jnp.float32)), None
 
         (acc, loss_sum), _ = jax.lax.scan(
             body, init, (jnp.arange(K),) + micro)
-        from ..distributed.gspmd import constrain_flat
-        for dts, g in groups.items():
-            flat = acc[dts] / K
-            off = 0
-            for name, sz, shape, _ in g:
-                param_t[name].grad = Tensor(
-                    constrain_flat(jax.lax.slice_in_dim(
-                        flat, off, off + sz)).reshape(shape),
-                    stop_gradient=True)
-                off += sz
+        for name, a in acc.items():
+            param_t[name].grad = Tensor(a / K, stop_gradient=True)
         return loss_sum / K
 
     def _validate_pipeline(self, shard_cfg, batch_arrays, pipe_M):
@@ -961,16 +918,20 @@ class TrainStep:
                     f"(FLAGS_pipeline_microbatches, 0 = auto = pp) must "
                     f"divide the batch dim {a.shape[0]}")
 
-    def _prime_state(self):
-        """Create optimizer state ahead of tracing so state rides as
-        donated inputs rather than baked constants. Fused optimizers build
-        their dtype buckets instead (flat state, O(#buckets) inputs); the
-        per-param schema priming is the fallback."""
-        params = list(self._params.values())
-        if self.optimizer._prime_fused(params):
-            return
-        for p in params:
-            self.optimizer._param_state(p)
+    def _prime_state(self, place=None):
+        """Create every leaf's optimizer state from ``_state_schema``
+        ahead of tracing, so that it rides as donated inputs rather than
+        baked constants; ``place(key, param, state)``, under a mesh, puts
+        each leaf and its fresh state where the step keeps them before
+        the next leaf's is made. Inside the compiled step the optimizer
+        updates each leaf where it lies, in its own shape, layout and
+        sharding (``Optimizer._apply``: traced gradients take the
+        per-leaf loop); the flat buckets of ``optimizer/fused.py`` are
+        the eager path's."""
+        for k, p in self._params.items():
+            st = self.optimizer._param_state(p)
+            if place is not None:
+                place(k, p, st)
 
 
 def save(layer, path, input_spec=None, **config):

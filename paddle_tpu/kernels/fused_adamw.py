@@ -4,10 +4,14 @@ One VMEM pass reads a dtype bucket's flat param/grad/moment buffers and
 writes the updated param + both moments (the TPU rebuild of the fused
 multi-tensor AdamW CUDA kernels behind the reference's
 python/paddle/optimizer/fusion_utils.py). Callers are the fused optimizer
-engine's flat buckets (optimizer/fused.py): params f32 or bf16, moments
-f32. The step-varying scalars (lr and the two bias corrections) ride in
-SMEM so a changing lr/step never retraces; betas/eps/weight_decay are
-compile-time constants. Block size is picked by the measured autotuner
+engine's flat buckets (optimizer/fused.py), which an EAGER ``opt.step()``
+runs on one device: params f32 or bf16, moments f32. A compiled
+``jit.TrainStep`` does not come here (it updates each leaf in its own
+layout and sharding through the compiler's elementwise fusion), so the
+kernel meets no mesh. The step-varying scalars (lr and the two bias
+corrections) ride in SMEM so a changing lr/step never retraces;
+betas/eps/weight_decay are compile-time constants. Block size is picked
+by the measured autotuner
 (kernels/autotune.py) when PADDLE_TPU_AUTOTUNE=1, and off-TPU callers get
 a pure-jnp fallback with identical math.
 
@@ -170,29 +174,11 @@ def maybe_fused_adamw(p, g, m, v, lr, t, *, beta1, beta2, eps,
     on_tpu = _on_tpu()
     if not (on_tpu or forced):
         return None
-    from ..distributed.gspmd import active_mesh, flat_state_sharded
-    if flat_state_sharded():
-        # ZeRO on a data mesh: the partitioner splits the elementwise jnp
-        # update over the sharded state, which IS the ZeRO-1 update
-        return None
-
-    def run(p, g, m, v, lr, t):
+    try:
         return fused_adamw(p, g, m, v, lr, t, beta1=beta1, beta2=beta2,
                            eps=eps, weight_decay=weight_decay,
                            decoupled=decoupled,
                            interpret=forced and not on_tpu)
-
-    mesh = active_mesh()
-    if mesh is not None:
-        # a Mosaic kernel cannot be partitioned automatically: run it as
-        # a manual region over the mesh. The flat bucket is replicated
-        # there (gspmd.constrain_flat), so every device updates its copy.
-        from jax.sharding import PartitionSpec as P
-        run = jax.shard_map(run, mesh=mesh, in_specs=P(), out_specs=P(),
-                            check_vma=False)
-        lr, t = jnp.asarray(lr), jnp.asarray(t)
-    try:
-        return run(p, g, m, v, lr, t)
     except Exception:
         from ..core.flags import GLOBAL_FLAGS
         if GLOBAL_FLAGS.get("enable_fusion_fallback"):

@@ -27,10 +27,21 @@ not in the step: the kernel's own pad and slice find nothing to do, and no
 bucket-sized copy stands between the state and the kernel. Leaves are cut
 out by offset; nothing ever reads the tail.
 
+This is the EAGER path, and only that. The buckets exist so that an eager
+``step()`` costs O(#buckets) dispatches; inside a compiled step
+(``jit.TrainStep``) there is one dispatch whatever the optimizer does, and on
+a TPU a leaf in its tiled layout and its span of a 1-D buffer are different
+layouts, so every ravel, concatenation operand and cut back into a leaf is a
+pass over HBM (and under a mesh a gather of every shard). So
+``Optimizer._apply`` never enters the engine when the gradients are tracers:
+the compiled step updates each leaf where it lies, with per-param state, and
+``Optimizer._flat_state_to_params`` carries the moments across when a model
+moves between the two (one format at rest: ``state_dict`` is per parameter).
+
 Fallbacks keep the per-param loop authoritative where flattening is wrong:
 multi-device (sharded/replicated) params or states — distributed/sharding.py
 owns those placements — and optimizers without ``_fused_flat_update``.
-``FLAGS_fused_optimizer=False`` opts out globally.
+``FLAGS_fused_optimizer=False`` opts the eager path out globally.
 """
 from __future__ import annotations
 
@@ -92,12 +103,7 @@ def _concat_flat(arrays, length):
     """The leaves raveled into one flat span of ``length`` elements: a zero
     tail is the concatenation's last operand, so the span arrives at the
     bucket's length without a pass of its own."""
-    # under a GSPMD partitioning scope each raveled span is constrained
-    # replicated before the concat: the flat bucket is logically whole,
-    # and the 0.4.x CPU SPMD partitioner miscompiles concatenate over
-    # dim-0-sharded operands (distributed/gspmd.constrain_flat)
-    from ..distributed.gspmd import constrain_flat
-    parts = [constrain_flat(a.ravel()) for a in arrays]
+    parts = [a.ravel() for a in arrays]
     tail = length - sum(p.shape[0] for p in parts)
     if tail:
         parts.append(jnp.zeros(tail, parts[0].dtype))
@@ -122,11 +128,10 @@ class _Bucket:
 class FusedOptimizerEngine:
     """Dtype/device-bucketed flat optimizer updates for one Optimizer.
 
-    Owned lazily by ``Optimizer.step`` (and primed eagerly by
-    ``jit.TrainStep`` so the flat state rides as donated inputs of the
-    compiled step). Under an outer trace the cached jitted bucket updates
-    inline, shrinking the compiled step's optimizer segment to O(#buckets)
-    fused ops.
+    Owned lazily by an EAGER ``Optimizer.step`` and never entered under a
+    trace: ``Optimizer._apply`` sends traced gradients (``jit.TrainStep``)
+    to the per-param loop, and whoever wants the state per leaf takes it
+    down first (``Optimizer._flat_state_to_params``).
     """
 
     def __init__(self, opt):
@@ -154,12 +159,9 @@ class FusedOptimizerEngine:
             for p, gd in zip(params, grad_dtypes))
 
     def prime(self, params) -> bool:
-        """Build buckets ahead of jit tracing (TrainStep): every param is
-        assumed to participate with grad dtype == param dtype. Must run on
-        concrete arrays — priming under a trace would bake state into the
-        program as constants."""
-        if _is_traced([p._data for p in params]):
-            return self.active
+        """Build buckets ahead of the first step (tenancy/tune.py: a step
+        whose grads land on a subset then masks, never rebuilds): every
+        param is assumed to participate with grad dtype == param dtype."""
         return self._build(
             params, [str(jnp.result_type(p._data)) for p in params])
 
@@ -227,7 +229,7 @@ class FusedOptimizerEngine:
         self.state_dirty = True
         return b
 
-    # -- state bridging (state_dict / TrainStep) -----------------------
+    # -- state bridging (state_dict, hand-over to per-param state) ------
 
     def sync_to_param_state(self):
         """Materialize the flat buffers back into per-param ``opt._state``
@@ -243,49 +245,20 @@ class FusedOptimizerEngine:
                         flat, off, off + sz).reshape(shp)
                     off += sz
 
-    def state_arrays(self) -> dict:
-        return {f"fused{i}.{name}": arr
-                for i, b in enumerate(self.buckets)
-                for name, arr in b.state.items()}
-
-    def install_state(self, arrays: dict):
-        for i, b in enumerate(self.buckets):
-            for name in list(b.state):
-                b.state[name] = arrays[f"fused{i}.{name}"]
-        self.state_dirty = True
-
-    def snapshot(self):
-        return (self._sig, self._sig_set, list(self.buckets),
-                [dict(b.state) for b in self.buckets], self.state_dirty)
-
-    def restore(self, snap):
-        self._sig, self._sig_set, self.buckets, states, dirty = snap
-        self.state_dirty = dirty
-        for b, st in zip(self.buckets, states):
-            b.state = st
-
     # -- the fused step -------------------------------------------------
 
     def step(self, params, grads, lr) -> bool:
         """Apply one fused update. False → caller must run the per-param
-        loop (unbuildable buckets: sharded params, unseen traced sets)."""
+        loop (unbuildable buckets: sharded params or state)."""
         grad_dtypes = [str(jnp.result_type(g)) for g in grads]
         sig = self._signature(params, grad_dtypes)
         if sig != self._sig:
             if self.active and self._sig_set.issuperset(sig):
                 # a SUBSET of the primed params participates (MoE experts
                 # off-route, freshly frozen params): mask their spans
-                # instead of rebuilding — mandatory under a trace, and
-                # cheaper than a rebuild when eager participation flickers
+                # instead of rebuilding — cheaper than a rebuild when
+                # participation flickers
                 return self._run(params, grads, lr, masked=True)
-            if _is_traced([p._data for p in params] + list(grads)):
-                if self.active:
-                    raise RuntimeError(
-                        "fused optimizer: the traced parameter set does not "
-                        "match the primed buckets (new params or changed "
-                        "dtypes inside jit.TrainStep). Rebuild the TrainStep "
-                        "or set FLAGS_fused_optimizer=False for this model.")
-                return False
             if not self._build(params, grad_dtypes):
                 return False
         return self._run(params, grads, lr, masked=False)
@@ -305,8 +278,7 @@ class FusedOptimizerEngine:
             grads = clip._clip_arrays(params, grads)
         id2g = {id(p): g for p, g in zip(params, grads)}
         t = opt._step_count
-        traced = _is_traced([p._data for p in params] + list(grads))
-        donate = (not traced) and jax.default_backend() != "cpu"
+        donate = jax.default_backend() != "cpu"
         for b in self.buckets:
             present = tuple(id(p) in id2g for p in b.params)
             if masked and not all(present):
@@ -378,8 +350,6 @@ class FusedOptimizerEngine:
         sizes, shapes, length = list(b.sizes), list(b.shapes), b.length
 
         def body(p_arr, g_arr, state, aux, lr, t, scale, mask):
-            from ..distributed.gspmd import stage_state
-            state = {k: stage_state(v) for k, v in state.items()}
             # The tail past the leaves is zero in p, g and every state
             # span, and every rule the engine carries keeps it so: a zero
             # grad scaled, clipped round zero or L1'd stays zero; SGD and
@@ -403,14 +373,9 @@ class FusedOptimizerEngine:
                 new_state = {k: jnp.where(mask, v, state[k])
                              for k, v in new_state.items()}
             outs, off = [], 0
-            from ..distributed.gspmd import constrain_flat
             for sz, shp in zip(sizes, shapes):
-                # the replicated staging constraint is needed on BOTH
-                # sides of the flat buffer (see _concat_flat): the
-                # slice must land replicated before reshaping back into
-                # a leaf the out_shardings re-partition
-                outs.append(constrain_flat(jax.lax.slice_in_dim(
-                    new_flat, off, off + sz)).reshape(shp))
+                outs.append(jax.lax.slice_in_dim(
+                    new_flat, off, off + sz).reshape(shp))
                 off += sz
             return tuple(outs), new_state
 

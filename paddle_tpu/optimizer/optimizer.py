@@ -95,13 +95,21 @@ class Optimizer:
             out["LR_Scheduler"] = self._lr.state_dict()
         return out
 
+    def _flat_state_to_params(self):
+        """Take the fused engine's live flat state down into per-param
+        ``_state`` and drop its buckets: whoever steps next (the per-param
+        loop, a compiled ``jit.TrainStep``, a rebuilt engine) starts from
+        the leaves' own state. Nothing to do when no bucket is live."""
+        eng = self._fused_engine
+        if eng is not None and eng.active:
+            eng.sync_to_param_state()
+            eng.invalidate()
+
     def set_state_dict(self, state):
-        if self._fused_engine is not None and self._fused_engine.active:
-            # refresh per-param views first so keys ABSENT from `state`
-            # keep their live values, then let the loaded keys overwrite;
-            # buckets rebuild from the merged per-param state next step
-            self._fused_engine.sync_to_param_state()
-            self._fused_engine.invalidate()
+        # refresh per-param views first so keys ABSENT from `state` keep
+        # their live values, then let the loaded keys overwrite; buckets
+        # rebuild from the merged per-param state next eager step
+        self._flat_state_to_params()
         self._step_count = state.get("step", 0)
         for p in self._parameter_list:
             st = {}
@@ -127,17 +135,21 @@ class Optimizer:
             self._apply(params, grads, lr)
 
     def _apply(self, params, grads, lr):
-        if params and self._fused_enabled():
-            from .fused import FusedOptimizerEngine
+        """Eager: one jitted update a dtype bucket over flat buffers
+        (fused.py: O(#buckets) dispatches). Under a trace (the gradients
+        are tracers: ``jit.TrainStep``, ``to_static``) there is one
+        dispatch whatever runs here, so each leaf is updated where it
+        lies, in its own shape, layout and sharding, by the per-param
+        loop below; a flat bucket would be staging for nothing."""
+        from .fused import FusedOptimizerEngine, _is_traced
+        if params and not _is_traced(grads) and self._fused_enabled():
             if self._fused_engine is None:
                 self._fused_engine = FusedOptimizerEngine(self)
             if self._fused_engine.step(params, grads, lr):
                 return
-        if self._fused_engine is not None and self._fused_engine.active:
-            # handing back to the per-param loop (flag flipped off, params
-            # became sharded): _apply_one must see the live flat state
-            self._fused_engine.sync_to_param_state()
-            self._fused_engine.invalidate()
+        # handing back to the per-param loop (flag flipped off, params
+        # became sharded): _apply_one must see the live flat state
+        self._flat_state_to_params()
         from .fused import record_dispatch
         if self._grad_clip is not None:
             grads = self._grad_clip._clip_arrays(params, grads)
@@ -161,9 +173,10 @@ class Optimizer:
             and hasattr(self, "_fused_flat_update")
 
     def _prime_fused(self, params):
-        """Build the fused engine's buckets ahead of jit tracing so flat
-        state rides as donated inputs of the compiled step (jit.TrainStep).
-        True when the fused path will serve the traced ``step()``."""
+        """Build the fused engine's buckets over ``params`` ahead of the
+        first eager step (tenancy/tune.py: a step whose grads land on a
+        subset then masks their spans, never rebuilds). True when the
+        fused path will serve ``step()``."""
         params = [p for p in params if not p.stop_gradient]
         if not (params and self._fused_enabled()):
             return False

@@ -134,41 +134,3 @@ def test_collective_counts_read_tpu_layouts():
         "collective_permute": 1, "all_to_all": 0}
     assert pipeline_permute_counts(text, pipe=2) == {
         "ring": 1, "other": 0, "total": 1}
-
-
-def test_fused_adamw_is_a_manual_region_under_a_mesh(monkeypatch):
-    """A Mosaic kernel cannot be partitioned automatically: under an
-    active GSPMD mesh the fused AdamW kernel runs inside shard_map (the
-    flat bucket is replicated there), and steps aside for the
-    partitioner's own elementwise update when ZeRO shards the state."""
-    from jax.sharding import Mesh
-    from paddle_tpu.distributed import gspmd
-    from paddle_tpu.kernels.fused_adamw import _reference, maybe_fused_adamw
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")   # interpreter
-    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
-                (gspmd.DATA_AXIS, gspmd.MODEL_AXIS))
-    rng = np.random.default_rng(0)
-    n = 512 * 128
-    p, g = (jnp.asarray(rng.standard_normal(n), jnp.float32) for _ in "pg")
-    m = v = jnp.zeros(n, jnp.float32)
-    kw = dict(beta1=0.9, beta2=0.999, eps=1e-8, weight_decay=0.01,
-              decoupled=True)
-
-    def step(p, g, m, v):
-        return maybe_fused_adamw(p, g, m, v, 1e-3, 1, **kw)
-
-    with gspmd.partitioning_scope(mesh):
-        jitted = jax.jit(step)
-        assert "shard_map" in str(jax.make_jaxpr(step)(p, g, m, v))
-        got = jitted(p, g, m, v)
-    want = _reference(p, g, m, v, 1e-3, 1 - 0.9, 1 - 0.999, beta1=0.9,
-                      beta2=0.999, eps=1e-8, wd=0.01, decoupled=True)
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
-    dp_only = Mesh(np.asarray(jax.devices()[:2]), (gspmd.DATA_AXIS,))
-    with gspmd.partitioning_scope(dp_only, zero=True):
-        assert gspmd.flat_state_sharded()
-        assert maybe_fused_adamw(p, g, m, v, 1e-3, 1, **kw) is None
-    with gspmd.partitioning_scope(mesh, zero=True):
-        assert not gspmd.flat_state_sharded()    # zero x tp: replicated

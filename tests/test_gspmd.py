@@ -7,9 +7,10 @@ provable chip-free. The acceptance bars, asserted not logged:
 - DP/TP/ZeRO presets are ANNOTATIONS ONLY: the same TrainStep call with
   a different preset string produces loss bit-comparable (<= 1e-6) to
   the single-device reference — no per-regime step code;
-- the fused optimizer's flat buckets survive as sharded flat state
-  under the ZeRO preset (per-device span = global/degree) with
-  matching in/out shardings (the donation-validity condition);
+- optimizer state rests one array a leaf where its parameter lives;
+  under the ZeRO preset it is split over the data axis besides
+  (per-device state = global/degree) with matching in/out shardings
+  (the donation-validity condition);
 - the collective mix read from the compiled HLO matches what each
   preset promises (DP: grad all-reduce, no gathers; ZeRO: param
   all-gather appears; TP: strictly more all-reduces than DP);
@@ -88,29 +89,56 @@ def test_preset_loss_parity_vs_single_device(runs, preset):
         f"{preset}: {got} vs reference {ref}")
 
 
-def test_zero_shards_flat_optimizer_state(runs):
+def test_zero_shards_optimizer_state_a_leaf_at_a_time(runs):
+    """ZeRO-1 per leaf (ISSUE 40 took the flat buckets out of the compiled
+    step): every moment is split over the data axis on the first
+    dimension its parameter leaves whole and the degree divides, so a
+    data rank holds 1/dp of it; the parameters stay replicated."""
     _, step, opt = runs["dp=8,zero"]
-    eng = opt._fused_engine
-    assert eng is not None and eng.active, (
-        "ZeRO must keep the fused flat buckets (not fall back to the "
-        "per-param loop)")
-    arrs = eng.state_arrays()
-    assert arrs, "no flat optimizer state survived"
+    assert opt._fused_engine is None
+    arrs = step._opt_state_arrays()
+    assert len(arrs) == 2 * len(step._params)
     dp = 8
+    held = whole = 0
     for k, v in arrs.items():
+        p = step._params[k.split(".", 1)[0]]._data
+        assert p.sharding.spec == P() and v.shape == p.shape
         sh = v.sharding
         assert isinstance(sh, NamedSharding), (k, sh)
-        assert sh.spec == P(gspmd.DATA_AXIS), (
-            f"{k}: flat state not sharded over the data axis: {sh.spec}")
-        # per-device state memory really is global/degree
-        local = v.addressable_shards[0].data.shape[0]
-        assert local == v.shape[0] // dp, (k, local, v.shape)
+        local = v.addressable_shards[0].data
+        whole += v.size
+        held += local.size
+        if any(d % dp == 0 for d in v.shape):
+            d = next(i for i, n in enumerate(v.shape) if n % dp == 0)
+            assert sh.spec[d] == gspmd.DATA_AXIS, (k, sh.spec)
+            # per-device state memory really is global/degree
+            assert local.shape[d] == v.shape[d] // dp, (k, local.shape)
+    assert held * dp == whole       # every leaf of this model divides
     # donation-validity condition: the state coming OUT of the step has
     # exactly the sharding the step takes IN (identical in/out specs)
     mesh = step._mesh
-    o_sh = gspmd.opt_state_shardings(arrs, {}, mesh, zero=True)
+    p_sh = {k: p._data.sharding for k, p in step._params.items()}
+    o_sh = gspmd.opt_state_shardings(arrs, p_sh, mesh, zero=True)
     for k, v in arrs.items():
         assert v.sharding.spec == o_sh[k].spec
+
+
+def test_tp_state_lives_where_its_parameter_lives(runs):
+    """``tp=2,dp=4``: every moment's sharding equals its parameter's, so
+    each chip updates the half of a tensor-parallel leaf that it holds
+    and no state array is replicated that its parameter is not."""
+    _, step, opt = runs["tp=2,dp=4"]
+    assert opt._fused_engine is None
+    arrs = step._opt_state_arrays()
+    assert len(arrs) == 2 * len(step._params)
+    sharded = 0
+    for k, v in arrs.items():
+        p = step._params[k.split(".", 1)[0]]._data
+        assert v.sharding.spec == p.sharding.spec, (k, v.sharding.spec)
+        assert v.addressable_shards[0].data.shape == \
+            p.addressable_shards[0].data.shape
+        sharded += gspmd.MODEL_AXIS in p.sharding.spec
+    assert sharded == 2 * (2 * 7 + 2)   # 7 a layer, embed, lm_head
 
 
 def test_tp_shards_params_on_model_axis(runs):

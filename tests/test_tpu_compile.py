@@ -424,8 +424,9 @@ BUCKET_LEAVES = [(1024, 2048), (2048, 1024), (512, 2048), (2048,), (2048,)]
 
 def _engine_bucket():
     """The real engine's one f32 AdamW bucket over BUCKET_LEAVES and its
-    jitted update, state donated as ``FusedOptimizerEngine._run`` and
-    ``TrainStep`` have it."""
+    jitted update, state donated as ``FusedOptimizerEngine._run`` has it
+    (the eager path's: a compiled ``TrainStep`` builds no bucket, see
+    ``test_a_train_step_updates_every_leaf_where_it_lies``)."""
     import numpy as np
     import paddle_tpu as paddle
     params = []
@@ -486,6 +487,49 @@ def test_an_unaligned_fused_adamw_pads_and_slices_the_bucket(one_chip,
     text = _compiled_text(fn, one_chip, *shapes)
     assert len(_sized_ops(text, "pad", n // 2)) == 4
     assert len(_sized_ops(text, "slice", n // 2)) == 3
+
+
+def test_a_train_step_updates_every_leaf_where_it_lies(one_chip,
+                                                       monkeypatch):
+    """ISSUE 40. A two-layer step of SmolLM2's shapes at a quarter of
+    its widths (tests/trainstep_witness.py), built as the training cells
+    build theirs and compiled whole for the chip: under
+    ``phase.optimizer`` no concatenation, no ``dynamic-update-slice``,
+    no ``fused_adamw`` kernel and no result as large as the leaves
+    together, and every parameter and moment input is aliased to a
+    result (updated in place)."""
+    import re
+    from paddle_tpu.profiler import phases
+    import paddle_tpu.kernels as K
+    from trainstep_witness import (flat_bucket_traces,
+                                   optimizer_instructions,
+                                   smollm2_like_step)
+    step, model, opt, ids = smollm2_like_step(batch=2)
+    seen = []
+    real = phases.launch_specs
+    monkeypatch.setattr(phases, "launch_specs",
+                        lambda args: seen.append(real(args)) or seen[-1])
+    step(ids)                       # on this host: builds the specialization
+    specs, = seen
+    jitted, = step._cache.values()
+    # the same traced function, donated as on the chip, the kernels'
+    # entry points steered onto their TPU branch
+    monkeypatch.setattr(K, "_ON_TPU", True)
+    monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS", raising=False)
+    specs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+        if isinstance(s, jax.ShapeDtypeStruct) else s, specs)
+    text = jax.jit(jitted.__wrapped__, donate_argnums=(0, 1)) \
+        .lower(*specs).compile().as_text()
+    assert optimizer_instructions(text), "no phase.optimizer in the text"
+    leaves = sum(p._data.size for p in step._params.values())
+    assert not flat_bucket_traces(text, leaves)
+    assert "fused_adamw" not in text
+    # flat arguments: the parameters (a dict: sorted keys), then the state
+    n = len(step._params) + len(step._opt_state_arrays())
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}", alias)}
+    assert set(range(n)) <= aliased, sorted(set(range(n)) - aliased)
 
 
 # the serving launch's control buffer (spec_decode.StepOperands) at the
