@@ -308,10 +308,11 @@ class LlamaForCausalLM(nn.Layer):
             # the residual stream and the loss collapses to ~0.
             w = (self.model.embed_tokens.weight if self.lm_head is None
                  else self.lm_head.weight)
+            # [batch, seq] unflattened: a chunk keeps to its rows, so a
+            # data-parallel rank computes its own rows' loss
             with phases.phase("loss"):
                 loss = F.fused_linear_cross_entropy(
-                    h[:, :-1].reshape([-1, self.config.hidden_size]), w,
-                    labels[:, 1:].reshape([-1]),
+                    h[:, :-1], w, labels[:, 1:],
                     chunk_size=self.config.loss_chunk_size,
                     transpose_weight=self.lm_head is None)
             return None, loss

@@ -157,8 +157,7 @@ class LlamaMoeForCausalLM(nn.Layer):
             w = (self.model.embed_tokens.weight if self.lm_head is None
                  else self.lm_head.weight)
             loss = F.fused_linear_cross_entropy(
-                h[:, :-1].reshape([-1, self.config.hidden_size]), w,
-                labels[:, 1:].reshape([-1]),
+                h[:, :-1], w, labels[:, 1:],
                 chunk_size=self.config.loss_chunk_size,
                 transpose_weight=self.lm_head is None)
             aux = self.model.aux_loss()

@@ -7,6 +7,8 @@ inputs (class weights, normalizers) ride as trailing positional arrays.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -336,17 +338,23 @@ def _fused_linear_cross_entropy(h, w, lbl, *, chunk_size, transpose_weight,
                                 reduction, ignore_index):
     from jax import lax
 
-    n, d = h.shape
-    chunk = min(chunk_size, n)
+    # chunks run along the LAST token axis: [tokens] flat, or
+    # [batch, seq] with chunk_size // batch positions a row, so a chunk
+    # holds chunk_size tokens of the whole batch and never crosses a row
+    *rows, n, d = h.shape
+    chunk = min(max(1, chunk_size // math.prod(rows)), n)
     pad = (-n) % chunk
     if pad:  # pad to a chunk multiple with ignored labels (no divisor
         # search: a prime token count must not degrade to chunk=1)
-        h = jnp.concatenate([h, jnp.zeros((pad, d), h.dtype)])
-        lbl = jnp.concatenate(
-            [lbl, jnp.full((pad,), ignore_index, lbl.dtype)])
+        h = jnp.pad(h, [(0, 0)] * len(rows) + [(0, pad), (0, 0)])
+        lbl = jnp.pad(lbl, [(0, 0)] * len(rows) + [(0, pad)],
+                      constant_values=ignore_index)
         n = n + pad
 
     def chunk_loss(h_c, l_c):
+        # a chunk's rows as one token axis: the matmul [chunk_size, d]
+        # the flat form has (batch leads, so a split of it stays whole)
+        h_c, l_c = h_c.reshape(-1, d), l_c.reshape(-1)
         logits = (h_c @ w.T if transpose_weight else h_c @ w)
         logits = logits.astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
@@ -356,8 +364,10 @@ def _fused_linear_cross_entropy(h, w, lbl, *, chunk_size, transpose_weight,
         tok = jnp.where(valid, lse - gold, 0.0)
         return tok.sum(), valid.sum()
 
-    h_r = h.reshape(n // chunk, chunk, d)
-    l_r = lbl.reshape(n // chunk, chunk)
+    # [n_chunks, *rows, chunk, ...]: the scan walks the chunks, each
+    # chunk keeps the batch axis (and its split over a mesh) in front
+    h_r = jnp.moveaxis(h.reshape(*rows, n // chunk, chunk, d), len(rows), 0)
+    l_r = jnp.moveaxis(lbl.reshape(*rows, n // chunk, chunk), len(rows), 0)
 
     def body(carry, hl):
         acc, cnt = carry
@@ -384,13 +394,30 @@ def fused_linear_cross_entropy(hidden, weight, label, chunk_size=1024,
     rematerialized in backward (jax.checkpoint), cutting peak HBM by
     ~2 x tokens x vocab x 4B at ~6% extra head FLOPs.
 
-    hidden: [tokens, hidden]; weight: [hidden, vocab] (or [vocab, hidden]
-    with transpose_weight=True, the tied-embedding layout); label: [tokens].
+    hidden: [tokens, hidden] with label [tokens], or [batch, seq, hidden]
+    with label [batch, seq]; weight: [hidden, vocab] (or [vocab, hidden]
+    with transpose_weight=True, the tied-embedding layout).
+
+    The 3-D form is what a model's training loss passes: a chunk is
+    [batch, chunk_size // batch] tokens (the same chunk_size tokens of
+    the whole batch), each row's sequence padded with ignore_index labels
+    to a chunk multiple, so no chunk crosses a row. Under a data-parallel
+    mesh a rank's rows then stay on it and each chip computes its own
+    rows' loss; flattening batch into tokens first makes chunks that
+    cross the ranks' boundary, and the partitioner gathers every rank's
+    hidden states onto every chip on every chunk. Mean and sum are over
+    the same tokens either way.
     """
     if reduction not in ("mean", "sum"):
         raise ValueError(
             f"fused_linear_cross_entropy supports reduction='mean'|'sum', "
             f"got {reduction!r} (use cross_entropy for per-token losses)")
+    if len(hidden.shape) not in (2, 3) \
+            or list(label.shape) != list(hidden.shape[:-1]):
+        raise ValueError(
+            f"fused_linear_cross_entropy takes hidden [tokens, hidden] or "
+            f"[batch, seq, hidden] and label of its leading shape, got "
+            f"{list(hidden.shape)} and {list(label.shape)}")
     return op_call("fused_linear_cross_entropy", _fused_linear_cross_entropy,
                    hidden, weight, label, chunk_size=chunk_size,
                    transpose_weight=bool(transpose_weight),
@@ -446,7 +473,6 @@ def _hsigmoid_loss(x, lbl, w, *rest, num_classes, has_bias, has_path):
         mask = (tbl >= 0).astype(x.dtype)
         safe = jnp.maximum(tbl, 0).astype(jnp.int32)
     else:
-        import math
         c = lbl.reshape(-1).astype(jnp.int32)
         n_leaf_base = num_classes - 1
         depth = max(1, int(math.ceil(math.log2(max(num_classes, 2)))))

@@ -177,6 +177,48 @@ def test_training_continues_after_first_compile(runs):
         assert len(step._cache) == 1, preset
 
 
+def _train_chunked_loss(preset, n_steps=3):
+    """A tied model trained on its own chunked loss (``labels``): 23
+    predicted positions a row, which no per-row chunk divides."""
+    cfg = llama_tiny_config(**CFG, tie_word_embeddings=True,
+                            loss_chunk_size=32)
+    paddle.seed(7)
+    model = LlamaForCausalLM(cfg)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step = pjit.TrainStep(model, lambda ids: model(ids, labels=ids)[1], opt,
+                          sharding=preset)
+    rng = np.random.default_rng(1)
+    losses = [float(step(paddle.to_tensor(
+        rng.integers(0, cfg.vocab_size, (8, 24)))).numpy())
+        for _ in range(n_steps)]
+    return losses, step
+
+
+@pytest.fixture(scope="module")
+def chunked_loss_reference():
+    return _train_chunked_loss(None)[0]
+
+
+@pytest.mark.parametrize("preset", ["tp=2,dp=4", "dp=8"])
+def test_chunked_loss_keeps_each_ranks_rows(chunked_loss_reference, preset):
+    """The chunked loss's chunks keep to their batch rows, so under a
+    data-parallel split each rank computes its own rows' loss: the
+    compiled step moves nothing under ``phase.loss`` but all-reduces
+    (no hidden state or label is gathered onto every chip), and the
+    losses are the single device's."""
+    from paddle_tpu.jit.hlo_forensics import instruction_metadata
+    losses, step = _train_chunked_loss(preset)
+    moved = [f"{opcode} {op_name}" for _, opcode, op_name, _ in
+             instruction_metadata(step.last_hlo_text)
+             if opcode.removesuffix("-start") in ("all-gather", "all-to-all")
+             and "phase.loss" in (op_name or "")]
+    assert not moved, moved
+    ref = chunked_loss_reference
+    assert max(abs(a - b) for a, b in zip(ref, losses)) <= 1e-6, (
+        f"{preset}: {losses} vs reference {ref}")
+
+
 # ---------------------------------------------------------------------------
 # serving: tensor-parallel engine
 # ---------------------------------------------------------------------------

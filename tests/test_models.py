@@ -174,6 +174,42 @@ def test_fused_linear_cross_entropy_parity():
                                float(full_pad.numpy()), rtol=1e-5)
 
 
+@pytest.mark.parametrize("tied,batch,seq,chunk,ignored", [
+    (False, 3, 16, 12, False),    # a row of 4 tokens a chunk, no pad
+    (True, 3, 16, 12, True),      # tied layout, ignore_index labels
+    (False, 2, 13, 8, True),      # 13 positions padded to 16 a row
+    (True, 5, 7, 4, False),       # batch > chunk_size: one token a row
+])
+def test_fused_linear_cross_entropy_rows_equal_flat(tied, batch, seq, chunk,
+                                                    ignored):
+    """[batch, seq, hidden] with per-row chunks is the same loss over the
+    same tokens as the flat [tokens, hidden] form, and has the same
+    gradients with respect to the hidden states and the weight."""
+    rng = np.random.default_rng(batch * 100 + seq)
+    d, v = 16, 37
+    h = rng.standard_normal((batch, seq, d)).astype(np.float32)
+    w = (rng.standard_normal((v, d) if tied else (d, v)) * 0.1) \
+        .astype(np.float32)
+    lbl = rng.integers(0, v, (batch, seq))
+    if ignored:
+        lbl[:, ::3] = -100
+
+    def run(hidden, label):
+        ht = paddle.to_tensor(hidden, stop_gradient=False)
+        wt = paddle.to_tensor(w, stop_gradient=False)
+        loss = F.fused_linear_cross_entropy(
+            ht, wt, paddle.to_tensor(label, dtype="int64"),
+            chunk_size=chunk, transpose_weight=tied)
+        loss.backward()
+        return (float(loss.numpy()), ht.grad.numpy().reshape(-1, d),
+                wt.grad.numpy())
+
+    rows = run(h, lbl)
+    flat = run(h.reshape(-1, d), lbl.reshape(-1))
+    for got, want in zip(rows, flat):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.slow
 def test_llama_tied_embeddings_causal_shift():
     # Without the causal label shift, a tied-embedding model "predicts" its

@@ -475,6 +475,39 @@ def test_the_bucket_update_pads_and_slices_nothing(one_chip, on_tpu):
         f"{alias[:200]}")
 
 
+@pytest.mark.parametrize("form", ["rows", "flat"])
+def test_the_chunked_loss_keeps_each_ranks_rows_on_the_chip(topo, form):
+    """The chunked loss and its gradients compiled for a described v5e
+    2x2 mesh, hidden states split over ``data``, the tied weight's vocab
+    over ``model``: handed ``[batch, seq, hidden]`` the chip's partitioner
+    moves nothing but all-reduces; the flat ``[tokens, hidden]`` form it
+    replaced (the control) gathers the hidden states onto every chip."""
+    import re
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.nn.functional.loss import _fused_linear_cross_entropy
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    b, s, d, v = 4, 255, 512, 2048       # 255 positions: each row pads
+
+    def loss(h, w, lbl):
+        if form == "flat":
+            h, lbl = h.reshape(-1, d), lbl.reshape(-1)
+        return _fused_linear_cross_entropy(
+            h, w, lbl, chunk_size=256, transpose_weight=True,
+            reduction="mean", ignore_index=-100)
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*axes)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((b, s, d), jnp.bfloat16, "data"),
+        spec((v, d), jnp.bfloat16, "model"),
+        spec((b, s), jnp.int32, "data")).compile().as_text()
+    moved = re.findall(r"= \S+ (all-gather|all-to-all)(?:-start)?\(", text)
+    assert "all-reduce" in text
+    assert bool(moved) == (form == "flat"), moved
+
+
 def test_an_unaligned_fused_adamw_pads_and_slices_the_bucket(one_chip,
                                                              on_tpu):
     """The guard above can see what it guards against: handed the same
