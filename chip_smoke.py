@@ -12,6 +12,11 @@ user calls, at TinyLlama-1.1B widths (vocab 32000, hidden 2048, ffn
   dots.vlm1.inst's head sizes (128 heads against rows of 512 + 64 values
   held in 640 lanes), a few pages, decode rows and a chunk in one launch,
   behind ``kv_append``; against its ``jnp`` reference.
+- gated delta: ``kernels/gated_delta.py::ragged_gated_delta`` alone at
+  Qwen3-Next's linear heads (32 value heads over 16 key heads of 128),
+  decode rows and a 200-token chunk in one launch from float32 state
+  slots, then again with the chunk's tokens all one key; against its
+  token-by-token ``jnp`` recurrence.
 - train: ``paddle.jit.TrainStep`` + AdamW + bf16 autocast + remat at
   b 1 x s 2048, as deep as one 16 GB chip holds; loss finite and falling
   on a repeated batch.
@@ -58,6 +63,9 @@ SERVE = dict(layers=(22, 2), max_len=(1024, 128),
 # the latent kernel: heads, row width held, value width, page slots a row
 LATENT = dict(heads=(128, 4), row=(640, 128), width=(576, 40),
               v_width=(512, 32), pages=(64, 24))
+# the gated-delta kernel: value heads, key heads, head size, chunk tokens
+GATED_DELTA = dict(heads=(32, 4), key_heads=(16, 2), dim=(128, 16),
+                   chunk=(200, 20))
 TRAIN = dict(layers=(10, 2), batch=(1, 1), seq=(2048, 128), steps=(4, 3))
 SHARDED = dict(layers=(10, 2), batch=(2, 2), seq=(2048, 128), steps=(3, 3),
                preset="dp=2,tp=2")
@@ -278,6 +286,79 @@ def latent_leg(on_chip, rehearse):
                       "max_abs_err": err, "limit": tol}), flush=True)
 
 
+def gated_delta_leg(on_chip, rehearse):
+    """The gated-delta recurrence over state slots at the published
+    linear heads: two decode rows from their slots, a chunk that ends
+    mid-block from its slot, a fresh row, in one launch."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.gated_delta import (gated_delta_reference,
+                                                ragged_gated_delta)
+    h, hk, d, chunk = (pick(GATED_DELTA, k, rehearse)
+                       for k in ("heads", "key_heads", "dim", "chunk"))
+    qb, slots = 8, 6
+    rng = np.random.default_rng(1)
+    q_lens = np.array([1, chunk, 1, 13, 0], np.int32)
+    fresh = np.array([0, 0, 0, 1, 0], np.int32)
+    rows_slot = np.array([3, 1, 5, 2, 0], np.int32)
+    blocks = -(-q_lens // qb) * qb
+    t = int(blocks.sum()) + qb
+    q_starts = np.where(q_lens > 0, np.cumsum(blocks) - blocks, t) \
+        .astype(np.int32)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(rng.standard_normal((t, hk, d))) * d ** -0.5
+    k = unit(rng.standard_normal((t, hk, d)))
+    v = rng.standard_normal((t, h, d))
+    g = -np.log1p(np.exp(rng.standard_normal((t, h)))) * rng.uniform(
+        0.01, 4.0, (1, h))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((t, h))))
+    state = (rng.standard_normal((slots, h, d, d)) * 0.1).astype(np.float32)
+    # float32 on both sides; the chunked form sums in another order
+    tol = 1e-3 if on_chip else 1e-4
+    rep = h // hk
+
+    def launch(case, k, g, beta):
+        ops = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta, state)]
+        got, new_state = ragged_gated_delta(
+            *ops, jnp.asarray(rows_slot), jnp.asarray(q_starts),
+            jnp.asarray(q_lens), jnp.asarray(fresh), q_block=qb,
+            interpret=not on_chip)
+        err = 0.0
+        for r in np.flatnonzero(q_lens):
+            s0, n = int(q_starts[r]), int(q_lens[r])
+            s_in = np.zeros((h, d, d)) if fresh[r] else state[rows_slot[r]]
+            want_o, want_s = gated_delta_reference(
+                *(jnp.asarray(x, jnp.float32) for x in (
+                    np.repeat(q[s0:s0 + n], rep, 1),
+                    np.repeat(k[s0:s0 + n], rep, 1),
+                    v[s0:s0 + n], g[s0:s0 + n], beta[s0:s0 + n], s_in)))
+            err = max(err, float(jnp.abs(got[s0:s0 + n] - want_o).max()),
+                      float(jnp.abs(new_state[rows_slot[r]] - want_s).max()))
+        check(bool(jnp.isfinite(got[:int(blocks.sum())]).all()),
+              f"gated delta ({case}): non-finite output")
+        check(err <= tol, f"gated delta ({case}): kernel differs from its "
+                          f"recurrence by {err:.2e} (limit {tol})")
+        untouched = np.asarray(new_state[np.array([0, 4])]) \
+            - state[np.array([0, 4])]
+        check(not untouched.any(),
+              f"gated delta ({case}): a slot no row holds moved")
+        print(json.dumps({"leg": "gated_delta", "case": case, "heads": h,
+                          "key_heads": hk, "dim": d,
+                          "tokens": int(q_lens.sum()), "max_abs_err": err,
+                          "limit": tol}), flush=True)
+
+    launch("random keys", k, g, beta)
+    # the chunk row's tokens all one key (a repeated token), written
+    # strongly and hardly forgotten: the case an unstable inverse of the
+    # chunk's triangular system gets wrong by orders of magnitude
+    s0, n = int(q_starts[1]), int(q_lens[1])
+    k, g, beta = k.copy(), g.copy(), beta.copy()
+    k[s0:s0 + n] = k[s0]
+    g[s0:s0 + n], beta[s0:s0 + n] = -1e-3, 0.9
+    launch("one key repeated", k, g, beta)
+
+
 def train_steps(paddle, widths, layers, batch, seq, steps, sharding=None):
     """Build model + AdamW + TrainStep from the seed, take ``steps`` steps
     on one repeated batch. Returns (losses, step, model, seconds the
@@ -449,6 +530,7 @@ def main(argv=None):
         serve_leg(paddle, dev, on_chip, args.rehearse)
         gc.collect()
         latent_leg(on_chip, args.rehearse)
+        gated_delta_leg(on_chip, args.rehearse)
         train_leg(paddle, dev, on_chip, args.rehearse)
 
     if not on_chip or args.rehearse:

@@ -27,7 +27,8 @@ import jax
 #: names the metric each is for)
 PHASES = ("embed", "norm", "attn.qkv", "attn.core", "attn.out", "mlp",
           "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
-          "head", "guard", "sample", "loss", "optimizer")
+          "head", "guard", "sample", "loss", "optimizer",
+          "linear.proj", "linear.scan", "linear.out")
 #: the scope of a recomputed forward, ``jax.checkpoint``'s own name for it
 REMAT_SCOPE = "rematted_computation"
 

@@ -259,6 +259,16 @@ class LayerKind:
     #: values a kv head; the pool is then a latent one
     #: (serving/kv_cache.py ``latent_row``)
     latent: bool = False
+    #: rotary on the first ``rotary_dim`` dims of each q and k head and
+    #: none on the rest; None = the whole head
+    rotary_dim: int | None = None
+    #: an output gate: ``q`` projects to each head's query and gate, and
+    #: attention's output is multiplied by ``sigmoid(gate)`` before ``o``
+    gate: bool = False
+    #: linear attention (Gated DeltaNet, its sizes the config's): no
+    #: keys or values are cached, a row carries a recurrent state in a
+    #: slot of the pool (serving/kv_cache.py ``state_layers``)
+    linear: bool = False
 
 
 def layer_kinds(cfg):

@@ -1,6 +1,7 @@
-"""A dropless, sigmoid-routed expert layer that is told which experts it
-holds — the serving path's routed feed-forward (pure ``jax.numpy`` over
-raw arrays, like ``models/generation.py``'s bodies).
+"""A dropless routed expert layer that is told which experts it holds —
+the serving path's routed feed-forward (pure ``jax.numpy`` over raw
+arrays, like ``models/generation.py``'s bodies). The router scores with a
+sigmoid (:func:`route_sigmoid`) or a softmax (:func:`route_softmax`).
 
 The layer a chip runs in an expert-parallel deployment: the router scores
 ALL ``router_width`` experts in float32 and picks ``top_k`` a token; this
@@ -66,6 +67,28 @@ def route_sigmoid(x, router, bias, *, top_k, scaling, norm_topk_prob=True,
     if norm_topk_prob:
         g = g / jnp.sum(g, -1, keepdims=True)
     return idx.astype(jnp.int32), g * scaling
+
+
+@phases.scoped("moe.route")
+def route_softmax(x, router, *, top_k, norm_topk_prob=True):
+    """Experts and gates of every token under a softmax router: ``s =
+    softmax(x router)`` over all ``router_width`` experts in float32 at
+    full precision, the ``top_k`` largest chosen, their gates ``s``
+    renormalised over the chosen where ``norm_topk_prob``. Returns
+    ``(idx [T, k] int32, gates [T, k] float32)``."""
+    s = jax.nn.softmax(jnp.dot(x.astype(F32), router.astype(F32),
+                               precision="highest"), -1)
+    g, idx = jax.lax.top_k(s, top_k)
+    if norm_topk_prob:
+        g = g / jnp.sum(g, -1, keepdims=True)
+    return idx.astype(jnp.int32), g
+
+
+def shared_expert_gate(x, w):
+    """The shared expert's gate of a gated shared expert: ``sigmoid(x
+    w)`` a token, ``x [T, h]``, ``w [h, 1]``, in float32 (``[T, 1]``)."""
+    return jax.nn.sigmoid(jnp.dot(x.astype(F32), w.astype(F32),
+                                  precision="highest"))
 
 
 def buffer_rows(tokens, top_k, held, tm):
@@ -143,4 +166,5 @@ def dropless_experts(x, idx, gates, live, gate_w, up_w, down_w, *, first,
 
 
 __all__ = ["buffer_rows", "dispatch_plan", "dropless_experts",
-           "limit_to_groups", "route_sigmoid"]
+           "limit_to_groups", "route_sigmoid", "route_softmax",
+           "shared_expert_gate"]

@@ -376,6 +376,9 @@ class LLMEngine:
         #: latent layers (a token caches one compressed row every head
         #: reads): the pool is then a latent one, all layers of the kind
         self._latent = any(k.latent for k in self._kinds)
+        #: linear-attention layers (a row carries a recurrent state in a
+        #: slot of the pool, beside the other layers' pages)
+        self._linear = any(k.linear for k in self._kinds)
         if not self._plain_layers:
             refused = {
                 "quantized_mode": quantized_mode is not None,
@@ -401,6 +404,14 @@ class LLMEngine:
                 "prefix_store / fleet_prefix_cache / pinned_prefix_pages "
                 "(with latent layers: they move pages as (K, V) blocks)":
                     self._latent and (prefix_store is not None
+                                      or fleet_prefix_cache is not None
+                                      or bool(pinned_prefix_pages)),
+                "prefix_caching=True / prefix_store / fleet_prefix_cache / "
+                "pinned_prefix_pages (with linear layers: a shared prefix "
+                "would need the recurrent state at its end, which no slot "
+                "keeps)":
+                    self._linear and (bool(prefix_caching)
+                                      or prefix_store is not None
                                       or fleet_prefix_cache is not None
                                       or bool(pinned_prefix_pages)),
             }
@@ -508,6 +519,24 @@ class LLMEngine:
                 prefetch=bool(kv_prefetch),
                 prefetch_depth=self._kv_prefetch_depth,
                 spill_seed=kv_spill_seed)
+        elif self._linear:
+            # the full layers' pages, and a slot a row for the linear
+            # layers' state: every row slot can hold one, and slot 0 is
+            # the null slot pad rows name
+            conv = 2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim \
+                + cfg.linear_num_value_heads * cfg.linear_value_head_dim
+            self.pool = PagedKVPool(
+                cfg.num_hidden_layers, cfg.num_key_value_heads,
+                cfg.head_dim, num_pages=num_pages, page_size=page_size,
+                dtype=dtype, high_watermark=high_watermark,
+                low_watermark=low_watermark,
+                state_layers=[i for i, k in enumerate(self._kinds)
+                              if k.linear],
+                state_shape=(cfg.linear_num_value_heads,
+                             cfg.linear_key_head_dim,
+                             cfg.linear_value_head_dim),
+                conv_shape=(cfg.linear_conv_kernel_dim - 1, conv),
+                state_slots=max_num_seqs + 1)
         elif self._latent:
             # one compressed row a token a layer, one array a layer
             self.pool = PagedKVPool(
@@ -773,7 +802,8 @@ class LLMEngine:
         kinds = self._kinds
         operands = self._operands = StepOperands(
             T, R, PPS, K, window=bool(self.pool.window_layers),
-            adapters=self.adapters is not None)
+            adapters=self.adapters is not None,
+            slots=bool(self.pool.state_layers))
 
         def ragged_step(params, kv, kv_scales, ctl, draft_tokens,
                         draft_probs, base_key, adapters):
@@ -796,7 +826,8 @@ class LLMEngine:
             # carries slot_ids, the per-token slot ids: which adapter a
             # token wears is a gather — data, never shape.
             # tbls_w: the window page group's tables, in the buffer for
-            # a model with window layers.
+            # a model with window layers; slots: each row's state slot,
+            # for a model with linear layers.
             o = operands.unpack(ctl)
             tokens, positions, tbls = o["tokens"], o["positions"], o["tbls"]
             q_starts, q_lens, kv_lens = (o["q_starts"], o["q_lens"],
@@ -805,6 +836,7 @@ class LLMEngine:
             temps, top_ks, top_ps = o["temps"], o["top_ks"], o["top_ps"]
             seeds, sample_pos = o["seeds"], o["sample_pos"]
             adapter_slots, tbls_w = o.get("slot_ids"), o.get("tbls_w")
+            state_slots = o.get("slots")
             tok_row = live = pre = None
             moe_stats = []
             if prefill_fused:
@@ -848,7 +880,7 @@ class LLMEngine:
                     tbls_w if kind.window else tbls, tok_row, live,
                     q_starts, q_lens, kv_lens, cfg, ps, PPS, qb,
                     interpret, adapters=ad, slots=adapter_slots,
-                    kind=kind, moe_stats=moe_stats)
+                    kind=kind, moe_stats=moe_stats, state_slots=state_slots)
 
             def int8_layer(lyr, ad, h, Kp, Ks, Vp, Vs):
                 if prefill_fused:
@@ -953,6 +985,12 @@ class LLMEngine:
                         h, pages, _ = fp_layer(lyr, None, h, pages, None,
                                                kinds[li])
                         new_kv.append(pages)
+                        continue
+                    if kinds[li].linear:
+                        # a linear layer's slots: its state and its
+                        # convolution's inputs
+                        h, S, Cv = fp_layer(lyr, None, h, *pages, kinds[li])
+                        new_kv.append((S, Cv))
                         continue
                     Kp, Vp = pages
                     ad = adapters[li] if adapters is not None else None
@@ -2346,6 +2384,7 @@ class LLMEngine:
         temps, top_ks, top_ps = o["temps"], o["top_ks"], o["top_ps"]
         seeds, sample_pos = o["seeds"], o["sample_pos"]
         tbls_w, slot_ids = o.get("tbls_w"), o.get("slot_ids")
+        state_slots = o.get("slots")
         specs = plan.spec_lens
         prefill_tokens = 0
         for i, (seq, q_start, q_len) in enumerate(plan.rows):
@@ -2367,6 +2406,8 @@ class LLMEngine:
             tbls[i] = self.pool.padded_block_table(seq.seq_id, PPS)
             if tbls_w is not None:
                 tbls_w[i] = self.pool.padded_window_table(seq.seq_id, PPS)
+            if state_slots is not None:
+                state_slots[i] = self.pool.slot_of(seq.seq_id)
             q_starts[i] = q_start
             q_lens[i] = q_len
             kv_lens[i] = lo + q_len
@@ -2401,10 +2442,12 @@ class LLMEngine:
                 * self.pool.page_bytes)
         else:
             # by layer kind: the layers that see every key, and those
-            # that see a window (all of one width, LLMEngine.__init__)
+            # that see a window (all of one width, LLMEngine.__init__);
+            # linear layers see no keys
             window = self.pool.window
             n_win = len(self.pool.window_layers)
-            n_full = len(self._kinds) - n_win
+            n_lin = len(self.pool.state_layers)
+            n_full = len(self._kinds) - n_win - n_lin
 
             def walked(w):
                 return ragged_kv_tokens_read(
@@ -2424,7 +2467,14 @@ class LLMEngine:
                     attn_kv_tokens_read=(
                         (n_full and n_full * walked(None))
                         + (n_win and n_win * walked(window)))
-                    // len(self._kinds))
+                    // (len(self._kinds) - n_lin))
+            if n_lin:
+                # what the linear layers' kernel reads and writes: a
+                # state a live row and layer, and its tokens
+                sp.set(linear_rows=n_lin * int((q_lens > 0).sum()),
+                       linear_tokens=n_lin * int(q_lens.sum()),
+                       state_slots_used=self.pool.state_slots_used,
+                       state_slots=self.pool.state_capacity)
         sp.phase("serve.dispatch")
         if draft_tokens is None:
             # ordinary round: the prebuilt zero operands on the device

@@ -63,8 +63,22 @@ not care what a page holds; what moves page DATA in the (K, V) wire
 format (export, adopt, the prefix store, the host tier, a mesh, int8)
 is refused by name.
 
+State slots (``state_layers`` + ``state_shape`` + ``conv_shape`` +
+``state_slots``; a Gated DeltaNet layer): such a layer keeps no pages. A
+row holds ONE slot from admission to finish, cancel or preemption, and
+the slot holds the row's recurrent state ``state_shape`` (float32) and
+the last pre-convolution inputs ``conv_shape`` of every such layer, at
+the row's committed length and no other: ``allocate`` claims it, ``free``
+returns it, ``check_invariants`` counts it, and whatever sharing or
+rewinding would need the state at an earlier position (fork, pins,
+export / adopt, ``rollback``) is refused by name. Slot 0 is the null
+slot pad rows name. A row's slot is never read before the row has
+written it: the step starts a row whose chunk begins at position 0 from
+zeros (so a recomputed row starts afresh whatever its slot held).
+
 The device arrays themselves live in ``kv`` (one (K, V) pair per layer;
-one array per layer in a latent pool) and are updated *functionally* by the engine's jitted ragged step (the
+one array per layer in a latent pool; a (state, conv) pair of ``[slots,
+...]`` arrays per state layer) and are updated *functionally* by the engine's jitted ragged step (the
 engine reassigns ``kv`` after each donated call); this class tracks the
 host-side ownership metadata plus the eager CoW/scale-reset fixups.
 """
@@ -132,6 +146,8 @@ class InvariantViolation(AssertionError):
 
 
 NULL_PAGE = 0
+#: the state slot pad rows name (never held by a sequence)
+NULL_SLOT = 0
 
 
 class PagedKVPool:
@@ -146,9 +162,32 @@ class PagedKVPool:
                  page_size, dtype=jnp.float32, high_watermark=0.90,
                  low_watermark=0.50, pinned_page_budget=0, mesh=None,
                  window_layers=(), window=None, window_pages=None,
-                 latent_row=None):
+                 latent_row=None, state_layers=(), state_shape=None,
+                 conv_shape=None, state_slots=None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
+        #: layers that keep a recurrent state in slots and no pages
+        self.state_layers = tuple(sorted(int(i) for i in state_layers))
+        self.state_num_slots = 0
+        if self.state_layers:
+            if state_slots is None or int(state_slots) < 2:
+                raise ValueError("state_layers need state_slots >= 2 "
+                                 "(slot 0 is reserved)")
+            if state_shape is None or conv_shape is None:
+                raise ValueError("state_layers need a state_shape and a "
+                                 "conv_shape")
+            if window_layers or latent_row is not None or mesh is not None \
+                    or jnp.dtype(dtype) == jnp.dtype(jnp.int8):
+                raise ValueError(
+                    "a pool with state slots is single-device, unquantized "
+                    "and keeps (K, V) pages for its other layers: window "
+                    "layers, latent rows, a mesh and int8 pages are not "
+                    "carried beside recurrent state")
+            if self.state_layers[0] < 0 \
+                    or self.state_layers[-1] >= num_layers:
+                raise ValueError(f"state_layers {self.state_layers} outside "
+                                 f"0..{num_layers - 1}")
+            self.state_num_slots = int(state_slots)
         # the second page group: which layers' pages live in it, how
         # many keys a query of theirs sees, how many pages it has
         #: values a token holds a layer in a latent pool (the row as the
@@ -211,6 +250,11 @@ class PagedKVPool:
         self.quantized = self.dtype == jnp.dtype(jnp.int8)
         self.kv = []
         for i in range(num_layers):
+            if i in self.state_layers:
+                n = self.state_num_slots
+                self.kv.append((jnp.zeros((n, *state_shape), jnp.float32),
+                                jnp.zeros((n, *conv_shape), dtype)))
+                continue
             if self.latent_row is not None:
                 self.kv.append(jnp.zeros(
                     (num_pages, page_size, self.latent_row), dtype))
@@ -257,6 +301,9 @@ class PagedKVPool:
         #: window-pool page, NULL_PAGE where released or not yet claimed
         self._wfree = list(range(self.window_num_pages - 1, NULL_PAGE, -1))
         self._wtables: dict[object, list[int]] = {}
+        #: the state slots' free list and each live sequence's slot
+        self._sfree = list(range(self.state_num_slots - 1, NULL_SLOT, -1))
+        self._slots: dict[object, int] = {}
 
     # ---- byte accounting (pool sizing / bench fields) ----
     @staticmethod
@@ -291,7 +338,8 @@ class PagedKVPool:
             return self.num_layers * self.page_size * self.latent_row \
                 * self.dtype.itemsize
         return self.page_bytes_for(
-            self.num_layers - len(self.window_layers), self.num_kv_heads,
+            self.num_layers - len(self.window_layers)
+            - len(self.state_layers), self.num_kv_heads,
             self.head_dim, self.page_size, self.dtype)
 
     @property
@@ -309,9 +357,17 @@ class PagedKVPool:
         return self.page_bytes / self.page_size
 
     @property
+    def state_slot_bytes(self) -> int:
+        """Bytes of one state slot over the state layers: the recurrent
+        state and the convolution's inputs of each."""
+        return sum(self.kv[i][0][0].nbytes + self.kv[i][1][0].nbytes
+                   for i in self.state_layers)
+
+    @property
     def pool_bytes(self) -> int:
         return self.page_bytes * self.num_pages \
-            + self.window_page_bytes * self.window_num_pages
+            + self.window_page_bytes * self.window_num_pages \
+            + self.state_slot_bytes * self.state_num_slots
 
     @property
     def model_parallel_degree(self) -> int:
@@ -354,6 +410,19 @@ class PagedKVPool:
     @property
     def window_pages_used(self) -> int:
         return self.window_capacity - len(self._wfree)
+
+    @property
+    def state_capacity(self) -> int:
+        """Allocatable state slots (0 without state layers)."""
+        return max(self.state_num_slots - 1, 0)
+
+    @property
+    def state_slots_used(self) -> int:
+        return len(self._slots)
+
+    def slot_of(self, seq_id) -> int:
+        """The state slot ``seq_id`` holds."""
+        return self._slots[seq_id]
 
     @staticmethod
     def window_pages_per_row(window, chunk_tokens, page_size) -> int:
@@ -515,10 +584,15 @@ class PagedKVPool:
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already has an allocation")
         self._window_need(None, 0, num_tokens)     # raises if short
+        if self.state_layers and not self._sfree:
+            raise PoolExhausted(f"allocate {seq_id!r}: all "
+                                f"{self.state_capacity} state slots held")
         pages = self._claim(self.pages_for(num_tokens),
                             f"allocate {num_tokens} tokens")
         self._tables[seq_id] = pages
         self._lens[seq_id] = num_tokens
+        if self.state_layers:
+            self._slots[seq_id] = self._sfree.pop()
         if self.window_layers:
             self._wtables[seq_id] = []
             self._window_extend(seq_id, 0, num_tokens)
@@ -581,6 +655,11 @@ class PagedKVPool:
                 f"PagedKVPool.{what}: this pool has window layers, and "
                 f"page sharing, export and the host tier know one kind "
                 f"of page")
+        if self.state_layers:
+            raise ValueError(
+                f"PagedKVPool.{what}: this pool keeps recurrent state in "
+                f"slots, and sharing or moving a row's pages would need "
+                f"its state at that position, which no slot holds")
 
     def _window_first(self, pos: int) -> int:
         """Logical page of the first key the query at ``pos`` sees."""
@@ -702,6 +781,8 @@ class PagedKVPool:
         self._lens.pop(seq_id, None)
         self._wfree.extend(p for p in self._wtables.pop(seq_id, ())
                            if p != NULL_PAGE)
+        if seq_id in self._slots:
+            self._sfree.append(self._slots.pop(seq_id))
         return self._release_pages(pages)
 
     # ---- pinned prefix chains (LRU page cache over the pool) ----
@@ -1017,6 +1098,11 @@ class PagedKVPool:
                 f"rollback cannot grow {seq_id!r}: {cur} -> {new_len}")
         if new_len < 0:
             raise ValueError(f"negative rollback length {new_len}")
+        if self.state_layers and new_len < cur:
+            raise ValueError(
+                f"rollback of {seq_id!r} from {cur} to {new_len}: its state "
+                f"slot holds the recurrent state at {cur} only, and an "
+                f"earlier one is not kept")
         if self.window_layers and any(
                 p == NULL_PAGE for p in self._wtables[seq_id][
                     self._window_first(new_len):self.pages_for(new_len)]):
@@ -1053,6 +1139,8 @@ class PagedKVPool:
             "offending_pages": sorted(set(offending_pages)),
             "window_used_pages": self.window_pages_used,
             "window_free_list_size": len(self._wfree),
+            "state_slots_used": self.state_slots_used,
+            "state_free_list_size": len(self._sfree),
         }
 
     def _invariant_fail(self, reason, pages=()):
@@ -1151,7 +1239,30 @@ class PagedKVPool:
                  f"mapped pages")
         if self.window_layers:
             self._check_window_invariants()
+        if self.state_layers:
+            self._check_state_invariants()
         return True
+
+    def _check_state_invariants(self):
+        """The state slots: every live sequence holds exactly one, no two
+        hold the same, none holds the null slot, a held slot is not
+        free; held + free == capacity."""
+        fail = self._invariant_fail
+        if set(self._slots) != set(self._tables):
+            fail("state slots and page tables name different sequences")
+        held = list(self._slots.values())
+        if len(set(held)) != len(held):
+            fail(f"a state slot is held twice: {sorted(held)}")
+        free = set(self._sfree)
+        if len(free) != len(self._sfree):
+            fail("state slot free list has duplicates")
+        if NULL_SLOT in free or NULL_SLOT in held:
+            fail("null state slot held or free")
+        if free & set(held):
+            fail(f"state slots both held and free: {sorted(free & set(held))}")
+        if len(held) + len(free) != self.state_capacity:
+            fail(f"state slot leak: {len(held)} held + {len(free)} free != "
+                 f"capacity {self.state_capacity}")
 
     def _check_window_invariants(self):
         """The window group: a page is held by one row or free, never
@@ -1188,4 +1299,4 @@ class PagedKVPool:
 
 
 __all__ = ["InvariantViolation", "PagedKVPool", "PoolExhausted",
-           "NULL_PAGE"]
+           "NULL_PAGE", "NULL_SLOT"]

@@ -98,12 +98,14 @@ class StepOperands:
     packed tokens, ``R`` row slots, ``PPS``-wide tables, ``K`` draft
     tokens) and from which optional operands exist (``window``: the
     window page group's tables ``tbls_w``; ``adapters``: the per-token
-    LoRA ``slot_ids``). Every operand is a named static slice; the float
+    LoRA ``slot_ids``; ``slots``: each row's state slot, for linear
+    layers). Every operand is a named static slice; the float
     ones travel as their own bits (an ``np.float32`` view on the host,
     ``bitcast_convert_type`` in the graph). ``host()`` and ``unpack()``
     are the two readings of the same slices."""
 
-    def __init__(self, T, R, PPS, K, *, window=False, adapters=False):
+    def __init__(self, T, R, PPS, K, *, window=False, adapters=False,
+                 slots=False):
         i32, f32 = np.int32, np.float32
         # name, shape, dtype, what a pad row reads
         fields = [("tokens", (T,), i32, 0), ("positions", (T,), i32, 0),
@@ -118,6 +120,8 @@ class StepOperands:
             fields.append(("tbls_w", (R, PPS), i32, NULL_PAGE))
         if adapters:
             fields.append(("slot_ids", (T,), i32, 0))
+        if slots:
+            fields.append(("slots", (R,), i32, 0))     # pad rows: null slot
         self.R, self.K = R, K
         self.fields, lo = {}, 0
         for name, shape, dtype, _ in fields:
@@ -166,7 +170,7 @@ class StepOperands:
 def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                      q_starts, q_lens, kv_lens, cfg, page_size, max_pages,
                      q_block, interpret, *, adapters=None, slots=None,
-                     kind=LayerKind(), moe_stats=None):
+                     kind=LayerKind(), moe_stats=None, state_slots=None):
     """One fp decoder layer of the ragged forward: qkv proj -> rope ->
     page append -> ragged attention -> o proj -> mlp. Returns
     ``(h, Kp, Vp)``.
@@ -178,8 +182,11 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     feed-forward (``nn/moe_dropless.py``; a routed layer appends its
     ``[3]`` counts to the list ``moe_stats``), or latent attention
     (:func:`_latent_attention`: ``Kp`` is then the layer's ONE pool
-    array, ``Vp`` None, and None comes back in its place). Layers of
-    every kind trace into the one executable.
+    array, ``Vp`` None, and None comes back in its place), an output
+    gate and rotary on part of each head, or linear attention
+    (:func:`_linear_attention`: ``Kp``, ``Vp`` are then the layer's
+    state and convolution slots, ``state_slots [R]`` each row's slot).
+    Layers of every kind trace into the one executable.
 
     This is THE fp layer body — the engine's ragged step (fp pools) and
     the draft worker's forward both call it, so draft/target numerics
@@ -217,6 +224,11 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                 * _wmat(x, lyr["up"], lora=lo("up")),
                 lyr["down"], lora=lo("down"))
 
+    if kind.linear:
+        h, Kp, Vp = _linear_attention(lyr, h, Kp, Vp, state_slots, tok_row,
+                                      live, q_starts, q_lens, kv_lens, cfg,
+                                      q_block, interpret)
+        return feed_forward(h), Kp, Vp
     with phases.phase("attn.core"):
         slot = _page_slots(positions, tbls, tok_row, live, ps, max_pages)
     if kind.latent:
@@ -230,13 +242,21 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
     with phases.phase("norm"):
         x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)
     with phases.phase("attn.qkv"):
-        q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
+        if kind.gate:
+            # each head's query and gate side by side
+            q = _wmat(x, lyr["q"]).reshape(1, T, H, 2 * d)
+            q, gate = q[..., :d], q[..., d:]
+        else:
+            q = _wmat(x, lyr["q"], lora=lo("q")).reshape(1, T, H, d)
         k = _wmat(x, lyr["k"], lora=lo("k")).reshape(1, T, Hkv, d)
         v = _wmat(x, lyr["v"], lora=lo("v")).reshape(1, T, Hkv, d)
         if kind.qk_norm:
             q = _rms_norm(q, lyr["q_norm"], cfg.rms_norm_eps)
             k = _rms_norm(k, lyr["k_norm"], cfg.rms_norm_eps)
-        if kind.rope:
+        if kind.rope and kind.rotary_dim is not None:
+            q, k = (_rope_part(x, positions, cfg.rope_theta, kind.rotary_dim)
+                    for x in (q, k))
+        elif kind.rope:
             q = _rope(q, positions[None], cfg.rope_theta, d)
             k = _rope(k, positions[None], cfg.rope_theta, d)
     with phases.phase("attn.core"):
@@ -248,8 +268,94 @@ def _ragged_fp_layer(lyr, h, Kp, Vp, positions, tbls, tok_row, live,
                                    kv_lens, q_block=q_block,
                                    interpret=interpret, window=kind.window)
     with phases.phase("attn.out"):
+        if kind.gate:
+            o = (o.astype(jnp.float32)
+                 * jax.nn.sigmoid(gate[0].astype(jnp.float32))).astype(h.dtype)
         h = h + _wmat(o.reshape(1, T, H * d), lyr["o"], lora=lo("o"))
     return feed_forward(h), Kp, Vp
+
+
+def _rope_part(x, positions, theta, rotary_dim):
+    """Rotary on the first ``rotary_dim`` dims of each head of ``x [1, T,
+    heads, d]``, the rest as they are."""
+    rot = _rope(x[..., :rotary_dim], positions[None], theta, rotary_dim)
+    return jnp.concatenate([rot, x[..., rotary_dim:]], -1)
+
+
+def _linear_attention(lyr, h, S, Cv, state_slots, tok_row, live, q_starts,
+                      q_lens, kv_lens, cfg, q_block, interpret):
+    """The mixer of a LINEAR (Gated DeltaNet) layer, ``models/qwen3_next.py``
+    writing its equations down: the in-projections in the published
+    column layout (a key head's q, k, its value heads' v and z; its value
+    heads' b and a), the causal depthwise convolution over q, k and v
+    (``taps`` wide, the row's last ``taps - 1`` inputs before this step
+    kept in its slot ``Cv``), SiLU, L2-normalised q and k, ``beta =
+    sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``; the
+    recurrence over each row from its state slot ``S``
+    (``kernels/gated_delta.py``); the gated norm ``w RMSNorm(o)
+    silu(z)`` a head; ``out_proj``. A row whose chunk starts at position
+    0 starts from a zero state and zero inputs before it. Returns ``(h,
+    S, Cv)``."""
+    from ..kernels.gated_delta import ragged_gated_delta
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    rep, T = hv // hk, h.shape[1]
+    taps = Cv.shape[1] + 1
+    f32 = jnp.float32
+    with phases.phase("norm"):
+        x = _rms_norm(h, lyr["ln1"], cfg.rms_norm_eps)[0]
+    with phases.phase("linear.proj"):
+        qkvz = _wmat(x, lyr["in_qkvz"]).reshape(T, hk, 2 * dk + 2 * rep * dv)
+        ba = _wmat(x, lyr["in_ba"]).reshape(T, hk, 2 * rep)
+        z = qkvz[..., 2 * dk + rep * dv:].reshape(T, hv, dv)
+        mixed = jnp.concatenate(
+            [qkvz[..., :dk].reshape(T, -1), qkvz[..., dk:2 * dk].reshape(T, -1),
+             qkvz[..., 2 * dk:2 * dk + rep * dv].reshape(T, -1)], -1)
+        fresh = (kv_lens - q_lens) == 0                   # [R]
+        tail = jnp.where(fresh[:, None, None], 0, Cv[state_slots])
+        # a token's inputs j back: this step's where the row has them,
+        # the slot's tail for its first j tokens
+        off = jnp.arange(T) - q_starts[tok_row]
+        w = lyr["conv"].astype(f32)                       # [taps, channels]
+        acc = mixed.astype(f32) * w[taps - 1]
+        for j in range(1, taps):
+            back = jnp.where((off >= j)[:, None],
+                             jnp.pad(mixed, ((j, 0), (0, 0)))[:T], 0)
+            first = q_starts[:, None] + jnp.arange(j)     # [R, j]
+            back = back.at[first].set(tail[:, taps - 1 - j:taps - 1],
+                                      mode="drop")
+            acc = acc + back.astype(f32) * w[taps - 1 - j]
+        conv = jax.nn.silu(acc)
+        # the slot keeps the row's last taps - 1 inputs for its next step
+        last = q_lens[:, None] - (taps - 1) + jnp.arange(taps - 1)
+        keep = jnp.where(
+            (last >= 0)[..., None],
+            mixed[jnp.clip(q_starts[:, None] + last, 0, T - 1)],
+            tail[jnp.arange(tail.shape[0])[:, None],
+                 jnp.clip(last + taps - 1, 0, taps - 2)])
+        Cv = Cv.at[state_slots].set(keep.astype(Cv.dtype))
+
+        def unit(y):
+            return y * jax.lax.rsqrt(jnp.sum(y * y, -1, keepdims=True) + 1e-6)
+        q = unit(conv[:, :hk * dk].reshape(T, hk, dk)) * dk ** -0.5
+        k = unit(conv[:, hk * dk:2 * hk * dk].reshape(T, hk, dk))
+        v = conv[:, 2 * hk * dk:].reshape(T, hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :rep].reshape(T, hv).astype(f32))
+        g = -jnp.exp(lyr["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., rep:].reshape(T, hv).astype(f32)
+            + lyr["dt_bias"].astype(f32))
+    with phases.phase("linear.scan"):
+        o, S = ragged_gated_delta(q, k, v, g, beta, S, state_slots, q_starts,
+                                  q_lens, fresh, q_block=q_block,
+                                  interpret=interpret)
+    with phases.phase("linear.out"):
+        o = jnp.where(live[:, None, None], o, 0.0)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        o = o * lyr["out_norm"].astype(f32) * jax.nn.silu(z.astype(f32))
+        h = h + _wmat(o.astype(h.dtype).reshape(1, T, hv * dv),
+                      lyr["out_proj"])
+    return h, S, Cv
 
 
 def _page_slots(positions, tbls, tok_row, live, page_size, max_pages):
@@ -313,13 +419,20 @@ def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
     step: every live token's picks among ALL the router's experts, the
     part of their sum whose experts this model holds, and the shared
     expert. ``x [1, T, hidden]``."""
-    from ..nn.moe_dropless import dropless_experts, route_sigmoid
-    idx, gates = route_sigmoid(
-        x[0], lyr["router"], lyr["router_bias"],
-        top_k=cfg.num_experts_per_tok, scaling=cfg.routed_scaling_factor,
-        norm_topk_prob=cfg.norm_topk_prob,
-        n_group=getattr(cfg, "n_group", 1),
-        topk_group=getattr(cfg, "topk_group", 1))
+    from ..nn.moe_dropless import (dropless_experts, route_sigmoid,
+                                   route_softmax, shared_expert_gate)
+    if getattr(cfg, "router_scoring", "sigmoid") == "softmax":
+        idx, gates = route_softmax(x[0], lyr["router"],
+                                   top_k=cfg.num_experts_per_tok,
+                                   norm_topk_prob=cfg.norm_topk_prob)
+    else:
+        idx, gates = route_sigmoid(
+            x[0], lyr["router"], lyr["router_bias"],
+            top_k=cfg.num_experts_per_tok,
+            scaling=cfg.routed_scaling_factor,
+            norm_topk_prob=cfg.norm_topk_prob,
+            n_group=getattr(cfg, "n_group", 1),
+            topk_group=getattr(cfg, "topk_group", 1))
     y, stats = dropless_experts(
         x[0], idx, gates, live, lyr["experts_gate"], lyr["experts_up"],
         lyr["experts_down"], first=cfg.expert_offset, interpret=interpret)
@@ -328,6 +441,9 @@ def _routed_mlp(lyr, x, live, cfg, interpret, moe_stats):
     with phases.phase("mlp"):
         shared = _wmat(jax.nn.silu(_wmat(x, lyr["shared_gate"]))
                        * _wmat(x, lyr["shared_up"]), lyr["shared_down"])
+        if "shared_expert_gate" in lyr:
+            shared = (shared_expert_gate(x[0], lyr["shared_expert_gate"])
+                      * shared.astype(jnp.float32)).astype(shared.dtype)
     with phases.phase("moe.combine"):
         return y[None] + shared
 

@@ -254,6 +254,23 @@ def _latent(T, row, *, h=128, R=32, pages=16384, pps=1040, append=False):
                 _s((R,), i32), _s((R,), i32)), 2 if append else 1
 
 
+def _gated_delta(T=768, R=64, slots=65, h=32, hk=16, d=128):
+    """qwen3-next-80b-a3b.long-chat's linear layers: the recurrence over a
+    step of 768 token slots (64 rows x q blocks of 8 + a 256-token chunk)
+    for 32 value heads over 16 key heads of 128, from 65 float32 state
+    slots."""
+    from paddle_tpu.kernels.gated_delta import ragged_gated_delta
+    f32, i32 = jnp.float32, jnp.int32
+
+    def fn(q, k, v, g, b, state, sl, qs, ql, fr):
+        return ragged_gated_delta(q, k, v, g, b, state, sl, qs, ql, fr,
+                                  q_block=8)
+    return fn, (_s((T, hk, d), f32), _s((T, hk, d), f32), _s((T, h, d), f32),
+                _s((T, h), f32), _s((T, h), f32), _s((slots, h, d, d), f32),
+                _s((R,), i32), _s((R,), i32), _s((R,), i32),
+                _s((R,), i32)), 1
+
+
 CASES = {
     "flash_fwd_d64": lambda: _flash(DH, H, bwd=False),
     "flash_fwd_bwd_d64": lambda: _flash(DH, H, bwd=True),
@@ -291,6 +308,17 @@ CASES = {
     "grouped_matmul_dotsvlm1_down": lambda: _grouped(2048, 7168),
     "grouped_matmul_kexaone_up": lambda: _grouped(6144, 2048),
     "grouped_matmul_kexaone_down": lambda: _grouped(2048, 6144),
+    # the geometry of qwen3-next-80b-a3b.long-chat: the gated-delta
+    # recurrence over state slots, its three full layers' attention at
+    # head_dim 256 with 8 q heads a kv head (64 rows x 2,176 page slots
+    # over 32,768 pages), its routed experts at hidden 2048, width 512
+    "gated_delta_t768_h32_d128_qwen3next": _gated_delta,
+    "ragged_t768_qb8_bf16_qwen3next_full": lambda: _ragged(
+        768, 8, False, h=16, hkv=2, dh=256, R=64, pages=32768, pps=2176),
+    "grouped_matmul_qwen3next_up": lambda: _grouped(
+        2048, 512, held=32, rows=768 * 10 + 32 * 128),
+    "grouped_matmul_qwen3next_down": lambda: _grouped(
+        512, 2048, held=32, rows=768 * 10 + 32 * 128),
     "fused_adamw_f32": lambda: _adamw(11_534_336, jnp.float32),
     "fused_adamw_bf16": lambda: _adamw(11_534_336, jnp.bfloat16),
     "dequant_matmul_int8": lambda: _dequant(8),
