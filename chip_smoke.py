@@ -12,6 +12,11 @@ user calls, at TinyLlama-1.1B widths (vocab 32000, hidden 2048, ffn
   dots.vlm1.inst's head sizes (128 heads against rows of 512 + 64 values
   held in 640 lanes), a few pages, decode rows and a chunk in one launch,
   behind ``kv_append``; against its ``jnp`` reference.
+- ragged: ``kernels/paged_attention.py::ragged_paged_attention`` alone at
+  Qwen3-Next's full layers (2 kv heads x 8 q heads of 256: a 256-token
+  chunk behind 5 k tokens, walked a q tile at a time, beside decode
+  rows) and at Mistral-7B's (8 kv heads x 4 of 128: a 64-token chunk
+  behind 1 k, decode rows); against its ``jnp`` reference.
 - gated delta: ``kernels/gated_delta.py::ragged_gated_delta`` alone at
   Qwen3-Next's linear heads (32 value heads over 16 key heads of 128),
   decode rows and a 200-token chunk in one launch from float32 state
@@ -63,6 +68,13 @@ SERVE = dict(layers=(22, 2), max_len=(1024, 128),
 # the latent kernel: heads, row width held, value width, page slots a row
 LATENT = dict(heads=(128, 4), row=(640, 128), width=(576, 40),
               v_width=(512, 32), pages=(64, 24))
+# the ragged kernel: (kv heads, q heads a kv head, head size, chunk
+# tokens, context before the chunk, decode rows) a geometry
+RAGGED = dict(geometries=(
+    (("qwen3-next", 2, 8, 256, 256, 5120, 22),
+     ("mistral-7b", 8, 4, 128, 64, 1024, 24)),
+    (("qwen3-next", 2, 8, 32, 136, 300, 3),
+     ("mistral-7b", 2, 4, 32, 40, 100, 3))))
 # the gated-delta kernel: value heads, key heads, head size, chunk tokens
 GATED_DELTA = dict(heads=(32, 4), key_heads=(16, 2), dim=(128, 16),
                    chunk=(200, 20))
@@ -284,6 +296,58 @@ def latent_leg(on_chip, rehearse):
     print(json.dumps({"leg": "latent", "heads": h, "row": row,
                       "width": width, "tokens": int(q_lens.sum()),
                       "max_abs_err": err, "limit": tol}), flush=True)
+
+
+def ragged_leg(on_chip, rehearse):
+    """The ragged paged-attention kernel at two published geometries:
+    decode rows at contexts up to the chunk's, a long chunk walked a q
+    tile at a time behind its prefix, and a pad row, in one launch."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import (
+        ragged_kv_walks, ragged_paged_attention,
+        ragged_paged_attention_reference, ragged_q_tile)
+    ps, qb = 16, 8
+    dt = jnp.bfloat16 if on_chip else jnp.float32
+    for name, hkv, group, d, chunk, prefix, decode in pick(
+            RAGGED, "geometries", rehearse):
+        rng = np.random.default_rng(2)
+        q_lens = np.array([chunk] + [1] * decode + [0], np.int32)
+        kv_lens = np.concatenate([
+            [prefix + chunk], rng.integers(1, prefix + chunk, decode),
+            [0]]).astype(np.int32)
+        slots = -(-q_lens // qb) * qb
+        t = int(slots.sum()) + qb
+        q_starts = np.where(q_lens > 0, np.cumsum(slots) - slots, t) \
+            .astype(np.int32)
+        pps = -(-int(kv_lens.max()) // ps)
+        n_pages = len(q_lens) * pps + 1
+        tbl = (rng.permutation(n_pages - 1)[:len(q_lens) * pps] + 1) \
+            .reshape(len(q_lens), pps).astype(np.int32)
+        q = jnp.asarray(rng.standard_normal((t, hkv * group, d)), dt)
+        kp, vp = (jnp.asarray(rng.standard_normal((hkv, n_pages, ps, d)), dt)
+                  for _ in range(2))
+        args = (q, kp, vp, jnp.asarray(tbl), jnp.asarray(q_starts),
+                jnp.asarray(q_lens), jnp.asarray(kv_lens))
+        got = ragged_paged_attention(*args, q_block=qb,
+                                     interpret=not on_chip)
+        want = ragged_paged_attention_reference(*args)
+        live = np.zeros((t,), bool)
+        for s0, ql in zip(q_starts, q_lens):
+            live[s0:s0 + ql] = True
+        check(bool(jnp.isfinite(got).all()), f"ragged ({name}): non-finite")
+        err = float(jnp.abs(got.astype(jnp.float32) - want)[live].max())
+        # bf16 queries and pages, f32 in the kernel and in the oracle:
+        # outputs are means of N(0, 1) rows, sized ~0.01-1
+        tol = 3e-2 if on_chip else 1e-4
+        check(err <= tol, f"ragged ({name}): kernel differs from its "
+                          f"reference by {err:.4f} (limit {tol})")
+        tile = ragged_q_tile(qb, group, t)
+        print(json.dumps({"leg": "ragged", "geometry": name, "kv_heads": hkv,
+                          "group": group, "dim": d, "chunk": chunk,
+                          "prefix": prefix, "decode_rows": decode,
+                          "q_tile": tile, "walks": ragged_kv_walks(
+                              q_lens, q_block=qb, q_tile=tile),
+                          "max_abs_err": err, "limit": tol}), flush=True)
 
 
 def gated_delta_leg(on_chip, rehearse):
@@ -530,6 +594,7 @@ def main(argv=None):
         serve_leg(paddle, dev, on_chip, args.rehearse)
         gc.collect()
         latent_leg(on_chip, args.rehearse)
+        ragged_leg(on_chip, args.rehearse)
         gated_delta_leg(on_chip, args.rehearse)
         train_leg(paddle, dev, on_chip, args.rehearse)
 

@@ -51,8 +51,21 @@ gains ``j > p - W``. ``window=None`` lowers what it lowered before.
 GQA: each q block's ``[q_block * group, head_dim]`` rows ride one MXU
 matmul per slab and head; decode rows waste ``q_block - 1`` of those
 rows to padding, which is free in practice — the MXU tile is 128 rows.
-A chunk's prefix is walked once a q block (``ragged_kv_tokens_read``
-counts it): a q tile as wide as the chunk is the follow-on.
+
+A row with more than ``q_block`` tokens this launch (a prefill chunk)
+is walked a Q TILE at a time, not a q block at a time: the block that
+starts a tile (``ragged_q_tile`` tokens, as many as fill
+``_TILE_ROWS`` rows of one kv head's product) copies the tile's queries
+in from HBM itself, walks the row's KV ONCE, from the slab that holds
+the first key the tile's first query may see to its last query's
+causal horizon, and leaves the tile's output in VMEM; the tile's other
+blocks copy their rows of it out. A row's tiles start at its slot's
+start, so they follow the packing and no fixed grid. Each query row's
+softmax runs over the same slabs in the same order with the same
+operands: a slab outside a row's own range is wholly masked for it and
+adds exactly 0. The choice is the row's ``q_len``, read in the kernel:
+one program for decode rows, speculative rounds and chunks alike
+(``ragged_kv_tokens_read`` and ``ragged_kv_walks`` count both walks).
 
 int8 pools (``k_scales``/``v_scales`` per (head, page)) dequantize each
 fetched page in-kernel with scales read off the scalar-prefetch channel
@@ -73,6 +86,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _SLAB_TOKENS = 256
+# query rows (tokens x q heads of one kv head) a q tile's product fills:
+# four passes of the 128-row MXU tile (256 and 1,024 rows were no faster
+# on a v5e: PERF.md section 6)
+_TILE_ROWS = 512
 
 
 def ragged_slab_pages(page_size, pages_per_seq):
@@ -83,24 +100,52 @@ def ragged_slab_pages(page_size, pages_per_seq):
     return min(max(1, _SLAB_TOKENS // page_size), pages_per_seq)
 
 
-def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, page_size,
+def ragged_q_tile(q_block, group, total_q_tokens):
+    """Query tokens the ragged kernel's wide walk takes at once, from the
+    geometry alone: as many as fill ``_TILE_ROWS`` rows of one kv head's
+    product at ``group`` q heads a kv head (64 at group 8, 128 at group
+    4), a multiple of ``q_block``, at least one q block and at most the
+    launch. Equal to ``q_block``: no wide walk."""
+    tile = max(q_block, _TILE_ROWS // group // q_block * q_block)
+    return min(tile, total_q_tokens)
+
+
+def _ragged_walks(q_lens, q_block, q_tile):
+    """Every walk a launch with these (numpy) row lengths makes, as
+    ``(row, offset of its first query in the row's chunk, width)``: a
+    row of at most ``q_block`` tokens one q block a walk, a longer one
+    one ``q_tile`` a walk (its last tile as wide as what is left)."""
+    q_lens = np.asarray(q_lens, np.int64)
+    unit = np.where(q_lens > q_block, q_tile, q_block)
+    walks = -(-q_lens // unit)                   # a pad row: none
+    row = np.repeat(np.arange(len(q_lens)), walks)
+    first = np.cumsum(walks) - walks             # a row's first walk
+    off = (np.arange(len(row)) - first[row]) * unit[row]
+    return row, off, unit[row]
+
+
+def ragged_kv_walks(q_lens, *, q_block, q_tile):
+    """Walks over its KV one ragged call makes, a kv head: the live q
+    blocks of the rows of at most ``q_block`` tokens and the q tiles of
+    the longer ones."""
+    return len(_ragged_walks(q_lens, q_block, q_tile)[0])
+
+
+def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, q_tile, page_size,
                           pages_per_seq, window=None):
     """KV tokens ONE kv head's walk covers for a launch with these
-    (numpy) row lengths: over the live q blocks, each block's causal
-    horizon rounded up to the slab, less (with a ``window``) the whole
-    slabs below the first key the block's first query may see. The
-    roofline's floor counts a live token once; this counts it once a q
-    block that sees it (a 64-token chunk at ``q_block`` 8 walks its
-    prefix 8 times)."""
-    q_lens = np.asarray(q_lens, np.int64)
+    (numpy) row lengths: over the walks (:func:`ragged_kv_walks`), each
+    one's causal horizon rounded up to the slab, less (with a
+    ``window``) the whole slabs below the first key its first query may
+    see. The roofline's floor counts a live token once; this counts it
+    once a walk that sees it (a 256-token chunk at a ``q_tile`` of 64
+    walks its prefix 4 times, at a ``q_block`` of 8 it did 32)."""
     kv_lens = np.asarray(kv_lens, np.int64)
+    q_lens = np.asarray(q_lens, np.int64)
+    row, off, width = _ragged_walks(q_lens, q_block, q_tile)
     slab = ragged_slab_pages(page_size, pages_per_seq) * page_size
-    blocks = -(-q_lens // q_block)               # live q blocks a row
-    row = np.repeat(np.arange(len(q_lens)), blocks)
-    first = np.cumsum(blocks) - blocks           # a row's first block
-    off = (np.arange(len(row)) - first[row]) * q_block
-    start = kv_lens[row] - q_lens[row] + off     # the block's first query
-    horizon = np.minimum(kv_lens[row], start + q_block)
+    start = kv_lens[row] - q_lens[row] + off     # the walk's first query
+    horizon = np.minimum(kv_lens[row], start + width)
     walked = -(-horizon // slab)
     if window is not None:
         walked = walked - np.maximum(start - window + 1, 0) // slab
@@ -108,14 +153,14 @@ def ragged_kv_tokens_read(q_lens, kv_lens, *, q_block, page_size,
 
 
 def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
-                   page_size, q_block, scale, quantized, window):
+                   page_size, q_block, q_tile, scale, quantized, window):
     ks_ref = vs_ref = None
     if quantized:
         # int8 pool: the per-(head, page) dequant scales ride the
         # scalar-prefetch channel (SMEM) as operands 5 and 6
         ks_ref, vs_ref, *refs = refs
-    (q_ref, k_hbm, v_hbm, o_ref,
-     k_buf, v_buf, sem, m_ref, l_ref, acc_ref) = refs
+    (q_ref, q_hbm, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, m_ref, l_ref,
+     acc_ref, *tile_refs) = refs
     g = pl.program_id(0)          # q block
 
     row = row_ref[g]
@@ -123,29 +168,40 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
     kv_len = kl_ref[row]
     kv_start = kv_len - q_len     # absolute position of the chunk's token 0
     blk_off = g * q_block - qs_ref[row]   # this block's offset in the chunk
+    live_block = (blk_off >= 0) & (blk_off < q_len)
 
-    qb, hkv, grp, d = q_ref.shape
+    _, hkv, grp, d = q_ref.shape
     ppf = k_buf.shape[1]          # pages a fetch
     slab = ppf * page_size        # KV tokens a fetch
 
-    # causal horizon of the block's LAST live token: KV past it holds
-    # nothing any of this block's queries may see, so the walk ends
-    # there (early prefill chunks read only their causal prefix); a
-    # block outside every live slot walks nothing
-    live_block = (blk_off >= 0) & (blk_off < q_len)
-    horizon = jnp.where(
-        live_block, jnp.minimum(kv_len, kv_start + blk_off + q_block), 0)
+    if tile_refs:
+        qt_buf, ot_buf, q_sem = tile_refs
+        # a row of more than q_block tokens is walked a q tile at a time:
+        # the block that starts a tile walks for all of it, the tile's
+        # other blocks walk nothing
+        wide = live_block & (q_len > q_block)
+        walks = jnp.logical_not(wide) | (blk_off % q_tile == 0)
+        nq = jnp.where(wide, q_tile, q_block)
+    else:                         # no row is wider than a q block
+        wide, walks, nq = False, True, q_block
+
+    # causal horizon of the walk's LAST query: KV past it holds nothing
+    # any of its queries may see, so the walk ends there (early prefill
+    # chunks read only their causal prefix); a block outside every live
+    # slot walks nothing
+    horizon = jnp.where(live_block & walks,
+                        jnp.minimum(kv_len, kv_start + blk_off + nq), 0)
     n_pages = pl.cdiv(horizon, page_size)
     n_slabs = pl.cdiv(horizon, slab)
     if window is None:
         first_key = first_page = first_slab = 0
     else:
         # a window layer: a query at position p sees keys j with
-        # p - window < j <= p, so nothing below the block's FIRST
-        # query's window is seen by any of its queries. The walk starts
-        # at the slab that holds that key; pages under it were released
-        # by the pool (their table slots name the null page) and are
-        # not fetched
+        # p - window < j <= p, so nothing below the walk's FIRST query's
+        # window is seen by any of its queries. The walk starts at the
+        # slab that holds that key; pages under it were released by the
+        # pool (their table slots name the null page) and are not
+        # fetched
         first_key = jnp.maximum(kv_start + blk_off - window + 1, 0)
         first_page = first_key // page_size
         first_slab = first_page // ppf
@@ -153,7 +209,7 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
     def each_live_page(i, slot, op):
         """``op`` on the K and V copy of every live page of slab ``i``:
         one strided DMA a page brings all kv heads of it; pages of the
-        last slab past the block's last live page are not fetched, nor
+        last slab past the walk's last live page are not fetched, nor
         those of the first under a window's first page."""
         for j in range(ppf):
             p = i * ppf + j
@@ -169,13 +225,118 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
                 op(pltpu.make_async_copy(
                     v_hbm.at[:, page], v_buf.at[slot, j], sem.at[1, slot]))
 
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    def tile_queries(op):
+        """``op`` on the copies that bring a tile's queries in, a q block
+        at a time: the tile ends with the row's slot, which the next
+        row's may follow at any q block."""
+        n_in = jnp.minimum(q_tile, pl.cdiv(q_len, q_block) * q_block
+                           - blk_off) // q_block
+
+        def _one(j, carry):
+            op(pltpu.make_async_copy(
+                q_hbm.at[pl.ds((g + j) * q_block, q_block)],
+                qt_buf.at[pl.ds(j * q_block, q_block)], q_sem.at[0]))
+            return carry
+
+        jax.lax.fori_loop(0, n_in, _one, None)
+
+    def start(n):
+        """The softmax state of ``n`` (static) query tokens' rows."""
+        rows = n * grp
+        m_ref[:, :rows] = jnp.full((hkv, rows, 1), _NEG_INF, jnp.float32)
+        l_ref[:, :rows] = jnp.zeros((hkv, rows, 1), jnp.float32)
+        acc_ref[:, :rows] = jnp.zeros((hkv, rows, d), jnp.float32)
+
+    def normalized(h, n):
+        return (acc_ref[h, :n * grp] / jnp.maximum(l_ref[h, :n * grp], 1e-30)
+                ).reshape(n, grp, d)
+
+    def multiply(i, slot, n, q_of):
+        """Slab ``i`` against the walk's ``n`` (static: a q block or a q
+        tile) query tokens, ``q_of(h)`` their ``[n, grp, d]`` of kv head
+        ``h``: each head's online-softmax state in rows ``:n * grp``."""
+        rows = n * grp
+        base = i * slab
+        # per-token causal mask, the same for every head: token t of the
+        # chunk (absolute position kv_start + t) sees kv positions <= its
+        # own; tokens past q_len (slot padding) are masked out entirely
+        tok = blk_off + jax.lax.broadcasted_iota(jnp.int32, (n, grp, slab), 0)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (n, grp, slab), 2)
+        ok = (tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)
+        if window is not None:
+            ok &= pos > kv_start + tok - window
+        ok = ok.reshape(rows, slab)
+        # rows of V past the horizon are pool positions none of these
+        # queries may see, or slab pages that were not fetched: their
+        # weight is exactly 0, and 0 x what lies there must stay 0
+        vpos = base + jax.lax.broadcasted_iota(jnp.int32, (slab, d), 0)
+        seen = vpos < horizon
+        if window is not None:
+            seen &= vpos >= first_key
+
+        def slab_of(buf, s_ref, h):
+            """Head ``h`` of the slab as f32 ``[slab, d]``; an int8 page
+            is dequantized with its own per-(head, page) scale, a scalar
+            off SMEM indexed by the pool page the DMA read (a page that
+            was not fetched takes the last live page's: its columns are
+            masked)."""
+            pages = [buf[slot, j, h].astype(jnp.float32) for j in range(ppf)]
+            if quantized:
+                pages = [x * s_ref[h, tbl_ref[row, jnp.minimum(
+                    i * ppf + j, n_pages - 1)]]
+                    for j, x in enumerate(pages)]
+            return jnp.concatenate(pages, axis=0)
+
+        def _head(h, carry):
+            q = q_of(h).reshape(rows, d).astype(jnp.float32)
+            k = slab_of(k_buf, ks_ref, h)
+            v = jnp.where(seen, slab_of(v_buf, vs_ref, h), 0.0)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, _NEG_INF)               # [rows, slab]
+            m_prev = m_ref[h, :rows]                     # [rows, 1]
+            l_prev = l_ref[h, :rows]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            e = jnp.exp(s - m_new)                       # [rows, slab]
+            l_ref[h, :rows] = l_prev * alpha \
+                + jnp.sum(e, axis=1, keepdims=True)
+            m_ref[h, :rows] = m_new
+            acc_ref[h, :rows] = acc_ref[h, :rows] * alpha \
+                + jax.lax.dot_general(
+                    e, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [rows, d]
+            return carry
+
+        # unrolled: the heads' matmul -> softmax -> matmul chains are
+        # independent, and side by side they hide one another's latency
+        jax.lax.fori_loop(0, hkv, _head, None, unroll=True)
+
+    def narrow(i, slot):
+        multiply(i, slot, q_block, lambda h: q_ref[:, h])
+
+    def tile(i, slot):
+        multiply(i, slot, q_tile, lambda h: qt_buf[:, h])
+
+    if tile_refs:
+        @pl.when(wide & walks)
+        def _tile_in():
+            tile_queries(lambda c: c.start())
+            start(q_tile)
+        pl.when(jnp.logical_not(wide))(functools.partial(start, q_block))
+    else:
+        start(q_block)
 
     @pl.when(n_slabs > first_slab)
     def _first():
         each_live_page(first_slab, first_slab % 2, lambda c: c.start())
+
+    if tile_refs:
+        @pl.when(wide & walks)
+        def _tile_landed():
+            tile_queries(lambda c: c.wait())
 
     def _slab(i, carry):
         slot = i % 2
@@ -185,75 +346,42 @@ def _ragged_kernel(row_ref, qs_ref, ql_ref, kl_ref, tbl_ref, *refs,
             each_live_page(i + 1, 1 - slot, lambda c: c.start())
 
         each_live_page(i, slot, lambda c: c.wait())
-
-        base = i * slab
-        # per-token causal mask, the same for every head: token t of the
-        # chunk (absolute position kv_start + blk_off + t) sees kv
-        # positions <= its own; tokens past q_len (slot padding) are
-        # masked out entirely
-        tok = blk_off + jax.lax.broadcasted_iota(
-            jnp.int32, (qb, grp, slab), 0)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (qb, grp, slab), 2)
-        ok = (tok < q_len) & (pos <= kv_start + tok) & (pos < kv_len)
-        if window is not None:
-            ok &= pos > kv_start + tok - window
-        ok = ok.reshape(qb * grp, slab)
-        # rows of V past the horizon are pool positions no query of this
-        # block may see, or slab pages that were not fetched: their
-        # weight is exactly 0, and 0 x what lies there must stay 0
-        vpos = base + jax.lax.broadcasted_iota(jnp.int32, (slab, d), 0)
-        seen = vpos < horizon
-        if window is not None:
-            seen &= vpos >= first_key
-
-        def slab_of(buf, s_ref, h):
-            """Head ``h`` of the slab as f32 ``[slab, d]``; an int8
-            page is dequantized with its own per-(head, page) scale, a
-            scalar off SMEM indexed by the pool page the DMA read (a
-            page that was not fetched takes the last live page's: its
-            columns are masked)."""
-            pages = [buf[slot, j, h].astype(jnp.float32)
-                     for j in range(ppf)]
-            if quantized:
-                pages = [x * s_ref[h, tbl_ref[row, jnp.minimum(
-                    i * ppf + j, n_pages - 1)]]
-                    for j, x in enumerate(pages)]
-            return jnp.concatenate(pages, axis=0)
-
-        def _head(h, carry):
-            q = q_ref[:, h].reshape(qb * grp, d).astype(jnp.float32)
-            k = slab_of(k_buf, ks_ref, h)
-            v = jnp.where(seen, slab_of(v_buf, vs_ref, h), 0.0)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [qb*grp, slab]
-            s = jnp.where(ok, s, _NEG_INF)
-            m_prev = m_ref[h]                            # [qb*grp, 1]
-            l_prev = l_ref[h]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            e = jnp.exp(s - m_new)                       # [qb*grp, slab]
-            l_ref[h] = l_prev * alpha + jnp.sum(e, axis=1, keepdims=True)
-            m_ref[h] = m_new
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                e, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [qb*grp, d]
-            return carry
-
-        # unrolled: the heads' matmul -> softmax -> matmul chains are
-        # independent, and side by side they hide one another's latency
-        jax.lax.fori_loop(0, hkv, _head, None, unroll=True)
+        if tile_refs:
+            # one walk, two static widths: a chunk's tile, a q block
+            pl.when(wide)(functools.partial(tile, i, slot))
+            pl.when(jnp.logical_not(wide))(functools.partial(narrow, i, slot))
+        else:
+            narrow(i, slot)
         return carry
 
     jax.lax.fori_loop(first_slab, n_slabs, _slab, None)
 
     def _write(h, carry):
-        o_ref[:, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)) \
-            .reshape(qb, grp, d).astype(o_ref.dtype)
+        o_ref[:, h] = normalized(h, q_block).astype(o_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, hkv, _write, None)
+    if not tile_refs:
+        jax.lax.fori_loop(0, hkv, _write, None)
+        return
+
+    @pl.when(jnp.logical_not(wide))
+    def _narrow_out():
+        jax.lax.fori_loop(0, hkv, _write, None)
+
+    @pl.when(wide & walks)
+    def _tile_out():
+        def _keep(h, carry):
+            ot_buf[:, h] = normalized(h, q_tile).astype(ot_buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, hkv, _keep, None)
+
+    @pl.when(wide)
+    def _out():
+        # every block of a tile, the first included: its rows of the
+        # tile's output (the grid runs in order, so the tile's first
+        # block has written them)
+        o_ref[...] = ot_buf[pl.ds(blk_off % q_tile, q_block)]
 
 
 def ragged_block_row(q_starts, num_blocks, q_block):
@@ -294,7 +422,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, q_starts,
         dequant scales for int8 pools (both or neither).
     block_row:    optional precomputed :func:`ragged_block_row` result
         (``[total_q_tokens // q_block] int32``) — lets a fused prefill
-        step derive the map once and share it across layers.
+        step derive the map once and share it across layers. A row's q
+        tiles follow from it and the row lengths in the kernel.
     window:       None (every key up to the query's own), or the number
         of keys a query sees, itself included: the token at position
         ``p`` attends ``p - window < j <= p``. Static: a window layer
@@ -365,6 +494,16 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
             for x in (q, k_pages, v_pages))
     qg = q.reshape(t, hkv, group, dl)
     ppf = ragged_slab_pages(page_size, pages_per_seq)
+    q_tile = ragged_q_tile(q_block, group, t)
+    # the softmax state holds a q tile's rows; a q block uses its first
+    rows = q_tile * group
+    tile_scratch = [] if q_tile == q_block else [
+        # a wide walk's queries and its output, kept while the tile's
+        # other blocks copy their rows out
+        pltpu.VMEM((q_tile, hkv, group, dl), q.dtype),
+        pltpu.VMEM((q_tile, hkv, group, dl), q.dtype),
+        pltpu.SemaphoreType.DMA((1,)),
+    ]
 
     def _q_map(g, *prefetch):
         return (g, 0, 0, 0)
@@ -376,8 +515,11 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec((q_block, hkv, group, dl), _q_map),
+            # the queries again, in HBM: the first block of a q tile
+            # copies the tile's in itself
+            pl.BlockSpec(memory_space=pl.ANY),
             # the pools stay in HBM: the body fetches the pages its
-            # block's horizon covers, and no others
+            # walk's horizon covers, and no others
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -388,10 +530,10 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
             pltpu.VMEM((2, ppf, hkv, page_size, dl), k_pages.dtype),
             pltpu.VMEM((2, ppf, hkv, page_size, dl), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),                     # K/V, slot
-            pltpu.VMEM((hkv, q_block * group, 1), jnp.float32),  # m
-            pltpu.VMEM((hkv, q_block * group, 1), jnp.float32),  # l
-            pltpu.VMEM((hkv, q_block * group, dl), jnp.float32),  # acc
-        ],
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),     # m
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),     # l
+            pltpu.VMEM((hkv, rows, dl), jnp.float32),    # acc
+        ] + tile_scratch,
     )
     prefetch = [block_row, q_starts,
                 q_lens.astype(jnp.int32), kv_lens.astype(jnp.int32),
@@ -401,12 +543,16 @@ def _ragged_call(q, k_pages, v_pages, block_tables, q_starts, q_lens,
                      v_scales.astype(jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size,
-                          q_block=q_block, scale=scale,
+                          q_block=q_block, q_tile=q_tile, scale=scale,
                           quantized=quantized, window=window),
         out_shape=jax.ShapeDtypeStruct((t, hkv, group, dl), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret, name="ragged_paged_attention",
-    )(*prefetch, qg, k_pages, v_pages)
+        # in order: a tile's first block leaves the tile's output in
+        # VMEM for the blocks after it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(*prefetch, qg, qg, k_pages, v_pages)
     return out.reshape(t, hq, dl)[..., :d]
 
 
@@ -878,7 +1024,8 @@ def ragged_latent_attention_reference(q, c_pages, block_tables, q_starts,
 
 
 __all__ = ["kv_append", "paged_attention", "paged_attention_reference",
-           "ragged_block_row", "ragged_kv_tokens_read",
+           "ragged_block_row", "ragged_kv_tokens_read", "ragged_kv_walks",
+           "ragged_q_tile",
            "ragged_latent_attention", "ragged_latent_attention_reference",
            "ragged_paged_attention", "ragged_paged_attention_reference",
            "ragged_slab_pages"]

@@ -67,7 +67,8 @@ from ..models.generation import (LayerKind, _logits, _rms_norm, _rope,
                                  _wmat, extract_params, layer_kinds,
                                  request_keys, sample_rows)
 from ..kernels.paged_attention import (kv_append, ragged_kv_tokens_read,
-                                       ragged_paged_attention)
+                                       ragged_kv_walks,
+                                       ragged_paged_attention, ragged_q_tile)
 from ..profiler import phases, spans
 from .kv_cache import NULL_PAGE, PagedKVPool, PoolExhausted
 from .metrics import ServingMetrics
@@ -2449,9 +2450,16 @@ class LLMEngine:
             n_lin = len(self.pool.state_layers)
             n_full = len(self._kinds) - n_win - n_lin
 
+            # the ragged kernel's wide walk: a row of more than q_block
+            # tokens is walked a q tile at a time (the kernel's own rule)
+            q_tile = ragged_q_tile(
+                self.q_block,
+                self.cfg.num_attention_heads // self.cfg.num_key_value_heads,
+                self.step_token_budget)
+
             def walked(w):
                 return ragged_kv_tokens_read(
-                    q_lens, kv_lens, q_block=self.q_block,
+                    q_lens, kv_lens, q_block=self.q_block, q_tile=q_tile,
                     page_size=self.page_size, pages_per_seq=PPS, window=w)
             # a child span of its own, so the device's idle time under
             # these always-on counts has a name in a trace's breakdown
@@ -2467,7 +2475,11 @@ class LLMEngine:
                     attn_kv_tokens_read=(
                         (n_full and n_full * walked(None))
                         + (n_win and n_win * walked(window)))
-                    // (len(self._kinds) - n_lin))
+                    // (len(self._kinds) - n_lin),
+                    # the walks one layer's call makes: q blocks of the
+                    # short rows, q tiles of the long ones
+                    attn_kv_walks=ragged_kv_walks(
+                        q_lens, q_block=self.q_block, q_tile=q_tile))
             if n_lin:
                 # what the linear layers' kernel reads and writes: a
                 # state a live row and layer, and its tokens
@@ -2663,8 +2675,11 @@ class LLMEngine:
                # as the ragged walk would cover these rows, so that the
                # two counts stay a pair on every step
                attn_kv_tokens_read=ragged_kv_tokens_read(
-                   live, kv_lens, q_block=1, page_size=self.page_size,
-                   pages_per_seq=PPS))
+                   live, kv_lens, q_block=1, q_tile=1,
+                   page_size=self.page_size, pages_per_seq=PPS),
+               # a burst walks each row's context once an iteration: one
+               # walk a token it yields, as `decode_tokens` counts them
+               attn_kv_walks=int(gen.sum()))
         for i, (seq, cap) in enumerate(bplan.rows):
             if not ok[i]:
                 # the row went non-finite at some loop iteration: every

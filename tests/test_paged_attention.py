@@ -337,19 +337,135 @@ def test_ragged_poisoned_pool_never_reaches_the_output(qb):
     _assert_live_rows_close(out, ref, qs, ql)
 
 
+# the counts' geometry: 8 q heads a kv head (Qwen3-Next's, K-EXAONE's),
+# so a row longer than q_block 8 or 1 is walked 64 tokens at a time; at
+# q_block 128 the tile is the block and every row is walked a block at
+# a time
+_COUNT_GROUP, _COUNT_BUDGET = 8, 512
+
+
 @pytest.mark.parametrize("q_lens,kv_lens,qb,want", [
     ([0, 0], [0, 0], 8, 0),                   # nothing aboard
     ([1], [1], 8, _SLAB),                     # a row rounds up to a slab
     ([1, 1, 1], [_SLAB - 1, _SLAB, _SLAB + 1], 8, 4 * _SLAB),
-    # kv_start 28 under the slab boundary, eight blocks with horizons
-    # from 20 under it to 36 over it: three under (-20, -12, -4), five
-    # over
-    ([64], [_SLAB + 36], 8, 3 * _SLAB + 5 * 2 * _SLAB),
+    # kv_start 28 under the slab boundary: one 64-token tile whose last
+    # query's horizon is 36 over it, two slabs (a q block of 8 at a
+    # time it was eight walks, three of one slab and five of two)
+    ([64], [_SLAB + 36], 8, 2 * _SLAB),
     ([64], [_SLAB + 36], 128, 2 * _SLAB),     # one block sees it once
-    ([64, 0], [_SLAB + 36, 0], 1, 28 * _SLAB + 36 * 2 * _SLAB),
+    # q_block 1: the same tile (sixty-four one-token walks before, 28 of
+    # one slab and 36 of two)
+    ([64, 0], [_SLAB + 36, 0], 1, 2 * _SLAB),
+    # kv_start at the slab boundary, 200 tokens: tiles at 0, 64, 128 and
+    # 192 (8 wide), horizons 320, 384, 448, 456: two slabs each
+    ([200], [_SLAB + 200], 8, 4 * 2 * _SLAB),
+    # a decode row beside two chunks: 1 slab; a 70-token chunk from 0,
+    # tiles of 64 and 6 with horizons 64 and 70, one slab each; a 16-token
+    # chunk behind 500, one tile with horizon 516, three slabs
+    ([1, 70, 16], [3, 70, 516], 8, _SLAB + 2 * _SLAB + 3 * _SLAB),
 ])
 def test_ragged_kv_tokens_read_counts_the_walk(q_lens, kv_lens, qb, want):
-    """The count on ``serve.step``: each live q block's causal horizon
-    rounded up to the slab, summed — what one kv head's loops cover."""
-    assert ragged_kv_tokens_read(q_lens, kv_lens, q_block=qb,
+    """The count on ``serve.step``: each walk's causal horizon rounded up
+    to the slab, summed — what one kv head's loops cover. A row of at
+    most ``q_block`` tokens is walked a q block at a time, a longer one a
+    q tile at a time."""
+    tile = ragged_q_tile(qb, _COUNT_GROUP, _COUNT_BUDGET)
+    assert tile == (128 if qb == 128 else 64)
+    assert ragged_kv_tokens_read(q_lens, kv_lens, q_block=qb, q_tile=tile,
                                  page_size=_PS, pages_per_seq=_PPS) == want
+
+
+@pytest.mark.parametrize("q_lens,qb,want", [
+    ([0, 0], 8, 0),                   # nothing aboard: no walk
+    ([1, 1, 1, 0], 8, 3),             # a decode row: one block
+    ([8, 9], 8, 2),                   # one block; a 9-token chunk: a tile
+    ([64, 65, 200], 8, 1 + 2 + 4),    # tiles of 64: 1, 2 and 4 walks
+    ([64, 1], 1, 1 + 1),              # q_block 1: the chunk is one tile
+    ([200, 1], 128, 2 + 1),           # tile = q_block 128: blocks only
+    ([4, 200], 4, 1 + 4),             # spec rounds of 4 at q_block 4
+])
+def test_ragged_kv_walks_counts_blocks_and_tiles(q_lens, qb, want):
+    """``attn_kv_walks`` on ``serve.step``: the walks one layer's call
+    makes, a live q block of each row of at most ``q_block`` tokens and a
+    q tile of each longer one — the walks the count above sums."""
+    tile = ragged_q_tile(qb, _COUNT_GROUP, _COUNT_BUDGET)
+    assert ragged_kv_walks(q_lens, q_block=qb, q_tile=tile) == want
+    # every context here is under a slab: each walk reads one
+    assert ragged_kv_tokens_read(
+        q_lens, [n + 1 for n in q_lens], q_block=qb, q_tile=tile,
+        page_size=_PS, pages_per_seq=_PPS) == want * _SLAB
+
+
+# ---------------------------------------------------------------------------
+# the wide walk: a row longer than a q block walked a q tile at a time
+# ---------------------------------------------------------------------------
+
+from paddle_tpu.kernels.paged_attention import (  # noqa: E402
+    ragged_kv_walks, ragged_q_tile)
+
+# (q heads, kv heads, head_dim, q_lens, kv_lens): decode rows first, so
+# that each chunk's slot starts at a q block that is no multiple of the
+# tile; each chunk spans two or three tiles, its last one shorter
+_TILE_GEOMETRIES = {
+    # Qwen3-Next's full layers: 8 q heads a kv head of 256 (tile 64)
+    "g8_d256": (8, 1, 256, [1, 150, 1, 70, 0], [37, 450, 513, 330, 0]),
+    # Mistral-7B's: 4 q heads a kv head of 128 (tile 128); the second
+    # chunk is a fresh prompt, kv_len == q_len
+    "g4_d128": (8, 2, 128, [1, 200, 1, 136, 0], [300, 460, 37, 136, 0]),
+}
+# "fp" and "poisoned" share one compile
+_TILE_CASES = [("g8_d256", "fp"), ("g8_d256", "poisoned"),
+               ("g8_d256", "window"), ("g4_d128", "int8")]
+_tile_kernel = jax.jit(ragged_paged_attention,
+                       static_argnames=("q_block", "interpret", "window"))
+
+
+@pytest.mark.parametrize("geo,pool", _TILE_CASES)
+def test_ragged_q_tiles_match_the_reference(geo, pool):
+    """Chunks beside decode rows in one launch, each walked a q tile at a
+    time, against the oracle: f32 and int8 pools; a window layer whose
+    pages under each row's window are released (they name page 0) and
+    every position no query may see is NaN; a poisoned full layer."""
+    hq, hkv, d, q_lens, kv_lens = _TILE_GEOMETRIES[geo]
+    # pages of 64: four a slab, so the interpreter unrolls few copies
+    qb, ps, window = 8, 64, 24 if pool == "window" else None
+    budget = sum(-(-n // qb) * qb for n in q_lens) + qb
+    tile = ragged_q_tile(qb, hq // hkv, budget)
+    q_starts = _pack_rows(q_lens, qb, budget)
+    chunks = [n for n in q_lens if n > qb]
+    assert all(s % tile for s, n in zip(q_starts, q_lens) if n > qb)
+    assert all(n > tile and n % tile for n in chunks)
+    assert ragged_kv_walks(q_lens, q_block=qb, q_tile=tile) \
+        == len(q_lens) - 1 - len(chunks) + sum(-(-n // tile) for n in chunks)
+    rng = np.random.default_rng(13)
+    pps = -(-max(kv_lens) // ps)
+    npages = len(q_lens) * pps + 1
+    q = rng.standard_normal((budget, hq, d)).astype(np.float32)
+    kp = rng.standard_normal((hkv, npages, ps, d)).astype(np.float32)
+    vp = rng.standard_normal((hkv, npages, ps, d)).astype(np.float32)
+    tbl = (rng.permutation(npages - 1)[:len(q_lens) * pps] + 1) \
+        .reshape(len(q_lens), pps).astype(np.int32)
+    scales = {}
+    if pool == "int8":
+        kp, vp, scales = _quantize_pools(kp, vp)
+    want = np.asarray(ragged_paged_attention_reference(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        q_starts, np.asarray(q_lens), np.asarray(kv_lens), window=window,
+        **scales))
+    if pool in ("window", "poisoned"):
+        seen = np.zeros(kp.shape[1:3], bool)          # [page, slot]
+        for i, (n, m) in enumerate(zip(kv_lens, q_lens)):
+            first = max(n - m - window + 1, 0) if window else 0
+            pos = np.arange(first, n)
+            seen[tbl[i, pos // ps], pos % ps] = True
+            tbl[i, :first // ps] = 0                  # released
+        kp = np.where(seen[None, :, :, None], kp, np.nan)
+        vp = np.where(seen[None, :, :, None], vp, np.nan)
+    out = np.asarray(_tile_kernel(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tbl),
+        jnp.asarray(q_starts), jnp.asarray(q_lens, jnp.int32),
+        jnp.asarray(kv_lens, jnp.int32), q_block=qb, interpret=True,
+        window=window, **scales))
+    assert np.isfinite(out).all()
+    _assert_live_rows_close(out, want, q_starts, q_lens,
+                            tol=1e-4 if pool == "int8" else 2e-4)

@@ -190,9 +190,14 @@ def _run_counting(eng, prompts, max_new=4):
     the context attention had to read, the query tokens scheduled and
     what the ragged kernel's walk covers, as the scheduler's plan gives
     them."""
-    from paddle_tpu.kernels.paged_attention import ragged_kv_tokens_read
+    from paddle_tpu.kernels.paged_attention import (
+        ragged_kv_tokens_read, ragged_kv_walks, ragged_q_tile)
     want_live = []
     prepare = eng.scheduler.prepare_step
+    cfg = eng.cfg
+    tile = ragged_q_tile(
+        eng.q_block, cfg.num_attention_heads // cfg.num_key_value_heads,
+        eng.step_token_budget)
 
     def spy():
         plan = prepare()
@@ -202,8 +207,10 @@ def _run_counting(eng, prompts, max_new=4):
             want_live.append((sum(kv_lens), sum(q_lens),
                               ragged_kv_tokens_read(
                                   q_lens, kv_lens, q_block=eng.q_block,
-                                  page_size=eng.page_size,
-                                  pages_per_seq=eng.max_pages_per_seq)))
+                                  q_tile=tile, page_size=eng.page_size,
+                                  pages_per_seq=eng.max_pages_per_seq),
+                              ragged_kv_walks(q_lens, q_block=eng.q_block,
+                                              q_tile=tile)))
         return plan
 
     eng.scheduler.prepare_step = spy
@@ -265,8 +272,13 @@ def test_counts_agree_with_the_programs_counters(tiny_model):
         == delta("prefill_chunks")
     assert [(s.attrs["live_kv_tokens"],
              s.attrs["prefill_tokens"] + s.attrs["decode_tokens"],
-             s.attrs["attn_kv_tokens_read"])
+             s.attrs["attn_kv_tokens_read"], s.attrs["attn_kv_walks"])
             for s in step_recs] == want_live
+    # the 8-token chunks (q_block 8) are walked a block at a time: at
+    # most a walk a query token, at least a walk a row
+    assert all(s.attrs["rows"] <= s.attrs["attn_kv_walks"]
+               <= s.attrs["prefill_tokens"] + s.attrs["decode_tokens"]
+               for s in step_recs)
     # the walk covers every live token at least once
     assert all(s.attrs["attn_kv_tokens_read"] >= s.attrs["live_kv_tokens"]
                for s in step_recs)
@@ -307,6 +319,37 @@ def test_a_step_counts_the_rows_that_sample_and_mask(tiny_model, kw):
            "trace": None, "peaks": None}
     assert harness.read_layer_metric("sampler_short_step_pct", run) \
         == pytest.approx(100.0 * short / len(got))
+
+
+def test_the_q_per_walk_reader_on_a_canned_log(monkeypatch):
+    """``ragged_attn_q_per_walk``: mean query tokens over mean
+    ``attn_kv_walks`` over the window's steps, on records made by hand;
+    a log from before the count reads nothing."""
+    from benchmark import run as harness
+    ms = 1_000_000
+
+    def log(with_walks):
+        recs = [(1, 0, "serve.step", 0, 10 * ms,
+                 dict(prefill_tokens=0, decode_tokens=9, attn_kv_walks=9))]
+        # in the window: 256 + 20 tokens in 4 + 20 walks, 64 + 30 in
+        # 1 + 30, then a step that launched nothing
+        for i, (p, d, w) in enumerate([(256, 20, 24), (64, 30, 31)]):
+            attrs = dict(prefill_tokens=p, decode_tokens=d)
+            if with_walks:
+                attrs["attn_kv_walks"] = w
+            recs.append((i + 2, 0, "serve.step", (i + 1) * 10 * ms,
+                         (i + 2) * 10 * ms, attrs))
+        recs.append((4, 0, "serve.step", 30 * ms, 40 * ms, None))
+        return recs
+
+    run = {"step_s": [0.01] * 3, "end_to_end": {}, "trace": None,
+           "peaks": None}
+    for with_walks, want in ((True, (276 + 94) / (24 + 31)), (False, None)):
+        monkeypatch.setattr(spans, "records",
+                            lambda name=None, r=log(with_walks): list(r))
+        monkeypatch.setattr(spans, "open_spans", lambda name=None: [])
+        got = harness.read_layer_metric("ragged_attn_q_per_walk", run)
+        assert got == (want and pytest.approx(want))
 
 
 def test_a_requests_life_is_queue_then_prefill(tiny_model):
@@ -421,6 +464,10 @@ def test_burst_and_spec_steps_are_cut_into_phases_too(tiny_model, kind, kw):
     else:
         assert drafted == 0 and eng.metrics.burst_launches.value > 0 \
             and len(step_recs) < 8
+        # one row aboard: every step after the prompt's walks the row's
+        # context once a token it yields, a burst as a ragged step
+        assert all(s.attrs["attn_kv_walks"] == s.attrs["decode_tokens"]
+                   for s in step_recs[1:])
 
 
 def test_spans_share_the_profilers_clock(tiny_model, tmp_path):

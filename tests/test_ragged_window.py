@@ -11,7 +11,7 @@ import pytest
 
 from paddle_tpu.kernels.paged_attention import (
     ragged_kv_tokens_read, ragged_paged_attention,
-    ragged_paged_attention_reference, ragged_slab_pages)
+    ragged_paged_attention_reference, ragged_q_tile, ragged_slab_pages)
 
 PS, PPS, W = 8, 128, 24           # page, table width, window (3 pages)
 ROWS = 5
@@ -144,14 +144,25 @@ def test_window_released_pages_are_never_read():
     _close(out, want, qs, ql)
 
 
+# the q tile of this file's launches at q_block 8: 2 q heads a kv head
+# would fill 256 tokens, the launch holds 96
+TILE = ragged_q_tile(8, 2, BUDGET[8])
+
+
 @pytest.mark.parametrize("q_lens,kv_lens,window,want", [
     ([1], [40 * W], None, 4 * SLAB),            # 960 keys: four slabs
     ([1], [40 * W], W, SLAB),                   # 936..959 lie in slab 3
     ([1], [SLAB + 3], W, 2 * SLAB),             # window straddles a slab
-    ([64], [64], W, 8 * SLAB),                  # eight q blocks, slab 0
-    ([1, 16], [10, 2 * SLAB + 16], W, SLAB + 2 * 2 * SLAB),
+    # one tile (eight q blocks of slab 0 before)
+    ([64], [64], W, SLAB),
+    # a decode row, slab 0; a 16-token chunk at 512 is one tile whose
+    # horizon 528 ends in slab 2 and whose first query's window starts
+    # at 489, slab 1: two slabs (two q blocks of two before)
+    ([1, 16], [10, 2 * SLAB + 16], W, SLAB + 2 * SLAB),
 ])
 def test_kv_tokens_read_follows_the_window(q_lens, kv_lens, window, want):
-    got = ragged_kv_tokens_read(q_lens, kv_lens, q_block=8, page_size=PS,
-                                pages_per_seq=PPS, window=window)
+    assert TILE == 96
+    got = ragged_kv_tokens_read(q_lens, kv_lens, q_block=8, q_tile=TILE,
+                                page_size=PS, pages_per_seq=PPS,
+                                window=window)
     assert got == want
